@@ -28,7 +28,8 @@ import math
 __all__ = ["ArithCost", "mac_cost", "pm_mac_cost", "complex_mac_cost",
            "cpm4_cost", "cpm3_cost", "systolic_array_cost",
            "tensor_core_cost", "savings_table",
-           "TileCost", "pm_tile_vmem_bytes", "pm_tile_vpu_ops",
+           "TileCost", "vmem_tile_elems", "pm_tile_vmem_bytes",
+           "pm_tile_vpu_ops",
            "pm_grid_cost", "conv2d_window_elems", "conv2d_patch_bytes",
            "conv2d_grid_cost", "paged_attn_gather_bytes"]
 
@@ -163,6 +164,17 @@ class TileCost:
         return self.vpu_ops + 4096.0 * self.grid_steps + 256.0 * self.chunk_steps
 
 
+def vmem_tile_elems(*shape: int) -> int:
+    """Elements a VMEM buffer of ``shape`` occupies: 32-bit data lives in
+    (8, 128) tiles, so the two minor axes round up to 8 sublanes and 128
+    lanes (a (bm, 1) correction column costs a full lane row per row)."""
+    *lead, rows, cols = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    n = -(-rows // 8) * 8 * (-(-cols // 128)) * 128
+    for d in lead:
+        n *= d
+    return n
+
+
 def pm_tile_vmem_bytes(bm: int, bn: int, bk: int, kc: int, itemsize: int = 4,
                        n_row_ops: int = 1, n_col_ops: int = 1,
                        n_acc: int = 1) -> int:
@@ -171,13 +183,16 @@ def pm_tile_vmem_bytes(bm: int, bn: int, bk: int, kc: int, itemsize: int = 4,
     Counts the streamed operand slabs (``n_row_ops`` of (bm, bk) and
     ``n_col_ops`` of (bk, bn)), the scratch accumulator planes
     (``n_acc`` of (bm, bn)), the live rank-3 PM intermediate
-    (bm, kc, bn), and the (bm, 1)/(1, bn) correction vectors.
-    Double-buffering of the streamed slabs is included (x2).
+    (bm, kc, bn) -- one per accumulator, since the CPM bodies keep a
+    block per output plane -- and the (bm, 1)/(1, bn) correction
+    vectors, every buffer at its (8, 128)-tiled size.  Double-buffering
+    of the streamed slabs is included (x2).
     """
-    slabs = 2 * (n_row_ops * bm * bk + n_col_ops * bk * bn)
-    accs = n_acc * bm * bn * 2                 # scratch + out block
-    interm = bm * kc * bn
-    corr = 2 * (bm + bn)
+    slabs = 2 * (n_row_ops * vmem_tile_elems(bm, bk)
+                 + n_col_ops * vmem_tile_elems(bk, bn))
+    accs = n_acc * vmem_tile_elems(bm, bn) * 2      # scratch + out block
+    interm = n_acc * vmem_tile_elems(bm, kc, bn)
+    corr = 2 * (n_acc * vmem_tile_elems(bm, 1) + vmem_tile_elems(1, bn))
     return (slabs + accs + interm + corr) * itemsize
 
 
@@ -260,15 +275,15 @@ def conv2d_grid_cost(oh: int, ow: int, kh: int, kw: int, cin: int, cout: int,
     - VMEM holds the kernel's actual input block -- the FULL padded
       spatial plane, ``bk`` channels deep (windows of adjacent tiles
       overlap, so the kernel stages the plane, not a per-tile window) --
-      plus the tile-local slab (the in-SRAM im2col of one tile), tap
-      block, accumulator and live PM chunk.
+      plus the tile-local slab (one tap's in-SRAM im2col), tap block,
+      accumulator and live PM chunk, each at its (8, 128)-tiled size.
     """
     gm = -(-oh // bh) * (-(-ow // bw))
     gf = -(-cout // bf)
     gc = -(-cin // bk)
     grid = gm * gf * gc
     ktot = kh * kw * bk                      # flattened per-step K axis
-    chunks = grid * (-(-ktot // kc))
+    chunks = grid * kh * kw * (-(-bk // kc))
     m_pad = -(-oh // bh) * bh * (-(-ow // bw)) * bw
     k_pad = gc * ktot
     n_pad = gf * bf
@@ -282,13 +297,14 @@ def conv2d_grid_cost(oh: int, ow: int, kh: int, kw: int, cin: int, cout: int,
     # in range, so that is what actually sits in VMEM.
     ohp = -(-oh // bh) * bh
     owp = -(-ow // bw) * bw
-    plane = conv2d_window_elems(ohp, owp, kh, kw, bk, sh, sv)
-    vmem = (2 * plane                        # double-buffered input block
-            + 2 * kh * kw * bk * bf          # tap block
-            + 2 * bh * bw * bf               # scratch + out tile
-            + bh * bw * ktot                 # tile-local shifted-view slab
-            + bh * bw * kc * bf              # live rank-3 PM chunk
-            + bf) * itemsize
+    hp = (ohp - 1) * sh + kh
+    wp = (owp - 1) * sv + kw
+    vmem = (2 * hp * vmem_tile_elems(wp, bk)     # double-buffered plane
+            + 2 * kh * kw * vmem_tile_elems(bk, bf)        # tap block
+            + 3 * vmem_tile_elems(bh * bw, bf)   # scratch + out tile
+            + vmem_tile_elems(bh * bw, bk)       # one tap's view slab
+            + vmem_tile_elems(bh * bw, kc, bf)   # live rank-3 PM chunk
+            + vmem_tile_elems(1, bf)) * itemsize
     return TileCost(vmem_bytes=vmem, vpu_ops=pm + corr + loads,
                     grid_steps=grid, chunk_steps=chunks)
 

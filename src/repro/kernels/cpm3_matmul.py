@@ -18,9 +18,9 @@ shape (bm, kc, bn) for the "mkn" layout or (bm, bn, kc) for the
 minor-axis-reduce "mnk" layout -- see sq_matmul.py for the trade-off).
 
 The shared subexpressions of the three squares are HOISTED out of the
-chunk loop: the combined planes ``a+b`` (rows), ``c+s`` and ``s-c``
-(columns) are formed once per grid step on rank-2 slabs, so each PM term
-inside a chunk is exactly ONE broadcast add + one square --
+PM terms: the combined planes ``a+b`` (rows), ``c+s`` and ``s-c``
+(columns) are formed once per chunk on the rank-2 slabs, so each PM term
+is exactly ONE broadcast add + one square --
     shared = ((a+b) + c)^2    u = (b + (c+s))^2    v = (a + (s-c))^2
 -- the same adds/square ratio as the real kernel, instead of the naive
 two broadcast adds per term (6 rank-3 adds per chunk down to 3).
@@ -49,8 +49,8 @@ def _cpm3_body(rs, cs, axis, carry):
     """One chunk's three squares (paper eqs 32/34) on pre-broadcast slabs.
 
     Row slabs are (a+b, b, a); column slabs (c, c+s, s-c) -- the pairwise
-    sums hoisted once per grid step, so every square costs one broadcast
-    add here (see module docstring)."""
+    sums hoisted to rank 2 (:func:`_cpm3_rows`/:func:`_cpm3_cols`), so
+    every square costs one broadcast add here (see module docstring)."""
     re, im = carry
     ab_s, b_s, a_s = rs
     c_s, cs_s, sc_s = cs
@@ -63,6 +63,14 @@ def _cpm3_body(rs, cs, axis, carry):
     return re, im
 
 
+def _cpm3_rows(a, b):
+    return a + b, b, a
+
+
+def _cpm3_cols(c, s):
+    return c, c + s, s - c
+
+
 def cpm3_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sre_ref, sim_ref,
                        re_ref, im_ref, re_acc, im_acc, *, nk: int, kc: int,
                        pm_layout: str):
@@ -70,18 +78,13 @@ def cpm3_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sre_ref, sim_ref,
 
     @pl.when(k_step == 0)
     def _init():
-        re_acc[...] = sre_ref[:, 0][:, None] + jnp.zeros_like(re_acc)
-        im_acc[...] = sim_ref[:, 0][:, None] + jnp.zeros_like(im_acc)
+        re_acc[...] = jnp.broadcast_to(sre_ref[...], re_acc.shape)
+        im_acc[...] = jnp.broadcast_to(sim_ref[...], im_acc.shape)
 
-    a = a_ref[...]
-    b = b_ref[...]
-    c = c_ref[...]
-    s = s_ref[...]
-    # hoisted rank-2 combined planes (once per K slab, not per PM term)
     re, im = pm_chunked_reduce(
-        (re_acc[...], im_acc[...]),
-        (a + b, b, a), (c, c + s, s - c),
-        kc=kc, pm_layout=pm_layout, body=_cpm3_body)
+        (re_acc[...], im_acc[...]), (a_ref, b_ref), (c_ref, s_ref),
+        kc=kc, pm_layout=pm_layout, body=_cpm3_body, rows=_cpm3_rows,
+        cols=_cpm3_cols)
     re_acc[...] = re
     im_acc[...] = im
 
@@ -136,7 +139,7 @@ def cpm3_matmul_pallas(a, b, c, s, sre, sim, scs, ssc, *, bm: int = 256,
             pltpu.VMEM((bm, bn), a.dtype),
             pltpu.VMEM((bm, bn), a.dtype),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, c, s, sre, sim)

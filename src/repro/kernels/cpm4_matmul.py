@@ -15,7 +15,7 @@ Per (h, i, k):
 Unlike CPM3 there is NO square shared between the planes to hoist: each
 of the four squares pairs one row plane directly with one column plane,
 already one broadcast add per PM term.  The only hoistable subexpression
-is the negated column plane ``-s`` (formed rank-2 once per grid step so
+is the negated column plane ``-s`` (formed at rank 2 once per chunk so
 the (b - s) term is a uniform broadcast *add* like the other three); the
 remaining ~2x-vs-3x interpret gap against ``sq_matmul`` is intrinsic --
 CPM4 does 4 squares + 4 rank-3 adds per complex multiply where the real
@@ -52,6 +52,10 @@ def _cpm4_body(rs, cs, axis, carry):
     return re, im
 
 
+def _cpm4_cols(c, s):
+    return c, s, -s
+
+
 def cpm4_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sx_ref, re_ref, im_ref,
                        re_acc, im_acc, *, nk: int, kc: int, pm_layout: str):
     k_step = pl.program_id(2)
@@ -60,14 +64,12 @@ def cpm4_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sx_ref, re_ref, im_ref,
     def _init():
         # both planes start from the row correction Sx_h (col term added
         # by the wrapper, mirroring Fig.2's staggered Sb_j injection)
-        re_acc[...] = sx_ref[:, 0][:, None] + jnp.zeros_like(re_acc)
-        im_acc[...] = sx_ref[:, 0][:, None] + jnp.zeros_like(im_acc)
+        re_acc[...] = jnp.broadcast_to(sx_ref[...], re_acc.shape)
+        im_acc[...] = jnp.broadcast_to(sx_ref[...], im_acc.shape)
 
-    s = s_ref[...]
     re, im = pm_chunked_reduce(
-        (re_acc[...], im_acc[...]),
-        (a_ref[...], b_ref[...]), (c_ref[...], s, -s),
-        kc=kc, pm_layout=pm_layout, body=_cpm4_body)
+        (re_acc[...], im_acc[...]), (a_ref, b_ref), (c_ref, s_ref),
+        kc=kc, pm_layout=pm_layout, body=_cpm4_body, cols=_cpm4_cols)
     re_acc[...] = re
     im_acc[...] = im
 
@@ -112,7 +114,7 @@ def cpm4_matmul_pallas(a, b, c, s, sx, sy, *, bm: int = 256, bn: int = 256,
             pltpu.VMEM((bm, bn), a.dtype),
             pltpu.VMEM((bm, bn), a.dtype),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, c, s, sx)

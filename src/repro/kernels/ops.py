@@ -90,6 +90,7 @@ def _resolve_plan(m, n, k, dtype, *, bm, bn, bk, kc, pm_layout, interpret,
 # Prepare halves (the constant-operand, weight-stationary work)
 # --------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("plan", "acc_dtype"))
 def prepare_matmul_rhs(b, plan, acc_dtype):
     """The column-operand half of the matmul prep pipeline.
 
@@ -99,7 +100,10 @@ def prepare_matmul_rhs(b, plan, acc_dtype):
     ``(bw, sb)``: the kernel-ready column slab and its correction vector.
     This is the work :func:`repro.core.prepared.prepare_operand` amortizes
     across calls; raw-array dispatch runs it per call on the same code
-    path.
+    path.  It is one compiled program, dispatched on its own by both:
+    inlined into the execute half, XLA would fuse the correction's
+    reduction differently and the two styles would differ in the last
+    bits.
     """
     bw = b.astype(acc_dtype)
     sb = sq.col_correction(bw, axis=-2)[..., None, :]       # (..., 1, n)
@@ -171,9 +175,8 @@ def _sq_matmul_exec(a, bw, sb, n, plan, interpret):
     return out[:m, :n]
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
 def _sq_matmul_impl(a, b, plan, interpret):
-    """Raw-array path: prepare-then-execute in one jit."""
+    """Raw-array path: prepare, then execute."""
     acc = sq.accum_dtype(a.dtype)
     bw, sb = prepare_matmul_rhs(b, plan, acc)
     return _sq_matmul_exec(a, bw, sb, b.shape[-1], plan, interpret)
@@ -196,7 +199,6 @@ def _sq_matmul_batched_exec(a, bw, sb, n, fb, plan, interpret):
     return out[:nb, :m, :n]
 
 
-@functools.partial(jax.jit, static_argnames=("fb", "plan", "interpret"))
 def _sq_matmul_batched_impl(a, b, fb, plan, interpret):
     acc = sq.accum_dtype(a.dtype)
     bw, sb = prepare_matmul_rhs(b, plan, acc)

@@ -1,74 +1,83 @@
 """Shared chunked block-PM machinery for the Pallas square kernels.
 
-All three matmul-family kernels walk a K slab in ``kc``-wide chunks of
-rank-2 broadcast squaring; they differ only in the squares computed per
-chunk (one PM term for the real kernel, three/four for CPM3/CPM4).  This
-module owns the part they share -- slab slicing, broadcast shaping, the
-layout dispatch, and the homogeneous ``fori_loop`` -- so the layout logic
-exists exactly once.
+Every square kernel walks a K slab in ``kc``-wide chunks of rank-2
+broadcast squaring; they differ only in the squares computed per chunk
+(one PM term for the real kernel and for attention, three/four for
+CPM3/CPM4).  This module owns the part they share -- chunk slicing,
+broadcast shaping, the layout dispatch, and the homogeneous ``fori_loop``
+-- so the layout logic exists exactly once.
+
+Chunks are sliced from the VMEM *refs* (``ref[..., pl.ds(c * kc, kc)]``),
+never by slicing loaded values: Mosaic has no lowering for
+``dynamic_slice`` on values.  A dynamic start on the minor (lane) axis
+must be provably a multiple of 128 lanes, so on the TPU a multi-chunk
+walk needs a lane-aligned ``kc``; a single chunk (``kc == bk``) has a
+static start and any width.  The planner (:mod:`repro.kernels.tuning`)
+only proposes such ``"mkn"`` plans.  The chunk walk is a ``fori_loop``
+and not a Python loop: Mosaic gives every statically unrolled chunk its
+own VMEM stack allocation, so an unrolled walk runs out of VMEM.
 
 Two PM-block layouts (see kernels.sq_matmul for the performance story):
 
 ``"mkn"``
-    Slabs broadcast to (bm, kc, 1) x (1, kc, bn); ``body`` reduces axis 1.
-    bn stays on the 128-lane minor axis -- the TPU-native schedule.
+    Slabs broadcast to (bm, kc, 1) x (1, kc, bn); ``body`` reduces the
+    second-minor axis.  bn stays on the 128-lane minor axis -- the
+    TPU-native schedule.
 ``"mnk"``
-    Column operands are transposed once per grid step; slabs broadcast to
-    (bm, 1, kc) x (1, bn, kc); ``body`` reduces the minor axis, which
-    fuses into a dot-product-shaped loop nest -- the CPU/interpret
-    schedule.
+    Column chunks are transposed; slabs broadcast to (bm, 1, kc) x
+    (1, bn, kc); ``body`` reduces the minor axis, which fuses into a
+    dot-product-shaped loop nest -- the CPU/interpret schedule.
 
-The accumulator ``carry`` (an array or tuple of arrays) is threaded
-through one homogeneous ``fori_loop`` with no peeled first chunk -- XLA
-compiles the single loop body markedly better than a peeled-plus-loop mix.
+Refs may carry leading batch dims (the batch-folded matmul): they ride
+through every slice and broadcast unchanged.
 """
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 __all__ = ["PM_LAYOUTS", "pm_chunked_reduce"]
 
 PM_LAYOUTS = ("mkn", "mnk")
 
 
-def pm_chunked_reduce(carry, row_ops, col_ops, *, kc: int, pm_layout: str,
-                      body):
+def pm_chunked_reduce(carry, row_refs, col_refs, *, kc: int, pm_layout: str,
+                      body, rows=None, cols=None):
     """Run ``body`` over every kc-wide chunk of the K slab.
 
-    row_ops: tuple of (bm, bk) values; col_ops: tuple of (bk, bn) values
-    (already loaded from VMEM refs, pre-widened to the accumulator dtype).
+    row_refs: tuple of (..., bm, bk) refs; col_refs: tuple of (..., bk, bn)
+    refs, holding values already widened to the accumulator dtype.
+    ``rows``/``cols`` optionally map a chunk's loaded (..., bm, kc) /
+    (..., kc, bn) slabs to the tuple ``body`` squares (pairwise sums
+    formed once per chunk at rank 2, not once per PM term).
     ``body(row_slabs, col_slabs, axis, carry) -> carry`` receives the
-    chunk's slabs pre-broadcast to rank 3 (layouts above) and the
-    reduction axis; it computes the squares and accumulates.
+    chunk's slabs pre-broadcast (layouts above) and the reduction axis;
+    it computes the squares and accumulates.
     """
-    bk = row_ops[0].shape[1]
-    nc = bk // kc
-
-    if pm_layout == "mkn":
-        def slabs(c):
-            rs = tuple(jax.lax.dynamic_slice_in_dim(r, c * kc, kc, 1)
-                       [:, :, None] for r in row_ops)       # (bm, kc, 1)
-            cs = tuple(jax.lax.dynamic_slice_in_dim(co, c * kc, kc, 0)
-                       [None, :, :] for co in col_ops)      # (1, kc, bn)
-            return rs, cs
-        axis = 1
-    elif pm_layout == "mnk":
-        col_t = tuple(co.T for co in col_ops)               # (bn, bk)
-
-        def slabs(c):
-            rs = tuple(jax.lax.dynamic_slice_in_dim(r, c * kc, kc, 1)
-                       [:, None, :] for r in row_ops)       # (bm, 1, kc)
-            cs = tuple(jax.lax.dynamic_slice_in_dim(ct, c * kc, kc, 1)
-                       [None, :, :] for ct in col_t)        # (1, bn, kc)
-            return rs, cs
-        axis = -1
-    else:
+    if pm_layout not in PM_LAYOUTS:
         raise ValueError(f"unknown pm_layout {pm_layout!r}; "
                          f"expected one of {PM_LAYOUTS}")
+    nc = row_refs[0].shape[-1] // kc
 
     def chunk(c, carry):
-        rs, cs = slabs(c)
-        return body(rs, cs, axis, carry)
+        if nc == 1:                    # whole refs: no slice to align
+            rs = tuple(r[...] for r in row_refs)
+            cs = tuple(co[...] for co in col_refs)
+        else:
+            start = pl.multiple_of(c * kc, kc)
+            rs = tuple(r[..., pl.ds(start, kc)] for r in row_refs)
+            cs = tuple(co[..., pl.ds(start, kc), :] for co in col_refs)
+        rs = rows(*rs) if rows is not None else rs
+        cs = cols(*cs) if cols is not None else cs
+        if pm_layout == "mkn":
+            rs = tuple(r[..., :, :, None] for r in rs)       # (bm, kc, 1)
+            cs = tuple(co[..., None, :, :] for co in cs)     # (1, kc, bn)
+            return body(rs, cs, -2, carry)
+        rs = tuple(r[..., :, None, :] for r in rs)           # (bm, 1, kc)
+        cs = tuple(jnp.swapaxes(co, -1, -2)[..., None, :, :]
+                   for co in cs)                             # (1, bn, kc)
+        return body(rs, cs, -1, carry)
 
     if nc == 1:
         return chunk(0, carry)

@@ -23,7 +23,7 @@ in the same pass, so the kernel is self-contained.
 
 The input block uses an ELEMENT-indexed BlockSpec trick: we pass a padded
 input whose block size equals ``bo`` but read across the boundary via
-``pl.load`` on an un-blocked (whole-array) ref -- on real TPU silicon this
+a ``pl.ds`` window of an un-blocked (whole-array) ref -- on real TPU silicon this
 block would be double-buffered by the pipeline; sizes here are
 filter-engine scale (n_taps <= a few hundred), so a whole-stream VMEM
 residency is realistic for DSP workloads the paper targets.
@@ -62,7 +62,7 @@ def sq_conv_kernel(x_ref, w_ref, out_ref, *, n_taps: int, bo: int, tb: int):
         # bookkeeping -- it cost more than the arithmetic under interpret
         # execution (the PR 1 sq_conv regression: 84.9us seed -> 118.9us;
         # this path measures ~24us at the tracked L=2048/16-tap shape).
-        xwin = pl.load(x_ref, (pl.ds(start, bo + n_taps - 1),))
+        xwin = x_ref[pl.ds(start, bo + n_taps - 1)]
         acc = jnp.full((bo,), sw, dtype=out_ref.dtype)
         for t in range(n_taps):
             xs = jax.lax.slice_in_dim(xwin, t, t + bo)
@@ -74,7 +74,7 @@ def sq_conv_kernel(x_ref, w_ref, out_ref, *, n_taps: int, bo: int, tb: int):
     def tap_block(c, acc):
         t0 = c * tb
         # One window load covers all tb shifted views of this chunk.
-        xwin = pl.load(x_ref, (pl.ds(start + t0, bo + tb - 1),))
+        xwin = x_ref[pl.ds(start + t0, bo + tb - 1)]
         wblk = jax.lax.dynamic_slice_in_dim(w, t0, tb)          # (tb,)
         xs = jnp.stack([jax.lax.slice_in_dim(xwin, t, t + bo)
                         for t in range(tb)])                    # (tb, bo)
@@ -112,7 +112,7 @@ def sq_conv_pallas(x, w, *, bo: int = 256, tb: int = 8,
         ],
         out_specs=pl.BlockSpec((bo,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((k_out,), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)

@@ -12,25 +12,27 @@ Dataflow (window streaming, implicit GEMM)
 ------------------------------------------
 Outputs are tiled over a 5D grid ``(batch, oh/bh, ow/bw, cout/bf,
 cin/bk)``; the input-channel axis is the grid minor ("arbitrary")
-reduction axis, exactly like ``sq_matmul``'s K axis.  One grid step:
+reduction axis, exactly like ``sq_matmul``'s K axis.  One grid step walks
+the ``kh*kw`` taps in a ``fori_loop``:
 
-- loads ONE input window of ``((bh-1)*sh + kh, (bw-1)*sv + kw, bk)``
-  covering every output pixel of the (bh, bw) tile -- each input element
-  reaches the step once, instead of being duplicated ``kh*kw`` times in
-  HBM;
-- forms the ``kh*kw`` shifted views of that single window with *static
-  (strided) slices* -- a register-level re-index -- and lays them side by
-  side as a (bh*bw, kh*kw*bk) operand slab: the tile-local im2col that
-  implicit-GEMM convolutions form in SRAM, never written back to HBM
-  and bounded by the tile size, not the image size;
-- routes the whole slab through ONE chunked block-PM contraction
-  (:func:`repro.kernels.sq_matmul.pm_block_accum` against the
-  (kh*kw*bk, bf) tap block: ``kc``-wide rank-2 broadcast squaring, both
-  ``"mkn"``/``"mnk"`` layouts, one homogeneous chunk loop), accumulating
-  into a VMEM scratch tile that is live across the whole channel walk;
-- folds the data-side correction (the slab's ``-x^2`` terms, shared by
-  all ``bf`` filters of the step) in one rank-2 pass -- O(M*K), not
-  O(M*K*N).
+- each tap reads its shifted (and, for sh/sv > 1, strided) view of the
+  tile's input window straight from the VMEM-resident input plane --
+  each input element reaches the step from VMEM, instead of being
+  duplicated ``kh*kw`` times in HBM;
+- the (bh*bw, bk) view (and the tap's (bk, bf) filter block) is staged
+  in a VMEM scratch slab -- the tile-local
+  im2col that implicit-GEMM convolutions form in SRAM, bounded by the
+  tile size, not the image size -- and routed through the chunked
+  block-PM contraction (:func:`repro.kernels.sq_matmul.pm_block_accum`)
+  against that tap's (bk, bf) filter block: ``kc``-wide rank-2 broadcast
+  squaring in either PM layout, accumulating into a VMEM scratch tile
+  that is live across the whole channel walk;
+- the data-side correction (the view's ``-x^2`` terms, shared by all
+  ``bf`` filters of the step) is folded in one rank-2 pass -- O(M*K),
+  not O(M*K*N).
+
+The taps loop rather than unroll: Mosaic gives every unrolled PM block
+its own VMEM allocation, and nine of them overrun the scoped VMEM.
 
 The accumulator is initialized with the per-filter kernel correction
 ``Sw_f = -sum_{c,i,j} w^2`` at the first channel step (the paper's
@@ -47,9 +49,8 @@ The input block keeps the full (padded) spatial plane of one batch
 element resident per step (windows of adjacent output tiles overlap, so
 spatial blocking would re-DMA the halos); at CNN-layer scales a
 channel-sliced plane slab is a few hundred KB and on real TPU silicon it
-is double-buffered by the pipeline.  Strided output (sh, sv > 1)
-subsamples the shifted views -- the window load itself stays dense, which
-is what keeps the tap walk a static re-index.
+is double-buffered by the pipeline.  Strided output (sh, sv > 1) reads
+strided views of the same resident plane.
 """
 from __future__ import annotations
 
@@ -65,14 +66,17 @@ from repro.kernels.sq_matmul import pm_block_accum
 __all__ = ["sq_conv2d_kernel", "sq_conv2d_pallas"]
 
 
-def sq_conv2d_kernel(x_ref, w_ref, sw_ref, out_ref, acc_ref, *, nc: int,
-                     kc: int, bh: int, bw: int, sh: int, sv: int,
-                     pm_layout: str, is_int: bool):
+def sq_conv2d_kernel(x_ref, w_ref, sw_ref, out_ref, acc_ref, slab_ref,
+                     wtap_ref, *, nc: int, kc: int, bh: int, bw: int,
+                     sh: int, sv: int, pm_layout: str, is_int: bool):
     """One (b, i, j, f, c) grid step of the fused 2D square-convolution.
 
     x_ref: (1, Hp, Wp, bk) this batch element's plane, channel-sliced;
     w_ref: (kh, kw, bk, bf) tap block; sw_ref: (1, bf) filter corrections;
-    out_ref: (1, bh, bw, bf); acc_ref: (bh*bw, bf) VMEM scratch.
+    out_ref: (1, bh, bw, bf); VMEM scratch acc_ref: (bh*bw, bf), and
+    slab_ref: (bh*bw, bk) / wtap_ref: (bk, bf), one tap's operands staged
+    as plain 2D refs so the chunk walk slices them with provably aligned
+    starts.
     """
     i = pl.program_id(1)                 # output-row tile
     j = pl.program_id(2)                 # output-col tile
@@ -84,34 +88,23 @@ def sq_conv2d_kernel(x_ref, w_ref, sw_ref, out_ref, acc_ref, *, nc: int,
     def _init():
         # Accumulator init = Sw_f (paper eq 14 Sw): the per-filter kernel
         # correction, broadcast to every output pixel of the tile.
-        acc_ref[...] = jnp.broadcast_to(sw_ref[0, :][None, :], (bm, bf))
+        acc_ref[...] = jnp.broadcast_to(sw_ref[...], (bm, bf))
 
-    # ONE window load covers all kh*kw shifted views of this tile.
-    ihb = (bh - 1) * sh + kh
-    iwb = (bw - 1) * sv + kw
-    xwin = pl.load(x_ref, (pl.ds(0, 1), pl.ds(i * (bh * sh), ihb),
-                           pl.ds(j * (bw * sv), iwb), slice(None)))[0]
+    def tap(t, acc):
+        di, dj = t // kw, t % kw
+        # This tap's shifted view of the tile's input window.
+        xs = x_ref[0, pl.ds(i * (bh * sh) + di, bh, sh),
+                   pl.ds(j * (bw * sv) + dj, bw, sv), :]   # (bh, bw, bk)
+        slab_ref[...] = xs.reshape(bm, bk)
+        wtap_ref[...] = w_ref[di, dj]
+        acc = pm_block_accum(acc, slab_ref, wtap_ref, kc=kc,
+                             pm_layout=pm_layout)
+        # Data-side correction (-x^2, paper eq 14 Sx): rank-2, shared by
+        # all bf filters of the step -- O(M*K), not O(M*K*N).
+        a = slab_ref[...]
+        return acc - jnp.sum(a * a, axis=1, keepdims=True)
 
-    # Tile-local operand slab: the kh*kw static (strided) shifted views of
-    # the shared window, laid out (bm, kh*kw*bk) tap-major to match the
-    # (kh, kw, bk, bf) -> (kh*kw*bk, bf) tap block.
-    views = []
-    for di in range(kh):
-        for dj in range(kw):
-            xs = jax.lax.slice(
-                xwin, (di, dj, 0),
-                (di + (bh - 1) * sh + 1, dj + (bw - 1) * sv + 1, bk),
-                (sh, sv, 1))                        # (bh, bw, bk)
-            views.append(xs.reshape(bm, bk))
-    a = views[0] if len(views) == 1 else jnp.concatenate(views, axis=1)
-
-    # One chunked block-PM contraction over the whole slab -- the same
-    # machinery and the same single homogeneous chunk loop as sq_matmul.
-    acc = pm_block_accum(acc_ref[...], a, w_ref[...].reshape(kh * kw * bk, bf),
-                         kc=kc, pm_layout=pm_layout)
-    # Data-side correction (-x^2, paper eq 14 Sx): rank-2, shared by all
-    # bf filters of the step -- O(M*K), not O(M*K*N).
-    acc_ref[...] = acc - jnp.sum(a * a, axis=1, keepdims=True)
+    acc_ref[...] = jax.lax.fori_loop(0, kh * kw, tap, acc_ref[...])
 
     @pl.when(c == nc - 1)
     def _finalize():
@@ -134,8 +127,8 @@ def sq_conv2d_pallas(x, w, sw, *, ohp: int, owp: int, bh: int, bw: int,
     taps-major, sw (1, Np) per-filter ``-sum w^2`` corrections.  ``ohp`` /
     ``owp`` are the padded output extents (multiples of bh/bw); the padded
     input must cover every window: ``Hp >= (ohp-1)*sh + kh``.  ``kc``
-    chunks the *flattened* (kh*kw*bk)-wide per-step reduction axis and
-    must divide it (defaults to one unrolled chunk).
+    chunks each tap's bk-wide channel reduction and must divide ``bk``
+    (defaults to one chunk).
     """
     nb, Hp, Wp, Cp = x.shape
     kh, kw, Cp2, Np = w.shape
@@ -145,9 +138,8 @@ def sq_conv2d_pallas(x, w, sw, *, ohp: int, owp: int, bh: int, bw: int,
     assert Cp % bk == 0 and Np % bf == 0, (Cp, Np, bk, bf)
     assert Hp >= (ohp - 1) * sh + kh and Wp >= (owp - 1) * sv + kw, \
         (Hp, Wp, ohp, owp, stride, kh, kw)
-    ktot = kh * kw * bk
-    kc = ktot if kc is None else kc
-    assert ktot % kc == 0, (kh, kw, bk, kc)
+    kc = bk if kc is None else kc
+    assert bk % kc == 0, (bk, kc)
     nc = Cp // bk
     is_int = jnp.issubdtype(x.dtype, jnp.integer)
 
@@ -166,8 +158,10 @@ def sq_conv2d_pallas(x, w, sw, *, ohp: int, owp: int, bh: int, bw: int,
         out_specs=pl.BlockSpec((1, bh, bw, bf),
                                lambda b, i, j, f, c: (b, i, j, f)),
         out_shape=jax.ShapeDtypeStruct((nb, ohp, owp, Np), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bh * bw, bf), x.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
+        scratch_shapes=[pltpu.VMEM((bh * bw, bf), x.dtype),
+                        pltpu.VMEM((bh * bw, bk), x.dtype),
+                        pltpu.VMEM((bk, bf), x.dtype)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,

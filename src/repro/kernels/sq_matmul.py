@@ -37,8 +37,8 @@ Two PM-block layouts are compiled, selected by the static ``pm_layout``:
     on the 128-lane minor axis, so Mosaic keeps native vreg layouts -- the
     TPU-native schedule.
 ``"mnk"``
-    ``b`` is transposed once per grid step and the block is (bm, bn, kc),
-    reduced over the *minor* axis.  Minor-axis reduction fuses into a
+    Each ``b`` chunk is transposed and the block is (bm, bn, kc), reduced
+    over the *minor* axis.  Minor-axis reduction fuses into a
     dot-product-shaped loop nest, which is what CPU interpret mode (and
     the XLA CPU backend generally) executes fastest -- ~6x over the seed
     rank-1 kernel at 128^3 f32.
@@ -47,8 +47,11 @@ Both are the same arithmetic (one operand add + one square per PM term);
 the planner in :mod:`repro.kernels.tuning` picks ``(bm, bn, bk, kc)`` and
 the layout per call site (cost-model ranked, optionally autotuned).
 
-The grid is marked ``dimension_semantics=("parallel", "parallel",
-"arbitrary")``: M/N tiles carry no cross-step state (the scratch
+One kernel serves the plain, batched and batch-folded forms: its refs
+carry ``fb`` batch elements on a leading axis (``fb == 1`` for a plain or
+one-element-per-step batched call), and the PM machinery broadcasts over
+it.  The grid is marked ``dimension_semantics=("parallel", "parallel",
+"parallel", "arbitrary")``: batch/M/N tiles carry no cross-step state (the scratch
 accumulator is only live along K), so Mosaic may pipeline and reorder
 them freely; only the K axis is sequential.
 
@@ -68,130 +71,50 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pm_blocks import PM_LAYOUTS, pm_chunked_reduce
 
-__all__ = ["sq_matmul_kernel", "sq_matmul_pallas", "sq_matmul_batched_kernel",
-           "sq_matmul_batched_pallas", "sq_matmul_folded_kernel",
-           "pm_block_accum", "pm_block_accum_folded", "PM_LAYOUTS"]
+__all__ = ["sq_matmul_kernel", "sq_matmul_pallas", "sq_matmul_batched_pallas",
+           "pm_block_accum", "PM_LAYOUTS"]
 
 
-def pm_block_accum(acc, a, b, *, kc: int, pm_layout: str):
+def pm_block_accum(acc, a_ref, b_ref, *, kc: int, pm_layout: str):
     """Chunked block PM accumulation: ``acc + sum_k (a[i,k] + b[k,j])^2``.
 
-    a: (bm, bk) and b: (bk, bn) *values* (already loaded from VMEM refs),
-    pre-widened to the accumulator dtype; acc: the carried (bm, bn)
-    accumulator plane.  The K slab is processed in ``kc``-wide chunks via
-    the shared machinery in kernels.pm_blocks.
+    a_ref: (..., bm, bk) and b_ref: (..., bk, bn) VMEM refs holding values
+    pre-widened to the accumulator dtype; acc: the carried (..., bm, bn)
+    accumulator.  The K slab is processed in ``kc``-wide chunks via the
+    shared machinery in kernels.pm_blocks.
     """
     def body(rs, cs, axis, acc):
         s = rs[0] + cs[0]                    # PE operand adders
         return acc + jnp.sum(s * s, axis)    # squarers + block reduction
 
-    return pm_chunked_reduce(acc, (a,), (b,), kc=kc, pm_layout=pm_layout,
-                             body=body)
+    return pm_chunked_reduce(acc, (a_ref,), (b_ref,), kc=kc,
+                             pm_layout=pm_layout, body=body)
 
 
 def sq_matmul_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref, *,
                      nk: int, kc: int, pm_layout: str, is_int: bool):
-    """One (i, j, k) grid step of the chunked square-based matmul."""
-    k_step = pl.program_id(2)
+    """One (batch-block, i, j, k) grid step of the chunked square matmul.
+
+    a_ref: (fb, bm, bk); b_ref: (fb, bk, bn); sa_ref: (fb, bm, 1);
+    sb_ref: (fb, 1, bn); out_ref and the scratch acc_ref: (fb, bm, bn).
+    ``fb > 1`` folds a block of batch elements into one grid step --
+    ``fb * bm`` rows' worth of PM work amortizes one step's issue overhead
+    (the small-(M, N), large-B regime of kernels.routing).
+    """
+    k_step = pl.program_id(3)
 
     @pl.when(k_step == 0)
     def _init():
         # Accumulator init = Sa_i + Sb_j (paper Fig.1b: "initialise its
         # register first with Sa_i + Sb_j").
-        acc_ref[...] = sa_ref[:, 0][:, None] + sb_ref[0, :][None, :]
+        acc_ref[...] = sa_ref[...] + sb_ref[...]
 
-    acc_ref[...] = pm_block_accum(acc_ref[...], a_ref[...], b_ref[...],
-                                  kc=kc, pm_layout=pm_layout)
+    acc_ref[...] = pm_block_accum(acc_ref[...], a_ref, b_ref, kc=kc,
+                                  pm_layout=pm_layout)
 
     @pl.when(k_step == nk - 1)
     def _finalize():
         # The paper's final right shift: 2*c_ij -> c_ij.
-        acc = acc_ref[...]
-        if is_int:
-            out_ref[...] = jax.lax.shift_right_arithmetic(
-                acc, jnp.ones_like(acc))
-        else:
-            out_ref[...] = acc * 0.5
-
-
-def sq_matmul_batched_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref,
-                             *, nk: int, kc: int, pm_layout: str,
-                             is_int: bool):
-    """One (batch, i, j, k) grid step of the batched square-based matmul.
-
-    Identical arithmetic to :func:`sq_matmul_kernel`; the refs carry a
-    leading singleton batch-block axis (one batch element per grid step)
-    that is squeezed before the shared PM-block machinery runs.
-    """
-    k_step = pl.program_id(3)
-
-    @pl.when(k_step == 0)
-    def _init():
-        acc_ref[...] = sa_ref[0, :, 0][:, None] + sb_ref[0, 0, :][None, :]
-
-    acc_ref[...] = pm_block_accum(acc_ref[...], a_ref[0], b_ref[0],
-                                  kc=kc, pm_layout=pm_layout)
-
-    @pl.when(k_step == nk - 1)
-    def _finalize():
-        acc = acc_ref[...]
-        if is_int:
-            out_ref[...] = jax.lax.shift_right_arithmetic(
-                acc, jnp.ones_like(acc))[None]
-        else:
-            out_ref[...] = (acc * 0.5)[None]
-
-
-def pm_block_accum_folded(acc, a, b, *, kc: int, pm_layout: str):
-    """Batch-folded chunked PM accumulation.
-
-    a: (fb, bm, bk), b: (fb, bk, bn) values pre-widened to the accumulator
-    dtype; acc: the carried (fb, bm, bn) accumulator.  The ``fb`` batch
-    elements of one grid step are contracted in a single rank-4 broadcast
-    pass per chunk -- "folding batch into the M tile": ``fb * bm`` rows'
-    worth of PM work amortizes one grid step's issue overhead (the
-    small-(M, N), large-B regime of kernels.routing).
-    """
-    bk = a.shape[-1]
-    nc = bk // kc
-    if pm_layout == "mnk":
-        bt = jnp.swapaxes(b, 1, 2)                    # (fb, bn, bk)
-
-        def chunk(c, acc):
-            ab = jax.lax.dynamic_slice_in_dim(a, c * kc, kc, 2)
-            cb = jax.lax.dynamic_slice_in_dim(bt, c * kc, kc, 2)
-            s = ab[:, :, None, :] + cb[:, None, :, :]  # (fb, bm, bn, kc)
-            return acc + jnp.sum(s * s, axis=-1)
-    elif pm_layout == "mkn":
-        def chunk(c, acc):
-            ab = jax.lax.dynamic_slice_in_dim(a, c * kc, kc, 2)
-            cb = jax.lax.dynamic_slice_in_dim(b, c * kc, kc, 1)
-            s = ab[:, :, :, None] + cb[:, None, :, :]  # (fb, bm, kc, bn)
-            return acc + jnp.sum(s * s, axis=2)
-    else:
-        raise ValueError(f"unknown pm_layout {pm_layout!r}; expected one "
-                         f"of {PM_LAYOUTS}")
-    if nc == 1:
-        return chunk(0, acc)
-    return jax.lax.fori_loop(0, nc, chunk, acc)
-
-
-def sq_matmul_folded_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref,
-                            *, nk: int, kc: int, pm_layout: str,
-                            is_int: bool):
-    """One (batch-block, i, j, k) grid step with ``fb`` batch elements
-    folded into the row tile (see :func:`pm_block_accum_folded`)."""
-    k_step = pl.program_id(3)
-
-    @pl.when(k_step == 0)
-    def _init():
-        acc_ref[...] = sa_ref[...] + sb_ref[...]      # (fb,bm,1)+(fb,1,bn)
-
-    acc_ref[...] = pm_block_accum_folded(acc_ref[...], a_ref[...], b_ref[...],
-                                         kc=kc, pm_layout=pm_layout)
-
-    @pl.when(k_step == nk - 1)
-    def _finalize():
         acc = acc_ref[...]
         if is_int:
             out_ref[...] = jax.lax.shift_right_arithmetic(
@@ -206,12 +129,11 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
                              interpret: bool = False):
     """Batched pallas_call wrapper: a (B, m, k), b (B, k, n), corrections
     sa (B, m, 1) / sb (B, 1, n).  ``fb`` batch elements per grid step on
-    the (new, outermost) batch grid axis -- batched GEMMs run natively
-    instead of collapsing to rows or falling back.  ``fb == 1`` is the
-    one-element-per-step schedule; ``fb > 1`` folds a batch block into the
-    row tile (:func:`sq_matmul_folded_kernel`; B must be an fb multiple --
-    the ops wrapper zero-pads, and zero batch elements are exact no-ops).
-    Operands pre-widened/padded as in :func:`sq_matmul_pallas`."""
+    the outermost batch grid axis; B must be an fb multiple (the ops
+    wrapper zero-pads, and zero batch elements are exact no-ops).
+    Operands must be pre-widened to the accumulator dtype and pre-padded
+    to tile multiples (see kernels.ops).  ``kc`` must divide ``bk``
+    (defaults to ``bk``: one chunk)."""
     nb, m, k = a.shape
     nb2, k2, n = b.shape
     assert nb == nb2 and k == k2
@@ -223,14 +145,8 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
     nk = k // bk
     is_int = jnp.issubdtype(a.dtype, jnp.integer)
 
-    if fb > 1:
-        kernel = functools.partial(sq_matmul_folded_kernel, nk=nk, kc=kc,
-                                   pm_layout=pm_layout, is_int=is_int)
-        scratch = pltpu.VMEM((fb, bm, bn), a.dtype)
-    else:
-        kernel = functools.partial(sq_matmul_batched_kernel, nk=nk, kc=kc,
-                                   pm_layout=pm_layout, is_int=is_int)
-        scratch = pltpu.VMEM((bm, bn), a.dtype)
+    kernel = functools.partial(sq_matmul_kernel, nk=nk, kc=kc,
+                               pm_layout=pm_layout, is_int=is_int)
     return pl.pallas_call(
         kernel,
         grid=(nb // fb, m // bm, n // bn, nk),
@@ -242,8 +158,8 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
         ],
         out_specs=pl.BlockSpec((fb, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), a.dtype),
-        scratch_shapes=[scratch],
-        compiler_params=pltpu.TPUCompilerParams(
+        scratch_shapes=[pltpu.VMEM((fb, bm, bn), a.dtype)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -253,33 +169,9 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
 def sq_matmul_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
                      bk: int = 128, kc: int | None = None,
                      pm_layout: str = "mkn", interpret: bool = False):
-    """Raw pallas_call wrapper.  Operands must be pre-widened to the
-    accumulator dtype and pre-padded to tile multiples (see kernels.ops).
-    ``kc`` must divide ``bk`` (defaults to ``bk``: one unrolled chunk)."""
-    m, k = a.shape
-    k2, n = b.shape
-    assert k == k2 and sa.shape == (m, 1) and sb.shape == (1, n)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
-    kc = bk if kc is None else kc
-    assert bk % kc == 0, (bk, kc)
-    nk = k // bk
-    is_int = jnp.issubdtype(a.dtype, jnp.integer)
-
-    kernel = functools.partial(sq_matmul_kernel, nk=nk, kc=kc,
-                               pm_layout=pm_layout, is_int=is_int)
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm, n // bn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), a.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(a, b, sa, sb)
+    """Plain (m, k) x (k, n) wrapper: the batched kernel at one element,
+    with sa (m, 1) / sb (1, n).  Same operand contract as
+    :func:`sq_matmul_batched_pallas`."""
+    return sq_matmul_batched_pallas(
+        a[None], b[None], sa[None], sb[None], bm=bm, bn=bn, bk=bk, kc=kc,
+        pm_layout=pm_layout, interpret=interpret)[0]

@@ -20,13 +20,22 @@ scalar-prefetch operand, so the K/V/position BlockSpec index maps read
 directly; a NULL table entry (0) fetches the reserved null block, whose
 ``pos_pool`` entries hold the EMPTY sentinel and mask to nothing.
 
+Every block obeys Mosaic's rule that the two minor block dims are (8,
+128)-divisible or span the whole array dim.  The wrapper therefore lays
+the operands out head-major: queries as (B, KV, S*G, hd) with one
+position per query row, the pool as (nblk, KV, hd, bs) keys (already
+transposed for the score contraction) and (nblk, KV, bs, hd) values,
+positions as (nblk, 1, bs) -- so each grid step sees whole (S*G, hd),
+(hd, bs) and (bs, hd) tiles.  ``hd = 120`` is legal as a whole dim.
+
 Per grid step, both contractions run through the shared square-PM
 machinery (:func:`repro.kernels.sq_matmul.pm_block_accum`):
 
 - **scores**: ``2 * (q @ k^T)`` accumulated as ``sum_h (q + k)^2`` with
   the rank-2 corrections ``-sum q^2`` / ``-sum k^2`` as the accumulator
   init (paper Fig.1b), then the paper's final halving;
-- **PV**: ``2 * (p @ v)`` the same way over the block's token axis.
+- **PV**: ``2 * (p @ v)`` the same way over the block's token axis, with
+  ``p`` staged in VMEM scratch so its chunks are ref slices.
 
 An online-softmax carry (running max ``m``, normalizer ``l``, and the
 output accumulator -- flash-attention's recurrence) lives in VMEM scratch
@@ -59,19 +68,21 @@ __all__ = ["sq_paged_attn", "sq_paged_attn_kernel"]
 NEG_INF = -1e30
 
 
-def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, k_ref, v_ref, kpos_ref,
-                         out_ref, m_ref, l_ref, acc_ref, *, nb: int,
-                         kc_qk: int, kc_pv: int, pm_layout: str,
+def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, kt_ref, v_ref,
+                         kpos_ref, out_ref, m_ref, l_ref, acc_ref, p_ref, *,
+                         nb: int, kc_qk: int, kc_pv: int, pm_layout: str,
                          window: Optional[int], softcap: float,
                          attend_limit: int):
     """One (sequence, kv-head, block) grid step.
 
-    ``q_ref``: (1, S, 1, G, hd) queries (pre-scaled by ``hd**-0.5``);
-    ``k_ref``/``v_ref``: the (1, bs, 1, hd) pool block the scalar-prefetch
-    index map resolved for this table column; ``kpos_ref``: (1, bs) its
-    absolute positions; ``qpos_ref``: (1, S) query positions (-1 padding).
-    Scratch: running max/normalizer (S*G, 1) and output accumulator
-    (S*G, hd), carried across the sequential block axis.
+    ``q_ref``: (1, 1, rows, hd) queries of this head's group, rows ordered
+    (query, group) and pre-scaled by ``hd**-0.5``; ``qpos_ref``: (1, rows,
+    1) their positions (-1 padding); ``kt_ref``: (1, 1, hd, bs) and
+    ``v_ref``: (1, 1, bs, hd) the pool block the scalar-prefetch index
+    map resolved for this table column; ``kpos_ref``: (1, 1, bs) its
+    absolute positions.  Scratch: running max/normalizer (rows, 1),
+    output accumulator (rows, hd) and the probability tile (rows, bs),
+    carried across the sequential block axis.
     """
     del tables_ref                    # consumed by the BlockSpec index maps
     b = pl.program_id(2)
@@ -82,28 +93,23 @@ def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, k_ref, v_ref, kpos_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    S, G, hd = q_ref.shape[1], q_ref.shape[3], q_ref.shape[4]
-    bs = k_ref.shape[1]
-    rows = S * G
-
-    qr = q_ref[0, :, 0, :, :].reshape(rows, hd)
-    kb = k_ref[0, :, 0, :]                               # (bs, hd)
-    vb = v_ref[0, :, 0, :]                               # (bs, hd)
+    q, kt, v = q_ref.at[0, 0], kt_ref.at[0, 0], v_ref.at[0, 0]
 
     # -- scores: 2 * (q @ k^T) via the PM identity, corrections in-kernel.
     # acc init = -sum q^2 - sum k^2 (the Fig.1b register preload), each
     # K step adds (q + k)^2, the end applies the paper's right shift.
-    sq_row = -jnp.sum(qr * qr, axis=1, keepdims=True)    # (rows, 1)
-    sk_col = -jnp.sum(kb * kb, axis=1)[None, :]          # (1, bs)
-    s = 0.5 * pm_block_accum(sq_row + sk_col, qr, kb.T,
-                             kc=kc_qk, pm_layout=pm_layout)
+    qv, ktv = q[...], kt[...]
+    sq_row = -jnp.sum(qv * qv, axis=1, keepdims=True)    # (rows, 1)
+    sk_col = -jnp.sum(ktv * ktv, axis=0, keepdims=True)  # (1, bs)
+    s = 0.5 * pm_block_accum(sq_row + sk_col, q, kt, kc=kc_qk,
+                             pm_layout=pm_layout)
     if softcap and softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
     # -- absolute-position mask from the pos_pool block (causal + sentinel
-    # + optional sliding window), broadcast over the G query groups.
-    qp = jnp.broadcast_to(qpos_ref[0, :][:, None], (S, G)).reshape(rows, 1)
-    kp = kpos_ref[0, :][None, :]                         # (1, bs)
+    # + optional sliding window).
+    qp = qpos_ref[0]                                     # (rows, 1)
+    kp = kpos_ref[0]                                     # (1, bs)
     mask = (kp < attend_limit) & (kp <= qp)
     if window is not None:
         mask &= (qp - kp) < window
@@ -119,16 +125,18 @@ def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, k_ref, v_ref, kpos_ref,
 
     # -- PV: 2 * (p @ v) through the same PM machinery, over the block's
     # token axis.
+    p_ref[...] = p
+    vv = v[...]
     sp_row = -jnp.sum(p * p, axis=1, keepdims=True)      # (rows, 1)
-    sv_col = -jnp.sum(vb * vb, axis=0)[None, :]          # (1, hd)
-    pv = 0.5 * pm_block_accum(sp_row + sv_col, p, vb,
-                              kc=kc_pv, pm_layout=pm_layout)
+    sv_col = -jnp.sum(vv * vv, axis=0, keepdims=True)    # (1, hd)
+    pv = 0.5 * pm_block_accum(sp_row + sv_col, p_ref, v, kc=kc_pv,
+                              pm_layout=pm_layout)
     acc_ref[...] = acc_ref[...] * corr + pv
 
     @pl.when(b == nb - 1)
     def _finalize():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        out_ref[...] = out.reshape(1, S, 1, G, hd)
+        out_ref[...] = out[None, None]
 
 
 def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
@@ -177,11 +185,15 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
                          f"{block_size}")
 
     f32 = jnp.float32
-    qf = q.astype(f32)
-    kr = k_pool.astype(f32).reshape(num_blocks, block_size, KV, hd)
+    rows = S * G
+    # head-major layouts (module docstring): whole minor dims per block
+    qf = q.astype(f32).transpose(0, 2, 1, 3, 4).reshape(B, KV, rows, hd)
+    qpos = jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)[:, :, None]
+    kt = k_pool.astype(f32).reshape(num_blocks, block_size, KV, hd)
+    kt = kt.transpose(0, 2, 3, 1)                        # (nblk, KV, hd, bs)
     vr = v_pool.astype(f32).reshape(num_blocks, block_size, KV, hd)
-    posr = pos_pool.astype(jnp.int32).reshape(num_blocks, block_size)
-    qpos = q_pos.astype(jnp.int32)
+    vr = vr.transpose(0, 2, 1, 3)                        # (nblk, KV, bs, hd)
+    posr = pos_pool.astype(jnp.int32).reshape(num_blocks, 1, block_size)
 
     kernel = functools.partial(
         sq_paged_attn_kernel, nb=nb, kc_qk=kc_qk, kc_pv=kc_pv,
@@ -191,28 +203,30 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
         num_scalar_prefetch=1,
         grid=(B, KV, nb),
         in_specs=[
-            pl.BlockSpec((1, S, 1, G, hd),
-                         lambda i, kv, b, t: (i, 0, kv, 0, 0)),
-            pl.BlockSpec((1, S), lambda i, kv, b, t: (i, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda i, kv, b, t: (t[i, b], 0, kv, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda i, kv, b, t: (t[i, b], 0, kv, 0)),
-            pl.BlockSpec((1, block_size), lambda i, kv, b, t: (t[i, b], 0)),
+            pl.BlockSpec((1, 1, rows, hd), lambda i, kv, b, t: (i, kv, 0, 0)),
+            pl.BlockSpec((1, rows, 1), lambda i, kv, b, t: (i, 0, 0)),
+            pl.BlockSpec((1, 1, hd, block_size),
+                         lambda i, kv, b, t: (t[i, b], kv, 0, 0)),
+            pl.BlockSpec((1, 1, block_size, hd),
+                         lambda i, kv, b, t: (t[i, b], kv, 0, 0)),
+            pl.BlockSpec((1, 1, block_size),
+                         lambda i, kv, b, t: (t[i, b], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, S, 1, G, hd),
-                               lambda i, kv, b, t: (i, 0, kv, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rows, hd),
+                               lambda i, kv, b, t: (i, kv, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((S * G, 1), f32),       # running max
-            pltpu.VMEM((S * G, 1), f32),       # running normalizer
-            pltpu.VMEM((S * G, hd), f32),      # output accumulator
+            pltpu.VMEM((rows, 1), f32),           # running max
+            pltpu.VMEM((rows, 1), f32),           # running normalizer
+            pltpu.VMEM((rows, hd), f32),          # output accumulator
+            pltpu.VMEM((rows, block_size), f32),  # probability tile
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, G, hd), f32),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), f32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables.astype(jnp.int32), qf, qpos, kr, vr, posr)
+    )(tables.astype(jnp.int32), qf, qpos, kt, vr, posr)
+    return out.reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4)

@@ -7,7 +7,10 @@ Picks the ``(bm, bn, bk, kc)`` block plan for every kernel call site:
   operand is large enough to allow it);
 - ``bk`` is the K-slab streamed per grid step;
 - ``kc`` is the chunk width of the rank-2 broadcast squaring inside a step
-  (the live PM intermediate is (bm, kc, bn)).
+  (the live PM intermediate is (bm, kc, bn)).  A TPU ("mkn") plan chunks
+  in whole 128-lane groups or takes the slab in one chunk: Mosaic only
+  lowers a dynamic chunk start on the lane axis when it is a multiple of
+  128 (see kernels.pm_blocks).
 
 Two modes:
 
@@ -152,6 +155,16 @@ def _align_kc(kc: int, bk: int) -> int:
     return kc
 
 
+def _kc_ladder(bk: int, pm_layout: str) -> list[int]:
+    """The chunk widths a plan may take for a ``bk``-wide slab: every
+    :data:`KC_CANDIDATES` width (aligned to divide ``bk``) for "mnk";
+    whole lane groups dividing ``bk``, or the whole slab, for "mkn"."""
+    if pm_layout == "mkn":
+        return sorted({bk} | {c for c in range(LANE, bk, LANE)
+                              if bk % c == 0})
+    return sorted({_align_kc(c, bk) for c in KC_CANDIDATES})
+
+
 def candidate_plans(m: int, n: int, k: int,
                     *, itemsize: int = 4, n_row_ops: int = 1,
                     n_col_ops: int = 1, n_acc: int = 1,
@@ -182,7 +195,7 @@ def candidate_plans(m: int, n: int, k: int,
     for bm in bms:
         for bn in bns:
             for bk in bks:
-                for kc in sorted({_align_kc(c, bk) for c in KC_CANDIDATES}):
+                for kc in _kc_ladder(bk, pm_layout):
                     if pm_layout == "mnk" and kc > 1 and (
                             kc > KC_MNK_MAX or
                             (bn + SUBLANE) * kc * itemsize > CACHE_BUDGET):
@@ -229,24 +242,33 @@ def candidate_conv2d_plans(oh: int, ow: int, kh: int, kw: int, cin: int,
 
     Spatial tiles include the exact (oh, ow) extents (a full-plane tile
     has zero padding waste and maximal window reuse); channel/filter
-    tiles follow the matmul K/N candidate ladders.  ``kc`` chunks the
-    flattened (kh*kw*bk) per-step reduction axis; "mnk" plans cap it at
-    :data:`KC_MNK_MAX` like the matmul planner.
+    tiles follow the matmul K/N candidate ladders.  ``kc`` chunks each
+    tap's bk-wide channel reduction; "mnk" plans cap it at
+    :data:`KC_MNK_MAX` like the matmul planner.  "mkn" (TPU) plans keep
+    the Mosaic block rules: ``bw`` a sublane multiple (the kernel folds
+    (bh, bw) into rows), and ``bk`` the whole channel extent or one
+    128-lane group (Mosaic refuses a tap view at an unaligned dynamic
+    sublane offset that spans more lane groups), taken in one chunk.
     """
     sh, sv = stride
     bhs = sorted({max(1, min(c, oh)) for c in (4, 8, 16, 32)} | {oh})
-    bws = sorted({_divisor_near(c, ow) for c in (8, 16, 32, 64, 128)} | {ow})
-    bks = sorted({max(1, min(c, cin)) for c in (8, 32, 64, 128)} | {cin})
+    if pm_layout == "mkn":
+        bws = sorted({min(c, _round_up(ow, SUBLANE))
+                      for c in (8, 16, 32, 64, 128)})
+        bks = [min(cin, LANE)]
+    else:
+        bws = sorted({_divisor_near(c, ow) for c in (8, 16, 32, 64, 128)}
+                     | {ow})
+        bks = sorted({max(1, min(c, cin)) for c in (8, 32, 64, 128)}
+                     | {cin})
     bfs = sorted({_align_lane(c, cout) for c in (64, 128)}
                  | {max(1, min(cout, 256))})
     plans = []
     for bh in bhs:
         for bw in bws:
             for bk in bks:
-                ktot = kh * kw * bk
                 for bf in bfs:
-                    for kc in sorted({_align_kc(c, ktot)
-                                      for c in KC_CANDIDATES}):
+                    for kc in _kc_ladder(bk, pm_layout):
                         if pm_layout == "mnk" and kc > 1 and (
                                 kc > KC_MNK_MAX or
                                 bh * bw * bf * kc * itemsize
@@ -261,8 +283,8 @@ def candidate_conv2d_plans(oh: int, ow: int, kh: int, kw: int, cin: int,
     if not plans:      # degenerate shapes: one minimal feasible plan
         bk = max(1, min(8, cin))
         plans = [Conv2DPlan(max(1, min(4, oh)), max(1, min(8, ow)), bk,
-                            _align_kc(8, kh * kw * bk),
-                            max(1, min(cout, 64)), pm_layout)]
+                            _align_kc(8, bk), max(1, min(cout, 64)),
+                            pm_layout)]
     return plans
 
 
@@ -513,7 +535,7 @@ def plan_conv2d(h: int, w: int, kh: int, kw: int, cin: int, cout: int,
     silences (see :func:`autotune_enabled`).
 
     Fully-specified plans skip cache and model entirely (``kc`` is still
-    clamped to divide the flattened ``kh*kw*bk`` reduction axis)::
+    clamped to divide the per-tap ``bk`` reduction axis)::
 
         >>> from repro.kernels import tuning
         >>> tuning.plan_conv2d(34, 34, 3, 3, 64, 64, bh=16, bw=32, bk=64,
@@ -529,9 +551,8 @@ def plan_conv2d(h: int, w: int, kh: int, kw: int, cin: int, cout: int,
     explicit = (bh, bw, bk, bf)
     if all(v is not None for v in explicit):
         pbk = max(1, min(bk, cin))
-        ktot = kh * kw * pbk
         return Conv2DPlan(max(1, min(bh, oh)), max(1, min(bw, ow)), pbk,
-                          _align_kc(kc if kc is not None else ktot, ktot),
+                          _align_kc(kc if kc is not None else pbk, pbk),
                           max(1, min(bf, cout)), pm_layout)
     itemsize = jnp.dtype(dtype).itemsize
     use_cache = autotune_enabled()
@@ -547,10 +568,13 @@ def plan_conv2d(h: int, w: int, kh: int, kw: int, cin: int, cout: int,
     base = _model_pick_conv2d(oh, ow, kh, kw, cin, cout, stride=(sh, sv),
                               itemsize=itemsize, pm_layout=pm_layout)
     pbh = max(1, min(bh if bh is not None else base.bh, oh))
-    pbw = max(1, min(bw if bw is not None else base.bw, ow))
+    # an "mkn" tile may overhang ow up to the sublane granule (the wrapper
+    # pads the output grid); clamping it to ow would break that granule
+    ow_cap = _round_up(ow, SUBLANE) if pm_layout == "mkn" else ow
+    pbw = max(1, min(bw if bw is not None else base.bw, ow_cap))
     pbk = max(1, min(bk if bk is not None else base.bk, cin))
     pbf = max(1, min(bf if bf is not None else base.bf, cout))
-    pkc = _align_kc(kc if kc is not None else base.kc, kh * kw * pbk)
+    pkc = _align_kc(kc if kc is not None else base.kc, pbk)
     plan = Conv2DPlan(pbh, pbw, pbk, pkc, pbf, pm_layout)
     if use_cache and cached is None and no_user:
         _note_cache_lookup(key, hit=False)

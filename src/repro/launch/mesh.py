@@ -1,13 +1,24 @@
-"""Production mesh builders.
+"""Mesh builders.
 
 Functions (not module-level constants) so importing this module never
-touches jax device state.
+touches jax device state.  Every mesh is built with ``Auto`` axis types:
+the model code places its arrays through sharding annotations and lets
+the partitioner propagate them, which explicitly typed axes refuse
+(e.g. the embedding gather raises ``ShardingTypeError``).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,10 +29,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever devices exist locally, as a 1D data mesh (smoke tests)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((len(jax.devices()),), ("data",))

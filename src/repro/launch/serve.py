@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import SQUARE_GEMMS_POLICY
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.blocks import PAGEABLE_KINDS
 from repro.models.lm import build_model
 from repro.obs import trace as obs_trace
@@ -108,6 +109,7 @@ def main(argv=None):
                          "trace_event JSON (load in Perfetto / "
                          "chrome://tracing)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs_trace.enable()
@@ -195,6 +197,7 @@ def main(argv=None):
                 json.dump(snap, f, indent=1, sort_keys=True)
             print(f"  metrics snapshot -> {args.metrics_file}")
         results = {rid: r.tokens for rid, r in eresults.items()}
+        unfinished = sorted(rid for rid, r in eresults.items() if not r.ok)
     if legacy and args.metrics_file:
         print("note: --metrics-file needs the paged engine's registry; "
               "ignored under --legacy")
@@ -206,6 +209,11 @@ def main(argv=None):
     for rid in sorted(results)[:4]:
         print(f"  req {rid}: {results[rid][:8]}...")
     assert len(results) == args.requests
+    if not legacy and unfinished:
+        raise SystemExit(f"{len(unfinished)} request(s) did not complete: "
+                         + "; ".join(f"{rid}: {eresults[rid].status} "
+                                     f"{eresults[rid].error or ''}".strip()
+                                     for rid in unfinished))
     return results
 
 

@@ -3,10 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch fairsquare-demo \
         --steps 200 --global-batch 8 --seq 256 --ckpt-dir /tmp/fs_ckpt
 
-Auto-resumes from the newest checkpoint in --ckpt-dir.  On a real fleet this
-binary runs once per host under the cluster scheduler; jax.distributed
-initialization and the production mesh activate when more than one device is
-visible (the mesh/sharding code is identical to the dry-run's).
+Auto-resumes from the newest checkpoint in --ckpt-dir.  The run is one
+process on one device: it builds no mesh and shards nothing, whatever the
+number of visible devices (``launch/dryrun.py`` compiles the sharded
+production layout, but no training run uses it yet).
 """
 from __future__ import annotations
 
@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import build_model
 from repro.obs import trace as obs_trace
 from repro.obs.export import write_chrome_trace
@@ -49,6 +50,7 @@ def main(argv=None):
                     help="enable structured tracing and write a Chrome "
                          "trace_event JSON (Perfetto-loadable)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs_trace.enable()

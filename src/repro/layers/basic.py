@@ -73,7 +73,6 @@ def dense_tp_reduce(p, x, *, mode: Optional[str] = None, out_dtype=None,
     # contracts the raw weight (each shard computes its local corrections).
     w = fsp.unwrap(w)
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dsize = int(np.prod([mesh.shape[a] for a in data_axes])) if data_axes else 1
@@ -92,8 +91,8 @@ def dense_tp_reduce(p, x, *, mode: Optional[str] = None, out_dtype=None,
         part = jax.lax.psum(part, axis)
         return part.reshape(*xl.shape[:-1], wl.shape[-1])
 
-    out = shard_map(body, mesh=mesh, in_specs=(P(axis, None), in_x),
-                    out_specs=out_s, check_rep=False)(w, x)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(P(axis, None), in_x),
+                        out_specs=out_s, check_vma=False)(w, x)
     if "b" in p:
         out = out + p["b"].astype(out.dtype)
     if out_dtype is not None:

@@ -80,7 +80,6 @@ def _apply_moe(p, x, cfg, mode, policy=None):
     else:
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         dsize = int(np.prod([mesh.shape[a] for a in data_axes])) if data_axes else 1
         if (B * S) % max(1, dsize) != 0:
@@ -102,11 +101,11 @@ def _apply_moe(p, x, cfg, mode, policy=None):
             "w_down": {"w": P(None, model_ax, None)},
         }
         tok_spec = P(data_axes, None) if data_axes else P(None, None)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, tok_spec),
             out_specs=(tok_spec, P()),
-            check_rep=False)(p, xt)
+            check_vma=False)(p, xt)
     return out.reshape(B, S, D), aux
 
 

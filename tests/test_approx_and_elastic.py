@@ -62,7 +62,8 @@ def test_elastic_restore_across_device_counts(tmp_path):
         cfg = get_config("deepseek-7b").reduced()
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         pshard = shd.param_shardings(mesh, model.spec())
         params = jax.device_put(params, pshard)
         tcfg = step_mod.TrainConfig(opt=adamw.AdamWConfig(
@@ -78,6 +79,7 @@ def test_elastic_restore_across_device_counts(tmp_path):
         print(json.dumps({{"step": out["final_step"]}}))
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
     r = subprocess.run([sys.executable, "-c", code], env=env,

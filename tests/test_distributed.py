@@ -13,6 +13,7 @@ _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run(code: str) -> dict:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -29,7 +30,8 @@ def test_tp_square_matmul_equivalence():
         import json, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import matmul as M
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         a = jnp.asarray(rng.normal(size=(16, 64)).astype(np.float32))
         b = jnp.asarray(rng.normal(size=(64, 32)).astype(np.float32))
@@ -72,7 +74,8 @@ def test_sharded_train_step_matches_single_device():
         ts = jax.jit(step_mod.make_train_step(model, tcfg))
         p1, _, m1 = ts(params, opt, batch)
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         pshard = shd.param_shardings(mesh, model.spec())
         ibs = shd.input_shardings(mesh, batch)
         with mesh, dctx.use_mesh(mesh):
@@ -106,7 +109,8 @@ def test_moe_shard_map_matches_local():
         batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)),
                                        jnp.int32)}
         h1, _, _ = model.forward(params, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         pshard = shd.param_shardings(mesh, model.spec())
         ibs = shd.input_shardings(mesh, batch)
         with mesh, dctx.use_mesh(mesh):
@@ -127,7 +131,8 @@ def test_logical_rules_drop_indivisible():
         from repro.configs import get_config
         from repro.distributed import sharding as shd
         from repro.models.lm import build_model
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("paligemma-3b")      # kv=1, 8 heads, big vocab/mlp
         model = build_model(cfg)
         sh = shd.param_shardings(mesh, model.spec())

@@ -60,7 +60,8 @@ def test_collective_bytes_parsed():
         import json, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.roofline.hlo_analysis import analyze_compiled
-        mesh = jax.make_mesh((4,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("model",))
         a = jax.ShapeDtypeStruct((128, 256), jnp.float32)
         b = jax.ShapeDtypeStruct((256, 64), jnp.float32)
         with mesh:
@@ -73,6 +74,7 @@ def test_collective_bytes_parsed():
         print(json.dumps({"coll": c.collectives, "dot": c.dot_flops}))
     """)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = _SRC
     out = subprocess.run([sys.executable, "-c", code], env=env,
