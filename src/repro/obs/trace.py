@@ -19,6 +19,13 @@ The tracer is the first pillar of the observability layer
   clock and the chaos suites demand deterministic runs, so tests inject a
   counting clock (see ``tests/test_faults.py``) rather than reading wall
   time.
+- **On the device's clock, when asked.**  ``enable(annotate=...)`` takes
+  a factory that turns a span's name into a context manager; every span
+  then also enters and exits ``annotate(name)``.  Callers that profile
+  pass ``jax.profiler.TraceAnnotation``, so each span lands in the
+  profiler's trace beside the device ops (this module itself imports
+  nothing outside the standard library).  Instant events stay in the
+  ring only.
 - **Thread-safe.**  The checkpoint writer commits from a worker thread;
   records carry the emitting thread id (exported as the Chrome-trace
   ``tid`` so async commits render on their own track) and the open-span
@@ -38,10 +45,14 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, ContextManager, Dict, List, Optional
 
 __all__ = ["SpanRecord", "Tracer", "get_tracer", "enabled", "enable",
            "disable", "capture", "span", "event"]
+
+#: A factory that turns a span's name into a context manager entered and
+#: exited with the span (``jax.profiler.TraceAnnotation``).
+Annotate = Callable[[str], ContextManager]
 
 
 @dataclasses.dataclass
@@ -67,7 +78,7 @@ class _Span:
     to run -- and record the span -- on ANY unwind path, including
     ``BaseException`` (SimulatedKill/SIGTERM in the trainer chaos suite).
     """
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, object]):
@@ -77,18 +88,27 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._clock()
-        self._tracer._open_enter()
+        t = self._tracer
+        self._ann = None
+        if t._annotate is not None:
+            self._ann = t._annotate(self.name)
+            self._ann.__enter__()
+        self._t0 = t._clock()
+        t._open_enter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t = self._tracer
-        t._open_exit()
-        if exc_type is not None:
-            self.args = dict(self.args, error=exc_type.__name__)
-        t._record(SpanRecord(self.name, self.cat, self._t0,
-                             t._clock() - self._t0,
-                             threading.get_ident(), self.args))
+        try:
+            t._open_exit()
+            if exc_type is not None:
+                self.args = dict(self.args, error=exc_type.__name__)
+            t._record(SpanRecord(self.name, self.cat, self._t0,
+                                 t._clock() - self._t0,
+                                 threading.get_ident(), self.args))
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
         return False                      # never swallow the exception
 
 
@@ -96,10 +116,12 @@ class Tracer:
     """Bounded-ring span/event collector.  See the module docstring."""
 
     def __init__(self, capacity: int = 16384,
-                 clock: Optional[Callable[[], float]] = None):
+                 clock: Optional[Callable[[], float]] = None,
+                 annotate: Optional[Annotate] = None):
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self._clock = clock if clock is not None else time.perf_counter
+        self._annotate = annotate         # name -> context manager, or None
         self._ring: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._open: Dict[int, int] = {}   # thread id -> open span depth
@@ -178,10 +200,12 @@ def enabled() -> bool:
 
 
 def enable(capacity: int = 16384,
-           clock: Optional[Callable[[], float]] = None) -> Tracer:
-    """Install (and return) a fresh process-global tracer."""
+           clock: Optional[Callable[[], float]] = None,
+           annotate: Optional[Annotate] = None) -> Tracer:
+    """Install (and return) a fresh process-global tracer; with
+    ``annotate`` every span also enters ``annotate(name)``."""
     global _TRACER
-    _TRACER = Tracer(capacity=capacity, clock=clock)
+    _TRACER = Tracer(capacity=capacity, clock=clock, annotate=annotate)
     return _TRACER
 
 
@@ -193,12 +217,13 @@ def disable() -> None:
 
 @contextlib.contextmanager
 def capture(capacity: int = 16384,
-            clock: Optional[Callable[[], float]] = None):
-    """Scoped tracing for tests: install a fresh tracer, yield it,
-    restore whatever was installed before (including "disabled")."""
+            clock: Optional[Callable[[], float]] = None,
+            annotate: Optional[Annotate] = None):
+    """Scoped tracing: install a fresh tracer, yield it, restore whatever
+    was installed before (including "disabled")."""
     global _TRACER
     prev = _TRACER
-    _TRACER = Tracer(capacity=capacity, clock=clock)
+    _TRACER = Tracer(capacity=capacity, clock=clock, annotate=annotate)
     try:
         yield _TRACER
     finally:

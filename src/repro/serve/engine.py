@@ -100,6 +100,14 @@ __all__ = ["EngineConfig", "EngineMetrics", "Engine", "RequestStatus",
 SHED_POLICIES = ("reject-new", "evict-oldest")
 
 
+def _named(fn, name: str):
+    """A fresh closure over ``fn`` whose ``__name__`` is ``name``."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def eviction_window(cfg) -> Optional[int]:
     """The model's uniform block-eviction horizon, or None.
 
@@ -232,8 +240,9 @@ class EngineMetrics:
     # is observed at TERMINAL time from the final ``ttft_s`` value -- a
     # preempted request's rolled-back TTFT never lands in the histogram
     # (histograms cannot un-observe), only the TTFT its caller actually
-    # saw.  ``decode_step_hist`` observes each ragged decode step's wall
-    # time -- the per-token latency every live slot paid that step.
+    # saw.  ``decode_step_hist`` observes each ragged decode step from
+    # its dispatch through the synced sample -- the per-token latency
+    # every live slot paid that step.
     ttft_hist: obs_metrics.Histogram = dataclasses.field(
         default_factory=lambda: obs_metrics.Histogram("engine_ttft_seconds"))
     decode_step_hist: obs_metrics.Histogram = dataclasses.field(
@@ -336,28 +345,29 @@ class Engine:
 
         bs = cfg.block_size
 
-        def _chunk(params, cache, pos_pool, tables, tokens, positions):
+        def chunk(params, cache, pos_pool, tables, tokens, positions):
             hidden, cache, pos_pool = model.decode_paged(
                 params, cache, tokens, positions, tables, pos_pool,
                 block_size=bs)
             return hidden, cache, pos_pool
 
-        def _decode(params, cache, pos_pool, tables, tokens, positions):
+        def decode(params, cache, pos_pool, tables, tokens, positions):
             hidden, cache, pos_pool = model.decode_paged(
                 params, cache, tokens, positions, tables, pos_pool,
                 block_size=bs)
             logits = model.logits(params, hidden)[:, -1]   # (B, V)
             return logits, cache, pos_pool
 
-        def _logits_at(params, hidden, idx):
+        def logits_at(params, hidden, idx):
             h = jax.lax.dynamic_slice_in_dim(hidden, idx, 1, axis=1)
             return model.logits(params, h)[:, 0]           # (1, V)
 
         # raw model fns are kept so the compiled guard can re-jit after a
         # RouteHealth demotion (demotion is a trace-time branch: a cached
-        # trace keeps serving the square route until a fresh trace)
-        self._model_fns = {"_chunk": _chunk, "_decode": _decode,
-                           "_logits_at": _logits_at}
+        # trace keeps serving the square route until a fresh trace).  Keyed
+        # by program name; each is served as ``self._<name>``.
+        self._model_fns = {"chunk": chunk, "decode": decode,
+                           "logits_at": logits_at}
         self._jit_model_fns()
         from repro.kernels import routing as _routing
         self._route_epoch = _routing.route_epoch()
@@ -399,10 +409,6 @@ class Engine:
             "guard_trips": reg.counter("engine_guard_trips_total"),
             "guard_rejits": reg.counter("engine_guard_rejits_total"),
         }
-        self._g_queue = reg.gauge("engine_queue_depth")
-        self._g_blocks = reg.gauge("engine_blocks_used")
-        self._g_util = reg.gauge("engine_block_utilization")
-        self._g_live = reg.gauge("engine_live_slots")
         # the registry's latency histograms ARE the EngineMetrics ones
         # (one observe feeds both views)
         self.metrics.ttft_hist = reg.histogram("engine_ttft_seconds")
@@ -425,15 +431,27 @@ class Engine:
 
     def _sample(self, logits) -> np.ndarray:
         if self.cfg.temperature <= 0.0:
-            return np.asarray(jnp.argmax(logits, axis=-1))
-        self.key, sub = jax.random.split(self.key)
-        return np.asarray(jax.random.categorical(
-            sub, logits / self.cfg.temperature))
+            toks = jnp.argmax(logits, axis=-1)
+        else:
+            self.key, sub = jax.random.split(self.key)
+            toks = jax.random.categorical(sub, logits / self.cfg.temperature)
+        with obs_trace.span("engine.sync", cat="engine", what="sample"):
+            return np.asarray(toks)
+
+    def _table_rows(self, rows: slice):
+        """Block-table rows as a device array, from a snapshot: on the CPU
+        backend ``jnp.asarray`` may alias an aligned numpy buffer, and the
+        host edits the table in place (grow, evict, release) while a
+        program dispatched earlier can still be reading it."""
+        return jnp.asarray(self.tables.table[rows].copy())
 
     def _reset_pos(self, blocks: List[int]) -> None:
         if blocks:
-            idx = self.tables.reset_slots_index(blocks)
-            self.pos_pool = self.pos_pool.at[jnp.asarray(idx)].set(EMPTY_POS)
+            with obs_trace.span("engine.reset_pos", cat="engine",
+                                n=len(blocks)):
+                idx = self.tables.reset_slots_index(blocks)
+                self.pos_pool = self.pos_pool.at[jnp.asarray(idx)].set(
+                    EMPTY_POS)
 
     def _release(self, slot_id: int) -> None:
         self._reset_pos(self.tables.release(slot_id))
@@ -500,72 +518,78 @@ class Engine:
         terminal status (never an exception -- one bad request must not
         kill a batch); the single raising case is a duplicate ``rid``,
         which is a caller bug that would corrupt the results keying."""
+        with obs_trace.span("engine.submit", cat="engine", n=len(requests)):
+            for req in requests:
+                self._submit_one(req)
+
+    def _submit_one(self, req: Request) -> None:
         cfg = self.cfg
-        for req in requests:
-            if req.rid in self.results or req.rid in self._arrival:
-                raise ValueError(
-                    f"duplicate request id {req.rid}: a rid already "
-                    f"queued, in flight, or finished would silently "
-                    f"overwrite its result; use fresh rids per request")
-            self._c_requests["submitted"].inc()
-            obs_trace.event("request.submit", cat="engine", rid=req.rid,
-                            prompt_tokens=len(req.tokens))
-            if len(req.tokens) == 0:
-                self._reject(req, "empty prompt (there is no position to "
-                                  "sample the first token from)")
-                continue
-            total = len(req.tokens) + cfg.max_new_tokens
-            if total > cfg.max_len:
-                self._reject(
-                    req, f"prompt {len(req.tokens)} + max_new "
-                         f"{cfg.max_new_tokens} exceeds the per-sequence "
-                         f"ceiling {cfg.max_len} ({cfg.blocks_per_seq} "
-                         f"blocks x {cfg.block_size})")
-                continue
-            if self.allocator.blocks_for(total) > cfg.num_blocks - 1:
-                self._reject(
-                    req, f"needs {self.allocator.blocks_for(total)} blocks "
-                         f"but the pool only has {cfg.num_blocks - 1} "
-                         f"allocatable ones")
-                continue
-            if cfg.queue_limit is not None \
-                    and len(self.queue) >= cfg.queue_limit:
-                if cfg.shed_policy == "reject-new":
-                    self._reject(req, f"admission queue full "
-                                      f"(queue_limit={cfg.queue_limit}, "
-                                      f"shed_policy=reject-new)", shed=True)
-                    continue
-                # evict-oldest: shed the oldest *queued* request (in-flight
-                # work is never thrown away by admission pressure)
-                victim = self.queue.pop(0)
-                self._reject(victim,
-                             f"shed from the admission queue by a newer "
-                             f"request (queue_limit={cfg.queue_limit}, "
-                             f"shed_policy=evict-oldest)", shed=True)
-            now = self._now()
-            self._arrival[req.rid] = now
-            budget = (req.deadline_s if req.deadline_s is not None
-                      else cfg.deadline_s)
-            if budget is not None:
-                self._deadline[req.rid] = now + float(budget)
-            self.queue.append(req)
-            self.metrics.peak_queue_depth = max(
-                self.metrics.peak_queue_depth, len(self.queue))
+        if req.rid in self.results or req.rid in self._arrival:
+            raise ValueError(
+                f"duplicate request id {req.rid}: a rid already "
+                f"queued, in flight, or finished would silently "
+                f"overwrite its result; use fresh rids per request")
+        self._c_requests["submitted"].inc()
+        obs_trace.event("request.submit", cat="engine", rid=req.rid,
+                        prompt_tokens=len(req.tokens))
+        if len(req.tokens) == 0:
+            self._reject(req, "empty prompt (there is no position to "
+                              "sample the first token from)")
+            return
+        total = len(req.tokens) + cfg.max_new_tokens
+        if total > cfg.max_len:
+            self._reject(
+                req, f"prompt {len(req.tokens)} + max_new "
+                     f"{cfg.max_new_tokens} exceeds the per-sequence "
+                     f"ceiling {cfg.max_len} ({cfg.blocks_per_seq} "
+                     f"blocks x {cfg.block_size})")
+            return
+        if self.allocator.blocks_for(total) > cfg.num_blocks - 1:
+            self._reject(
+                req, f"needs {self.allocator.blocks_for(total)} blocks "
+                     f"but the pool only has {cfg.num_blocks - 1} "
+                     f"allocatable ones")
+            return
+        if cfg.queue_limit is not None \
+                and len(self.queue) >= cfg.queue_limit:
+            if cfg.shed_policy == "reject-new":
+                self._reject(req, f"admission queue full "
+                                  f"(queue_limit={cfg.queue_limit}, "
+                                  f"shed_policy=reject-new)", shed=True)
+                return
+            # evict-oldest: shed the oldest *queued* request (in-flight
+            # work is never thrown away by admission pressure)
+            victim = self.queue.pop(0)
+            self._reject(victim,
+                         f"shed from the admission queue by a newer "
+                         f"request (queue_limit={cfg.queue_limit}, "
+                         f"shed_policy=evict-oldest)", shed=True)
+        now = self._now()
+        self._arrival[req.rid] = now
+        budget = (req.deadline_s if req.deadline_s is not None
+                  else cfg.deadline_s)
+        if budget is not None:
+            self._deadline[req.rid] = now + float(budget)
+        self.queue.append(req)
+        self.metrics.peak_queue_depth = max(
+            self.metrics.peak_queue_depth, len(self.queue))
 
     def cancel(self, rid: int) -> bool:
         """Cancel a queued or in-flight request (terminal status
         CANCELLED, partial tokens returned, blocks recycled).  Returns
         False if ``rid`` is not pending."""
-        for req in self.queue:
-            if req.rid == rid:
-                self.queue.remove(req)
-                self._result(req, RequestStatus.CANCELLED, "cancelled")
-                return True
-        for slot_id, slot in enumerate(self.slots):
-            if slot is not None and slot.req.rid == rid:
-                self._terminate(slot_id, RequestStatus.CANCELLED, "cancelled")
-                return True
-        return False
+        with obs_trace.span("engine.cancel", cat="engine", rid=rid):
+            for req in self.queue:
+                if req.rid == rid:
+                    self.queue.remove(req)
+                    self._result(req, RequestStatus.CANCELLED, "cancelled")
+                    return True
+            for slot_id, slot in enumerate(self.slots):
+                if slot is not None and slot.req.rid == rid:
+                    self._terminate(slot_id, RequestStatus.CANCELLED,
+                                    "cancelled")
+                    return True
+            return False
 
     def drain_finished(self) -> List[RequestResult]:
         """Terminal results accumulated since the last drain (streaming
@@ -659,14 +683,16 @@ class Engine:
         # each call wraps the raw fns in FRESH closures before jitting:
         # jax's trace cache is keyed on the underlying callable, so
         # re-jitting the same object after a RouteHealth demotion would
-        # silently reuse the pre-demotion program
+        # silently reuse the pre-demotion program.  The closure carries
+        # the program's name, so the compiled module (``jit_decode``) and
+        # its device events in a profiler trace are named after it.
         for name, fn in self._model_fns.items():
-            wrapped = (jax.jit(lambda *a, _f=fn: _f(*a)) if self.cfg.jit
-                       else fn)
-            setattr(self, name, wrapped)
+            setattr(self, "_" + name,
+                    jax.jit(_named(fn, name)) if self.cfg.jit else fn)
 
     def _guarded_call(self, name: str, *args):
-        """Run one jitted model fn under the compiled numerics guard.
+        """Run the model program ``name`` (``self._<name>``) under the
+        compiled numerics guard.
 
         With ``guard=True, jit=True`` the traces carry host-callback
         finite probes (see ``core/guards``): after each call the
@@ -678,12 +704,15 @@ class Engine:
         assigned only on success by the callers), so the retry is
         token-exact.  Eager guarded engines (``jit=False``) keep the
         in-line dispatcher fallback and skip the drain entirely."""
+        fn = "_" + name
         if not (self.cfg.guard and self.cfg.jit):
-            return getattr(self, name)(*args)
+            return getattr(self, fn)(*args)
         from repro.kernels import routing
         for _ in range(self.cfg.max_step_retries + 1):
-            out = getattr(self, name)(*args)
-            jax.block_until_ready(out)
+            out = getattr(self, fn)(*args)
+            with obs_trace.span("engine.sync", cat="engine",
+                                what="guarded_call", fn=name):
+                jax.block_until_ready(out)
             trips = guards.drain_pending_trips()
             if not trips:
                 return out
@@ -731,6 +760,7 @@ class Engine:
         slot_id = min(cand, key=lambda i: (self._arrival[
             self.slots[i].req.rid], i))
         slot = self.slots[slot_id]
+        rid = slot.req.rid
         prompt = np.asarray(slot.req.tokens, np.int32)
         lo = slot.n_prefilled
         chunk = prompt[lo:lo + cfg.prefill_chunk]
@@ -739,32 +769,35 @@ class Engine:
             # grow the table to cover this chunk (admission only reserved
             # the first chunk); preempt youngest-first when the pool is
             # dry, exactly like the decode growth loop.
-            freed = self.tables.evict_window(slot_id, lo, self._evict_window)
-            if freed:
-                obs_trace.event("engine.evict", cat="engine",
-                                rid=slot.req.rid, blocks=len(freed))
-            self._reset_pos(freed)
-            while self.slots[slot_id] is not None and \
-                    not self.tables.ensure(slot_id, lo + len(chunk)):
-                if not self._preempt_for(slot_id):
-                    return False               # retry next tick
+            with obs_trace.span("engine.grow", cat="engine", rid=rid):
+                freed = self.tables.evict_window(slot_id, lo,
+                                                 self._evict_window)
+                if freed:
+                    obs_trace.event("engine.evict", cat="engine", rid=rid,
+                                    blocks=len(freed))
+                self._reset_pos(freed)
+                while self.slots[slot_id] is not None and \
+                        not self.tables.ensure(slot_id, lo + len(chunk)):
+                    if not self._preempt_for(slot_id):
+                        return False           # retry next tick
             if self.slots[slot_id] is None:    # preempted itself
                 return True
         C = cfg.prefill_chunk
-        toks = np.zeros((1, C), np.int32)
-        poss = np.full((1, C), -1, np.int32)
-        toks[0, :len(chunk)] = chunk
-        poss[0, :len(chunk)] = np.arange(lo, lo + len(chunk), dtype=np.int32)
-        tables_row = jnp.asarray(self.tables.table[slot_id:slot_id + 1])
         try:
             # the span covers the injector hook too: an injected raise is
             # an error-tagged span, not a gap in the trace
             with obs_trace.span("engine.prefill_chunk", cat="engine",
-                                rid=slot.req.rid, lo=lo, n=len(chunk)):
+                                rid=rid, lo=lo, n=len(chunk)):
+                toks = np.zeros((1, C), np.int32)
+                poss = np.full((1, C), -1, np.int32)
+                toks[0, :len(chunk)] = chunk
+                poss[0, :len(chunk)] = np.arange(lo, lo + len(chunk),
+                                                 dtype=np.int32)
+                tables_row = self._table_rows(slice(slot_id, slot_id + 1))
                 if self._faults is not None:
                     self._faults.before_step("prefill")
                 hidden, cache, pos_pool = self._guarded_call(
-                    "_chunk", self.params, self.cache, self.pos_pool,
+                    "chunk", self.params, self.cache, self.pos_pool,
                     tables_row, jnp.asarray(toks), jnp.asarray(poss))
         except Exception as e:                        # noqa: BLE001
             self._step_failed("prefill", e, [slot_id])
@@ -776,29 +809,34 @@ class Engine:
         self._c_work["prefill_chunks"].inc()
         self.metrics.prefill_tokens += len(chunk)
         if slot.n_prefilled == len(prompt):      # final chunk: first token
-            logits = self._guarded_call("_logits_at", self.params, hidden,
-                                        jnp.int32(len(chunk) - 1))
-            # one reduce + scalar transfer (nan/+inf propagate through
-            # max), not an elementwise isfinite over the vocab row
-            if cfg.guard and not np.isfinite(float(jnp.max(logits))):
-                self.metrics.guard_trips += 1
-                self._terminate(slot_id, RequestStatus.FAILED,
-                                "non-finite prefill logits (numerics guard)")
-                return True
-            tok = int(self._sample(logits)[0])
-            rid = slot.req.rid
-            self.metrics.ttft_s[rid] = self._now() - self._arrival[rid]
-            obs_trace.event("request.first_token", cat="engine", rid=rid,
-                            ttft_s=self.metrics.ttft_s[rid])
-            slot.req.out = [tok]
-            self.metrics.tokens_out += 1
-            self._c_work["tokens"].inc()
-            slot.last_tok = tok
-            slot.pos = len(prompt)
-            slot.remaining = cfg.max_new_tokens - 1
-            slot.state = "decode"
-            if tok == cfg.eos_id or slot.remaining <= 0:
-                self._finish(slot_id)
+            with obs_trace.span("engine.first_token", cat="engine", rid=rid):
+                logits = self._guarded_call("logits_at", self.params, hidden,
+                                            jnp.int32(len(chunk) - 1))
+                # one reduce + scalar transfer (nan/+inf propagate through
+                # max), not an elementwise isfinite over the vocab row
+                if cfg.guard:
+                    with obs_trace.span("engine.sync", cat="engine",
+                                        what="guard"):
+                        finite = np.isfinite(float(jnp.max(logits)))
+                    if not finite:
+                        self.metrics.guard_trips += 1
+                        self._terminate(slot_id, RequestStatus.FAILED,
+                                        "non-finite prefill logits "
+                                        "(numerics guard)")
+                        return True
+                tok = int(self._sample(logits)[0])
+                self.metrics.ttft_s[rid] = self._now() - self._arrival[rid]
+                obs_trace.event("request.first_token", cat="engine", rid=rid,
+                                ttft_s=self.metrics.ttft_s[rid])
+                slot.req.out = [tok]
+                self.metrics.tokens_out += 1
+                self._c_work["tokens"].inc()
+                slot.last_tok = tok
+                slot.pos = len(prompt)
+                slot.remaining = cfg.max_new_tokens - 1
+                slot.state = "decode"
+                if tok == cfg.eos_id or slot.remaining <= 0:
+                    self._finish(slot_id)
         return True
 
     def _decode_all(self) -> bool:
@@ -813,82 +851,98 @@ class Engine:
         # skips this tick -- it retries next tick, and the watchdog
         # surfaces the condition if it never clears.
         blocked = set()
-        for slot_id in list(live):
-            if self._evict_window is not None \
-                    and self.slots[slot_id] is not None:
-                freed = self.tables.evict_window(
-                    slot_id, self.slots[slot_id].pos, self._evict_window)
-                if freed:
-                    obs_trace.event("engine.evict", cat="engine",
-                                    rid=self.slots[slot_id].req.rid,
-                                    blocks=len(freed))
-                self._reset_pos(freed)
-            while self.slots[slot_id] is not None and \
-                    not self.tables.ensure(slot_id,
-                                           self.slots[slot_id].pos + 1):
-                if not self._preempt_for(slot_id):
-                    blocked.add(slot_id)
-                    break
+        with obs_trace.span("engine.grow", cat="engine", n_live=len(live)):
+            for slot_id in live:
+                if self._evict_window is not None \
+                        and self.slots[slot_id] is not None:
+                    freed = self.tables.evict_window(
+                        slot_id, self.slots[slot_id].pos, self._evict_window)
+                    if freed:
+                        obs_trace.event("engine.evict", cat="engine",
+                                        rid=self.slots[slot_id].req.rid,
+                                        blocks=len(freed))
+                    self._reset_pos(freed)
+                while self.slots[slot_id] is not None and \
+                        not self.tables.ensure(slot_id,
+                                               self.slots[slot_id].pos + 1):
+                    if not self._preempt_for(slot_id):
+                        blocked.add(slot_id)
+                        break
         live = [i for i, s in enumerate(self.slots)
                 if s is not None and s.state == "decode"
                 and i not in blocked]
         if not live:
             return False
-        B = cfg.max_slots
-        toks = np.zeros((B, 1), np.int32)
-        poss = np.full((B, 1), -1, np.int32)
-        for i in live:
-            toks[i, 0] = self.slots[i].last_tok
-            poss[i, 0] = self.slots[i].pos
-        t0 = time.perf_counter()
+        dispatched = False
         try:
+            # input build through the synced sample and the token
+            # bookkeeping; the span covers the injector hook too, so an
+            # injected raise is an error-tagged span, not a gap
             with obs_trace.span("engine.decode_step", cat="engine",
                                 n_live=len(live)):
+                B = cfg.max_slots
+                toks = np.zeros((B, 1), np.int32)
+                poss = np.full((B, 1), -1, np.int32)
+                for i in live:
+                    toks[i, 0] = self.slots[i].last_tok
+                    poss[i, 0] = self.slots[i].pos
+                tables = self._table_rows(slice(None))
+                toks, poss = jnp.asarray(toks), jnp.asarray(poss)
                 if self._faults is not None:
                     self._faults.before_step("decode")
+                t0 = time.perf_counter()
                 logits, cache, pos_pool = self._guarded_call(
-                    "_decode", self.params, self.cache, self.pos_pool,
-                    jnp.asarray(self.tables.table), jnp.asarray(toks),
-                    jnp.asarray(poss))
+                    "decode", self.params, self.cache, self.pos_pool,
+                    tables, toks, poss)
+                # the calls are functional: a raise before this point left
+                # nothing mutated; one after it is the host's own fault
+                dispatched = True
+                self._fail_streak["decode"] = 0
+                self.cache, self.pos_pool = cache, pos_pool
+                if self._faults is not None:
+                    logits = self._faults.poison_logits(
+                        logits, self.metrics.decode_steps)
+                nxt = self._sample(logits)
+                # one ragged decode step = one new token per live slot:
+                # dispatch through the synced sample IS the per-token
+                # latency those slots paid
+                self.metrics.decode_step_hist.observe(
+                    time.perf_counter() - t0)
+                finite = None
+                if cfg.guard:
+                    # per-row max probe: nan/+inf propagate, so a poisoned
+                    # row reads non-finite with one reduce instead of an
+                    # elementwise isfinite pass over (slots, vocab)
+                    with obs_trace.span("engine.sync", cat="engine",
+                                        what="guard"):
+                        finite = np.isfinite(
+                            np.asarray(jnp.max(logits, axis=-1)))
+                self.metrics.decode_steps += 1
+                self._c_work["decode_steps"].inc()
+                self.metrics.decode_slot_steps += len(live)
+                for i in live:
+                    if finite is not None and not finite[i]:
+                        # fail THIS slot, not the batch: argmax over a
+                        # poisoned row would silently serve token 0 forever
+                        self.metrics.guard_trips += 1
+                        self._terminate(i, RequestStatus.FAILED,
+                                        "non-finite logits (numerics guard)")
+                        continue
+                    slot = self.slots[i]
+                    tok = int(nxt[i])
+                    slot.req.out.append(tok)
+                    self.metrics.tokens_out += 1
+                    self._c_work["tokens"].inc()
+                    slot.pos += 1
+                    slot.last_tok = tok
+                    slot.remaining -= 1
+                    if tok == cfg.eos_id or slot.remaining <= 0:
+                        self._finish(i)
         except Exception as e:                        # noqa: BLE001
+            if dispatched:
+                raise
             self._step_failed("decode", e, live)
             return False
-        # one ragged decode step = one new token per live slot: the step
-        # wall time IS the per-token decode latency those slots paid
-        self.metrics.decode_step_hist.observe(time.perf_counter() - t0)
-        self._fail_streak["decode"] = 0
-        self.cache, self.pos_pool = cache, pos_pool
-        if self._faults is not None:
-            logits = self._faults.poison_logits(logits,
-                                                self.metrics.decode_steps)
-        nxt = self._sample(logits)
-        finite = None
-        if cfg.guard:
-            # per-row max probe: nan/+inf propagate, so a poisoned row
-            # reads non-finite with one reduce instead of an elementwise
-            # isfinite pass over (slots, vocab)
-            finite = np.isfinite(np.asarray(jnp.max(logits, axis=-1)))
-        self.metrics.decode_steps += 1
-        self._c_work["decode_steps"].inc()
-        self.metrics.decode_slot_steps += len(live)
-        for i in live:
-            if finite is not None and not finite[i]:
-                # fail THIS slot, not the batch: argmax over a poisoned
-                # row would silently serve token 0 forever
-                self.metrics.guard_trips += 1
-                self._terminate(i, RequestStatus.FAILED,
-                                "non-finite logits (numerics guard)")
-                continue
-            slot = self.slots[i]
-            tok = int(nxt[i])
-            slot.req.out.append(tok)
-            self.metrics.tokens_out += 1
-            self._c_work["tokens"].inc()
-            slot.pos += 1
-            slot.last_tok = tok
-            slot.remaining -= 1
-            if tok == cfg.eos_id or slot.remaining <= 0:
-                self._finish(i)
         return True
 
     # ------------------------------------------------------------ watchdog
@@ -937,24 +991,19 @@ class Engine:
                 did = self._admit()
             did = self._prefill_one() or did
             did = self._decode_all() or did
-        self.metrics.util_sum += self.allocator.utilization
-        self.metrics.util_steps += 1
-        self.metrics.peak_blocks_used = max(self.metrics.peak_blocks_used,
-                                            self.allocator.used_blocks)
-        occ = self.allocator.occupancy()
-        self._g_queue.set(len(self.queue))
-        self._g_blocks.set(occ["used_blocks"])
-        self._g_util.set(occ["utilization"])
-        self._g_live.set(sum(s is not None for s in self.slots))
-        pending = bool(self.queue) \
-            or any(s is not None for s in self.slots)
-        if pending and not did:
-            self._idle_ticks += 1
-            if self._idle_ticks >= self.cfg.watchdog_steps:
-                self._watchdog_fire()
-                pending = False
-        else:
-            self._idle_ticks = 0
+            self.metrics.util_sum += self.allocator.utilization
+            self.metrics.util_steps += 1
+            self.metrics.peak_blocks_used = max(
+                self.metrics.peak_blocks_used, self.allocator.used_blocks)
+            pending = bool(self.queue) \
+                or any(s is not None for s in self.slots)
+            if pending and not did:
+                self._idle_ticks += 1
+                if self._idle_ticks >= self.cfg.watchdog_steps:
+                    self._watchdog_fire()
+                    pending = False
+            else:
+                self._idle_ticks = 0
         return did or pending
 
     def run(self, requests: List[Request]) -> Dict[int, RequestResult]:

@@ -80,15 +80,6 @@ class BlockAllocator:
         """Blocks needed to hold ``n_tokens`` cache entries."""
         return -(-max(0, int(n_tokens)) // self.block_size)
 
-    def occupancy(self) -> dict:
-        """Pool occupancy snapshot for the observability layer (the
-        engine publishes these as ``engine_blocks_*`` gauges each tick;
-        see docs/observability.md)."""
-        return {"num_blocks": self.num_blocks - 1,
-                "used_blocks": self.used_blocks,
-                "free_blocks": self.free_blocks,
-                "utilization": self.utilization}
-
     def alloc(self, n: int) -> Optional[List[int]]:
         """Grant ``n`` blocks, or None (untouched) if they are not free."""
         if n > len(self._free):

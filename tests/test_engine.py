@@ -424,3 +424,171 @@ def test_engine_metrics_summary_never_divides_by_zero():
 def test_engine_config_validates_shed_policy():
     with pytest.raises(ValueError, match="shed_policy"):
         EngineConfig(shed_policy="drop-everything")
+
+
+# ------------------------------------------------------------- tracing
+
+class _Nesting:
+    """An ``annotate`` factory that records each span with the names of
+    the spans open around it when it was entered."""
+
+    def __init__(self):
+        self.stack, self.seen = [], []
+
+    def __call__(self, name):
+        outer = self
+
+        class _Ann:
+            def __enter__(self):
+                outer.seen.append((name, tuple(outer.stack)))
+                outer.stack.append(name)
+
+            def __exit__(self, *exc):
+                assert outer.stack.pop() == name
+        return _Ann()
+
+
+def _swa_world(window=8):
+    cfg = dataclasses.replace(get_config("starcoder2-3b").reduced(),
+                              window=window)
+    model = build_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+SWA_ENGINE = dict(max_slots=4, block_size=4, num_blocks=48, blocks_per_seq=10,
+                  prefill_chunk=8, max_new_tokens=8)
+
+
+def test_traced_engine_records_the_span_table():
+    """A traced run with a client that submits and cancels between ticks
+    records every span of the engine's table and closes them all.  The
+    client's calls are outermost; every other span nests under
+    ``engine.tick``, or under the call (``cancel`` releases blocks)."""
+    from repro.obs import trace as obs_trace
+    cfg, model, params = _swa_world()
+    reqs = _ragged_requests(cfg, 4, seed=4, lo=10, hi=24)
+    eng = Engine(model, params, EngineConfig(**SWA_ENGINE))
+    nest = _Nesting()
+    with obs_trace.capture(annotate=nest) as tr:
+        eng.submit([Request(r.rid, r.tokens) for r in reqs[:3]])
+        ticks = 0
+        while eng.step():
+            ticks += 1
+            if ticks == 6:
+                eng.submit([Request(reqs[3].rid, reqs[3].tokens)])
+                assert eng.cancel(reqs[0].rid)
+    assert tr.open_spans == 0 and not nest.stack
+    names = {n for n, _ in nest.seen}
+    assert names == {"engine.tick", "engine.admit", "engine.prefill_chunk",
+                     "engine.first_token", "engine.grow", "engine.decode_step",
+                     "engine.sync", "engine.reset_pos", "engine.submit",
+                     "engine.cancel"}
+    for name, around in nest.seen:
+        if name in ("engine.submit", "engine.cancel"):
+            assert "engine.tick" not in around, name
+        elif name == "engine.tick":
+            assert around == (), around
+        else:
+            assert around[:1] in (("engine.tick",), ("engine.cancel",)), \
+                (name, around)
+    recs = [r for r in tr.records() if r.dur is not None]
+    assert [n for n, _ in nest.seen] == [r.name for r in sorted(
+        recs, key=lambda r: (r.ts, -r.dur))]
+    per_req = ("engine.prefill_chunk", "engine.first_token", "engine.cancel")
+    assert all("rid" in r.args for r in recs if r.name in per_req)
+    assert {r.args["what"] for r in recs if r.name == "engine.sync"} \
+        == {"sample"}
+    assert all(r.args["n"] > 0 for r in recs if r.name == "engine.reset_pos")
+    assert sum(r.name == "engine.tick" for r in recs) == ticks + 1
+
+
+def test_engine_programs_carry_stable_names():
+    """The three step programs compile as modules named after them, on
+    the first jit and on every re-jit (which must build fresh closures)."""
+    cfg, model, params = _model()
+    eng = Engine(model, params, EngineConfig(
+        max_slots=2, block_size=8, num_blocks=16, blocks_per_seq=4,
+        prefill_chunk=8, max_new_tokens=3))
+    first = {n: getattr(eng, "_" + n) for n in ("decode", "chunk",
+                                                "logits_at")}
+    eng._jit_model_fns()
+    for name, old in first.items():
+        fn = getattr(eng, "_" + name)
+        assert fn is not old
+        assert fn.__name__ == old.__name__ == name
+    base = (eng.params, eng.cache, eng.pos_pool)
+    i32 = np.int32
+    decode = eng._decode.lower(*base, np.zeros((2, 4), i32),
+                               np.zeros((2, 1), i32), np.zeros((2, 1), i32))
+    chunk = eng._chunk.lower(*base, np.zeros((1, 4), i32),
+                             np.zeros((1, 8), i32), np.zeros((1, 8), i32))
+    hidden = jax.ShapeDtypeStruct((1, 8, cfg.d_model), np.float32)
+    logits_at = eng._logits_at.lower(eng.params, hidden, np.int32(0))
+    for name, low in (("decode", decode), ("chunk", chunk),
+                      ("logits_at", logits_at)):
+        assert f"module @jit_{name} " in low.as_text()
+
+
+def test_decode_step_histogram_times_the_synced_step():
+    """Each ``engine_decode_step_seconds`` observation runs from the
+    dispatch through the synced sample: its interval holds the step's
+    ``engine.sync`` span and lies inside the step's ``engine.decode_step``
+    span."""
+    import time
+
+    from repro.obs import trace as obs_trace
+    cfg, model, params = _model()
+    reqs = _ragged_requests(cfg, 3, lo=4, hi=12, seed=5)
+    eng = Engine(model, params, EngineConfig(
+        max_slots=4, block_size=8, num_blocks=32, blocks_per_seq=4,
+        prefill_chunk=8, max_new_tokens=5))
+    seen = []
+
+    class Spy:
+        def __init__(self, hist):
+            self.hist = hist
+
+        def observe(self, v):
+            seen.append((time.perf_counter(), v))
+            self.hist.observe(v)
+
+        def __getattr__(self, name):
+            return getattr(self.hist, name)
+    eng.metrics.decode_step_hist = Spy(eng.metrics.decode_step_hist)
+    with obs_trace.capture() as tr:           # the tracer's clock is
+        eng.run([Request(r.rid, r.tokens) for r in reqs])  # perf_counter
+    recs = tr.records()
+    steps = [r for r in recs if r.name == "engine.decode_step"]
+    syncs = [r for r in recs if r.name == "engine.sync"]
+    assert len(seen) == len(steps) == eng.metrics.decode_steps > 0
+    for (t_end, v), step in zip(seen, steps):
+        t0 = t_end - v
+        assert step.ts <= t0 and t_end <= step.ts + step.dur
+        inside = [s for s in syncs if t0 <= s.ts and s.ts + s.dur <= t_end]
+        assert [s.args["what"] for s in inside] == ["sample"]
+
+
+def test_engine_uploads_block_tables_by_value():
+    """Every program sees the block table as it was at dispatch.  The host
+    edits the table in place (grow, evict, release) while a program
+    dispatched earlier can still be reading it, so an upload that aliased
+    the host buffer (as the CPU backend may for an aligned array) would
+    let later edits reach it."""
+    cfg, model, params = _swa_world()
+    reqs = _ragged_requests(cfg, 4, seed=4, lo=10, hi=24)
+    eng = Engine(model, params, EngineConfig(**SWA_ENGINE))
+    uploads = []
+    for name in ("_chunk", "_decode"):
+        fn = getattr(eng, name)
+
+        def spy(params, cache, pos_pool, tables, *rest, _fn=fn):
+            uploads.append((tables, np.array(tables)))
+            return _fn(params, cache, pos_pool, tables, *rest)
+        setattr(eng, name, spy)
+    res = eng.run([Request(r.rid, r.tokens) for r in reqs])
+    assert all(r.ok for r in res.values())
+    assert eng.metrics.prefill_chunks > 0 and eng.metrics.decode_steps > 0
+    assert len(uploads) == (eng.metrics.prefill_chunks
+                            + eng.metrics.decode_steps)
+    for dev, at_dispatch in uploads:
+        np.testing.assert_array_equal(np.asarray(dev), at_dispatch)

@@ -110,6 +110,67 @@ def test_tracer_rejects_nonpositive_capacity():
         obs_trace.Tracer(capacity=0)
 
 
+class FakeAnnotation:
+    """An ``annotate`` factory that logs every enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, exc_type, exc, tb):
+                log.append(("exit", name,
+                            exc_type.__name__ if exc_type else None))
+        return _Ann()
+
+
+def test_spans_forward_to_the_annotate_factory_in_order():
+    ann = FakeAnnotation()
+    with obs_trace.capture(clock=FakeClock(), annotate=ann) as tr:
+        with obs_trace.span("outer", rid=3):
+            with obs_trace.span("inner"):
+                pass
+        obs_trace.event("instant")               # events are not forwarded
+    assert ann.log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner", None), ("exit", "outer", None)]
+    assert [r.name for r in tr.records()] == ["inner", "outer", "instant"]
+    assert tr.records()[1].args == {"rid": 3}
+    assert tr.open_spans == 0
+
+
+def test_forwarded_spans_balance_through_base_exception():
+    class Kill(BaseException):
+        pass
+
+    ann = FakeAnnotation()
+    tr = obs_trace.Tracer(annotate=ann)
+    with pytest.raises(Kill):
+        with tr.span("outer"):
+            with tr.span("inner"):
+                raise Kill()
+    assert tr.open_spans == 0
+    assert ann.log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner", "Kill"), ("exit", "outer", "Kill")]
+    assert all(r.args["error"] == "Kill" for r in tr.records())
+
+
+def test_disabled_path_stays_the_shared_noop_after_forwarding():
+    ann = FakeAnnotation()
+    obs_trace.enable(annotate=ann)
+    with obs_trace.span("on"):
+        pass
+    obs_trace.disable()
+    assert obs_trace.span("a") is obs_trace.span("b")
+    with obs_trace.span("off"):
+        pass
+    assert ann.log == [("enter", "on"), ("exit", "on", None)]
+
+
 # --------------------------------------------------------------- metrics
 
 def test_counter_is_monotonic_by_type():
