@@ -104,8 +104,9 @@ FOLD_MIN_BATCH = 4
 IM2COL_PATCH_BYTES_MAX = tuning.CACHE_BUDGET
 IM2COL_K_MAX = tuning.LANE
 
-# The fused paged-attention kernel streams one pool block per grid step;
-# its win condition is a long table walk amortizing a small query tile.
+# The fused paged-attention kernel streams a sequence's live pool blocks,
+# at least 128 tokens and every KV head per grid step; its win condition
+# is a long table walk amortizing a small query tile.
 # Decode steps carry a handful of query rows (S <= chunk of new tokens,
 # usually 1); above that the score tile rematerializes per block and the
 # dense gather's single big contraction wins.

@@ -93,10 +93,11 @@ class PagedAttnPlan:
     """Chunk plan for the fused paged-attention kernel.
 
     The kernel's tile geometry is fixed by the call (the query tile is
-    the whole (S*G, hd) panel, the K/V tile one pool block), so the only
-    free knobs are the PM chunk widths of its two contractions: ``kc_qk``
-    chunks the head_dim reduction of the score block, ``kc_pv`` the
-    block-token reduction of the PV block.  Each must divide its axis.
+    the whole (S*G, hd) panel of one head, the K/V tile ``tile`` tokens
+    of that head), so the only free knobs are the PM chunk widths of its
+    two contractions: ``kc_qk`` chunks the head_dim reduction of the
+    score block, ``kc_pv`` the tile-token reduction of the PV block.
+    Each must divide its axis.
     """
     kc_qk: int
     kc_pv: int
@@ -584,36 +585,48 @@ def plan_conv2d(h: int, w: int, kh: int, kw: int, cin: int, cout: int,
     return plan
 
 
-def plan_paged_attn(rows: int, hd: int, block_size: int,
-                    dtype=jnp.float32, *, kc_qk: Optional[int] = None,
+def _paged_key(rows: int, hd: int, tile: int, kv_heads: int, dtype) -> str:
+    """Cache key of a paged-attention plan: the KV head count leads, so no
+    entry of the one-head-one-block kernel's ``rows x hd x block_size``
+    keys is ever served to the whole-tile kernel."""
+    return (f"sq_paged_attn:{kv_heads}kv:{rows}x{hd}x{tile}:"
+            f"{jnp.dtype(dtype).name}")
+
+
+def plan_paged_attn(rows: int, hd: int, tile: int,
+                    dtype=jnp.float32, *, kv_heads: int = 1,
+                    kc_qk: Optional[int] = None,
                     kc_pv: Optional[int] = None,
                     pm_layout: str = "mkn") -> PagedAttnPlan:
     """Pick the (kc_qk, kc_pv, pm_layout) plan for a fused paged-attention
     call.  ``rows`` is the score-tile row count (``S * G``: query tile x
-    GQA group), ``hd`` the head dim, ``block_size`` the pool block length.
+    GQA group), ``hd`` the head dim, ``tile`` the tokens one grid step
+    walks (:func:`repro.kernels.sq_paged_attn.tile_blocks` table entries
+    of ``block_size``), ``dtype`` the pools' stored dtype and
+    ``kv_heads`` the heads one step serves.
 
     Same precedence as :func:`plan_matmul`: explicit knobs > autotune
-    cache (keyed ``sq_paged_attn:<rows>x<hd>x<block_size>:<dtype>``,
+    cache (keyed ``sq_paged_attn:<kv_heads>kv:<rows>x<hd>x<tile>:<dtype>``,
     served layout-matched) > the model pick.  The model pick mirrors the
     matmul kc rule: "mnk" caps the chunk at :data:`KC_MNK_MAX` (the
     measured interpret-mode sweet spot); "mkn" takes the full axis (the
-    rank-2 PM broadcast is widest-is-best on the VPU).  On a cache miss
-    the planner warns once per key; ``REPRO_AUTOTUNE=0`` silences.
+    rank-2 PM broadcast is widest-is-best on the VPU, and a chunked walk
+    would need lane-aligned chunks).  On a cache miss the planner warns
+    once per key; ``REPRO_AUTOTUNE=0`` silences.
 
     Fully-specified plans skip cache and model (each kc is still clamped
     to divide its axis)::
 
         >>> from repro.kernels import tuning
-        >>> tuning.plan_paged_attn(8, 64, 16, kc_qk=32, kc_pv=16,
+        >>> tuning.plan_paged_attn(8, 64, 128, kc_qk=32, kc_pv=16,
         ...                        pm_layout="mnk")
         PagedAttnPlan(kc_qk=32, kc_pv=16, pm_layout='mnk')
     """
     if kc_qk is not None and kc_pv is not None:
-        return PagedAttnPlan(_align_kc(kc_qk, hd), _align_kc(kc_pv,
-                                                             block_size),
+        return PagedAttnPlan(_align_kc(kc_qk, hd), _align_kc(kc_pv, tile),
                              pm_layout)
     use_cache = autotune_enabled()
-    key = _key("sq_paged_attn", rows, hd, block_size, dtype)
+    key = _paged_key(rows, hd, tile, kv_heads, dtype)
     cached = load_cache().get(key) if use_cache else None
     if cached is not None and kc_qk is None and kc_pv is None \
             and str(cached.get("pm_layout", pm_layout)) == pm_layout:
@@ -622,12 +635,12 @@ def plan_paged_attn(rows: int, hd: int, block_size: int,
                              pm_layout)
     if pm_layout == "mnk":
         base_qk = _align_kc(min(KC_MNK_MAX, hd), hd)
-        base_pv = _align_kc(min(KC_MNK_MAX, block_size), block_size)
+        base_pv = _align_kc(min(KC_MNK_MAX, tile), tile)
     else:
-        base_qk, base_pv = hd, block_size
+        base_qk, base_pv = hd, tile
     plan = PagedAttnPlan(
         _align_kc(kc_qk if kc_qk is not None else base_qk, hd),
-        _align_kc(kc_pv if kc_pv is not None else base_pv, block_size),
+        _align_kc(kc_pv if kc_pv is not None else base_pv, tile),
         pm_layout)
     if use_cache and cached is None and kc_qk is None and kc_pv is None:
         _note_cache_lookup(key, hit=False)
@@ -743,42 +756,44 @@ def autotune_conv2d(shapes: Iterable[tuple[int, int, int, int, int, int]],
     return cache
 
 
-def autotune_paged_attn(shapes: Iterable[tuple[int, int, int]],
+def autotune_paged_attn(shapes: Iterable[tuple[int, ...]],
                         dtype=jnp.float32, *, nb: int = 8,
                         pm_layouts: tuple[str, ...] = ("mnk", "mkn"),
                         reps: int = 3, path: Optional[str] = None,
                         verbose: bool = False) -> dict:
     """Sweep the fused paged-attention kc knobs; cache winners.
 
-    ``shapes`` holds (rows, hd, block_size) tuples -- the score-tile
-    geometry :func:`plan_paged_attn` keys on.  Timing is self-contained
-    (a synthetic single-sequence pool walked over ``nb`` table entries;
-    the contraction work per grid step is shape-exact, so the kc ranking
-    transfers to any batch/table length).  Winners land in the same JSON
-    cache the planner consults.
+    ``shapes`` holds (rows, hd, block_size) or (rows, hd, block_size,
+    kv_heads) tuples (one KV head by default).  Timing is self-contained:
+    a synthetic single-sequence pool of ``dtype`` walked over ``nb``
+    table entries, every one live, so each grid step does the
+    shape-exact contraction work of a full tile and the kc ranking
+    transfers to any batch/table length.  Winners land in the same JSON
+    cache the planner consults, keyed on the tile the kernel walks.
     """
     import time as _time
 
     import jax
     import numpy as np
 
-    from repro.kernels.sq_paged_attn import sq_paged_attn
+    from repro.kernels.sq_paged_attn import sq_paged_attn, tile_blocks
 
     cache = dict(load_cache(path))
-    for (rows, hd, block_size) in shapes:
+    for shape in shapes:
+        rows, hd, block_size, kv = (tuple(shape) + (1,))[:4]
+        tile = tile_blocks(block_size, nb) * block_size
         pool = nb * block_size
         rng = np.random.default_rng(0)
-        q = jnp.asarray(rng.normal(size=(1, rows, 1, 1, hd)), dtype)
-        kp = jnp.asarray(rng.normal(size=(pool, 1, hd)), dtype)
-        vp = jnp.asarray(rng.normal(size=(pool, 1, hd)), dtype)
+        q = jnp.asarray(rng.normal(size=(1, rows, kv, 1, hd)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(pool, kv, hd)), dtype)
+        vp = jnp.asarray(rng.normal(size=(pool, kv, hd)), dtype)
         tables = jnp.arange(nb, dtype=jnp.int32)[None, :]
         pos_pool = jnp.arange(pool, dtype=jnp.int32)
         q_pos = jnp.full((1, rows), pool - 1, jnp.int32)
         best, best_us = None, float("inf")
         for layout in pm_layouts:
             qk_cands = sorted({_align_kc(c, hd) for c in KC_CANDIDATES})
-            pv_cands = sorted({_align_kc(c, block_size)
-                               for c in KC_CANDIDATES})
+            pv_cands = sorted({_align_kc(c, tile) for c in KC_CANDIDATES})
             if layout == "mnk":
                 qk_cands = [c for c in qk_cands if c <= KC_MNK_MAX] or [1]
                 pv_cands = [c for c in pv_cands if c <= KC_MNK_MAX] or [1]
@@ -795,13 +810,13 @@ def autotune_paged_attn(shapes: Iterable[tuple[int, int, int]],
                            q_pos).block_until_ready()
                     us = (_time.perf_counter() - t0) / reps * 1e6
                     if verbose:
-                        print(f"  sq_paged_attn {rows}x{hd}x{block_size} "
+                        print(f"  sq_paged_attn {kv}kv {rows}x{hd}x{tile} "
                               f"kc_qk={kc_qk} kc_pv={kc_pv} {layout} "
                               f"-> {us:.1f}us")
                     if us < best_us:
                         best = PagedAttnPlan(kc_qk, kc_pv, layout)
                         best_us = us
-        cache[_key("sq_paged_attn", rows, hd, block_size, dtype)] = {
+        cache[_paged_key(rows, hd, tile, kv, dtype)] = {
             "kc_qk": best.kc_qk, "kc_pv": best.kc_pv,
             "pm_layout": best.pm_layout, "us_per_call": best_us,
         }

@@ -412,8 +412,9 @@ def _attn_paged_step(p, x, cache, pos, *, cfg, window, mode, policy, paged):
 
     - ``kernel`` -- the fused block-streaming Pallas kernel
       (:func:`repro.kernels.sq_paged_attn.sq_paged_attn`): block tables
-      are indexed inside the grid and the gathered window is never
-      materialized.  Guarded like every square-routed contraction: a
+      are indexed inside the grid, only each sequence's live columns are
+      read, straight from the stored pools, and the gathered window is
+      never materialized.  Guarded like every square-routed contraction: a
       non-finite output (eager only) trips the ``attn_paged`` route-health
       breaker and recomputes via the gather path.
     - ``gather`` -- ``paged_gather_indices`` + ``jnp.take`` materializes
@@ -480,11 +481,12 @@ def _attn_paged_step(p, x, cache, pos, *, cfg, window, mode, policy, paged):
         from repro.core import guards
         from repro.kernels import tuning
         from repro.kernels.ops import default_interpret
-        from repro.kernels.sq_paged_attn import sq_paged_attn
+        from repro.kernels.sq_paged_attn import sq_paged_attn, tile_blocks
         interp = default_interpret()
+        bs = paged["block_size"]
         plan = tuning.plan_paged_attn(
-            S * G, hd, paged["block_size"],
-            pm_layout="mnk" if interp else "mkn")
+            S * G, hd, tile_blocks(bs, paged["tables"].shape[1]) * bs,
+            k_pool.dtype, kv_heads=KV, pm_layout="mnk" if interp else "mkn")
         out = sq_paged_attn(
             qf, k_pool, v_pool, paged["tables"], paged["pos_pool"], pos,
             block_size=paged["block_size"], window=window,

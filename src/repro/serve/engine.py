@@ -87,6 +87,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import guards
+from repro.kernels.sq_paged_attn import walk_bounds
 from repro.models.attention import EMPTY_POS
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -212,6 +213,11 @@ class EngineMetrics:
     prefill_tokens: int = 0
     decode_steps: int = 0
     decode_slot_steps: int = 0    # sum of live slots over decode steps
+    # paged-attention table columns over the live slots of every decode
+    # step: those the fused kernel walks (its live range, hi - lo) and
+    # those the tables span (blocks_per_seq); host-side, from positions
+    paged_cols_walked: int = 0
+    paged_cols_spanned: int = 0
     prefill_chunks: int = 0
     preemptions: int = 0
     peak_blocks_used: int = 0
@@ -268,6 +274,13 @@ class EngineMetrics:
         return self.util_sum / self.util_steps if self.util_steps else 0.0
 
     @property
+    def paged_walk_share(self) -> float:
+        """Share of the decode steps' table columns the fused paged-
+        attention kernel walks (the rest it skips as dead)."""
+        return (self.paged_cols_walked / self.paged_cols_spanned
+                if self.paged_cols_spanned else 0.0)
+
+    @property
     def batch_occupancy(self) -> float:
         """Mean live slots per decode step (continuous-batching payoff)."""
         return (self.decode_slot_steps / self.decode_steps
@@ -281,6 +294,7 @@ class EngineMetrics:
             "mean_block_utilization": self.mean_utilization,
             "peak_blocks_used": self.peak_blocks_used,
             "batch_occupancy": self.batch_occupancy,
+            "paged_walk_share": self.paged_walk_share,
             "decode_steps": self.decode_steps,
             "prefill_chunks": self.prefill_chunks,
             "preemptions": self.preemptions,
@@ -342,6 +356,9 @@ class Engine:
         # to the pool (None: full-attention arch, or eviction disabled)
         self._evict_window = (eviction_window(model.cfg)
                               if cfg.window_eviction else None)
+        # the leading columns every layer's window has left: the fused
+        # paged-attention kernel walks none of them
+        self._walk_window = eviction_window(model.cfg)
 
         bs = cfg.block_size
 
@@ -887,6 +904,8 @@ class Engine:
                     toks[i, 0] = self.slots[i].last_tok
                     poss[i, 0] = self.slots[i].pos
                 tables = self._table_rows(slice(None))
+                lo, hi = walk_bounds(poss, cfg.block_size, self._walk_window)
+                walked = int((hi - lo).sum())
                 toks, poss = jnp.asarray(toks), jnp.asarray(poss)
                 if self._faults is not None:
                     self._faults.before_step("decode")
@@ -920,6 +939,9 @@ class Engine:
                 self.metrics.decode_steps += 1
                 self._c_work["decode_steps"].inc()
                 self.metrics.decode_slot_steps += len(live)
+                self.metrics.paged_cols_walked += walked
+                self.metrics.paged_cols_spanned += \
+                    len(live) * cfg.blocks_per_seq
                 for i in live:
                     if finite is not None and not finite[i]:
                         # fail THIS slot, not the batch: argmax over a

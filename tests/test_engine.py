@@ -592,3 +592,28 @@ def test_engine_uploads_block_tables_by_value():
                             + eng.metrics.decode_steps)
     for dev, at_dispatch in uploads:
         np.testing.assert_array_equal(np.asarray(dev), at_dispatch)
+
+
+@pytest.mark.parametrize("window,walked", [(None, 18), (6, 12)])
+def test_engine_counts_paged_columns_walked(window, walked):
+    """The decode steps' paged-attention columns, counted on the host:
+    prompts of 5 and 13 tokens, 4 new tokens each (the first from
+    prefill), 4-token blocks, 8-column tables.  The 3 decode steps of the
+    first request query positions 5, 6, 7 (columns [0, 2)); the second's
+    13, 14, 15 (columns [0, 4)): 3 * 2 + 3 * 4 = 18 walked.  A 6-token
+    window starts the second's walk at column (13 - 5) // 4 = 2, so 3 * 2
+    + 3 * 2 = 12.  Spanned: 6 slot-steps * 8 columns = 48."""
+    cfg, model, params = _model()
+    if window is not None:
+        cfg = dataclasses.replace(cfg, window=window)
+        model = build_model(cfg)
+    eng = Engine(model, params, EngineConfig(
+        max_slots=2, block_size=4, num_blocks=24, blocks_per_seq=8,
+        prefill_chunk=16, max_new_tokens=4))
+    reqs = [Request(0, list(range(1, 6))), Request(1, list(range(1, 14)))]
+    results = eng.run(reqs)
+    assert all(r.ok and len(r.tokens) == 4 for r in results.values())
+    m = eng.metrics
+    assert m.decode_slot_steps == 6
+    assert (m.paged_cols_walked, m.paged_cols_spanned) == (walked, 48)
+    assert m.summary()["paged_walk_share"] == pytest.approx(walked / 48)

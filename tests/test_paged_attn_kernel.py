@@ -8,6 +8,8 @@ row convention, same f32 accumulation -- because the serving engine flips
 between them purely on the cost model."""
 import dataclasses
 import functools
+import json
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +35,7 @@ def _no_autotune(monkeypatch):
 # ------------------------------------------------------------- fixtures
 
 def _setup(B=2, S=3, KV=2, G=2, hd=16, nb=4, block_size=4, n_ctx=None,
-           seed=0):
+           seed=0, dtype=np.float32):
     """Random pools + per-sequence block tables covering ``n_ctx`` tokens
     (default: the full table), queries at the last S positions."""
     rng = np.random.default_rng(seed)
@@ -54,8 +56,24 @@ def _setup(B=2, S=3, KV=2, G=2, hd=16, nb=4, block_size=4, n_ctx=None,
                     pos_pool[blk * block_size + j] = p
     q = rng.normal(size=(B, S, KV, G, hd)).astype(np.float32)
     q_pos = np.tile(np.arange(n - S, n), (B, 1)).astype(np.int32)
-    return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), jnp.asarray(pos_pool), jnp.asarray(q_pos))
+    return (jnp.asarray(q), jnp.asarray(k_pool, dtype),
+            jnp.asarray(v_pool, dtype), jnp.asarray(tables),
+            jnp.asarray(pos_pool), jnp.asarray(q_pos))
+
+
+def _evict(args, window, block_size):
+    """What windowed eviction leaves: leading table columns whose every
+    position has aged out of every query's window point at the null
+    block, and the freed blocks' positions are back to the sentinel."""
+    q, kp, vp, tables, pos_pool, q_pos = args
+    tables, pos_pool = np.array(tables), np.array(pos_pool)
+    for b in range(tables.shape[0]):
+        n_dead = max(0, (int(q_pos[b].min()) - window + 1) // block_size)
+        for blk in tables[b, :n_dead]:
+            pos_pool[blk * block_size:(blk + 1) * block_size] = \
+                attn.EMPTY_POS
+        tables[b, :n_dead] = 0
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(pos_pool), q_pos
 
 
 def _reference(q, k_pool, v_pool, tables, pos_pool, q_pos, *, block_size,
@@ -79,21 +97,154 @@ def _reference(q, k_pool, v_pool, tables, pos_pool, q_pos, *, block_size,
 
 # ------------------------------------------------------- kernel numerics
 
+# (setup, call) keyword sets: masks, chunking, and the live-column walk
+# (one live column, a whole 96-column table, leading columns a window
+# eviction nulled, padded query rows, 16- and 64-token blocks, bf16 pools)
+_CASES = {
+    "plain": ({}, {}),
+    "window4-kc8x2": ({}, dict(window=4, kc_qk=8, kc_pv=2)),
+    "softcap30-kc4x4": ({}, dict(softcap=30.0, kc_qk=4, kc_pv=4)),
+    "window6-softcap50-kc16x1": ({}, dict(window=6, softcap=50.0, kc_qk=16,
+                                          kc_pv=1)),
+    "one-live-column": (dict(S=1, nb=8, n_ctx=3), {}),
+    "all-96-columns": (dict(S=1, nb=96, block_size=16), {}),
+    "evicted-leading": (dict(S=2, nb=12, block_size=8, n_ctx=90),
+                        dict(window=20, evict=True)),
+    "s8-padded-rows": (dict(S=8, nb=6, n_ctx=21, pad=3), {}),
+    "softcap-bs16": (dict(S=1, nb=20, block_size=16, n_ctx=200),
+                     dict(softcap=30.0)),
+    "bs64": (dict(S=2, nb=5, block_size=64, n_ctx=300), {}),
+    "bf16-pools": (dict(S=1, nb=24, block_size=16, n_ctx=250,
+                        dtype=jnp.bfloat16), {}),
+}
+
+
 @pytest.mark.parametrize("pm_layout", ["mnk", "mkn"])
-@pytest.mark.parametrize("window,softcap,kc_qk,kc_pv", [
-    (None, 0.0, None, None),
-    (4, 0.0, 8, 2),
-    (None, 30.0, 4, 4),
-    (6, 50.0, 16, 1),
-])
-def test_kernel_matches_gather_reference(pm_layout, window, softcap,
-                                         kc_qk, kc_pv):
-    args = _setup()
-    out = sq_paged_attn(*args, block_size=4, window=window, softcap=softcap,
-                        kc_qk=kc_qk, kc_pv=kc_pv, pm_layout=pm_layout,
-                        interpret=True)
-    ref = _reference(*args, block_size=4, window=window, softcap=softcap)
+@pytest.mark.parametrize("case", list(_CASES))
+def test_kernel_matches_gather_reference(pm_layout, case):
+    setup, call = (dict(d) for d in _CASES[case])
+    pad = setup.pop("pad", 0)
+    bs = setup.get("block_size", 4)
+    args = _setup(**setup)
+    if call.pop("evict", False):
+        args = _evict(args, call["window"], bs)
+    if pad:                                   # padded query rows (-1)
+        args = (*args[:5], args[5].at[0, -pad:].set(-1))
+    out = sq_paged_attn(*args, block_size=bs, pm_layout=pm_layout,
+                        interpret=True, **call)
+    ref = _reference(*args, block_size=bs, window=call.get("window"),
+                     softcap=call.get("softcap", 0.0))
+    valid = np.asarray(args[5]) >= 0          # padding rows are discarded
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out)[valid],
+                               np.asarray(ref)[valid], atol=1e-4)
+
+
+def test_kernel_skips_poisoned_dead_columns():
+    """Table columns past each sequence's live range point at pool blocks
+    full of NaN: the kernel must not read them (read-and-masked would
+    give 0 * NaN in the PM form of p.v), so its output is finite and
+    equals the gather route over the live columns alone."""
+    KV, G, hd, bs, nb = 2, 2, 16, 16, 24
+    ctx = [5, 150, 320]                       # 1, 10 and 20 live columns
+    B = len(ctx)
+    rng = np.random.default_rng(7)
+    P = (1 + 2 * B * nb) * bs
+    k_pool = rng.normal(size=(P, KV, hd)).astype(np.float32)
+    v_pool = rng.normal(size=(P, KV, hd)).astype(np.float32)
+    pos_pool = np.full(P, attn.EMPTY_POS, np.int32)
+    tables = np.zeros((B, nb), np.int32)
+    live = np.zeros((B, nb), np.int32)
+    for b, n in enumerate(ctx):
+        blocks = 1 + 2 * b * nb + np.arange(nb)
+        tables[b] = blocks
+        for c, blk in enumerate(blocks):
+            sl = slice(blk * bs, (blk + 1) * bs)
+            if c * bs < n:                    # live: real positions
+                live[b, c] = blk
+                pos = c * bs + np.arange(bs)
+                pos_pool[sl] = np.where(pos < n, pos, attn.EMPTY_POS)
+            else:                             # dead: poisoned
+                k_pool[sl] = np.nan
+                v_pool[sl] = np.nan
+                pos_pool[sl] = c * bs + np.arange(bs)
+    q = jnp.asarray(rng.normal(size=(B, 1, KV, G, hd)), jnp.float32)
+    q_pos = jnp.asarray([[n - 1] for n in ctx], jnp.int32)
+    args = (q, jnp.asarray(k_pool), jnp.asarray(v_pool))
+    out = sq_paged_attn(*args, jnp.asarray(tables), jnp.asarray(pos_pool),
+                        q_pos, block_size=bs, interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    # the reference reads only live columns (dead ones -> null block)
+    ref = _reference(*args, jnp.asarray(live), jnp.asarray(pos_pool),
+                     q_pos, block_size=bs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_walk_fetches_only_live_blocks(window):
+    """Replay the pipeline's fetches: an operand's block is copied in
+    only when its index map names another block than at the previous
+    grid step.  Every block fetched for a sequence with a live query is
+    one of its live columns' blocks, each fetched once per operand that
+    reads it, and a sequence with none fetches one block per operand."""
+    from repro.kernels.sq_paged_attn import (_walk_tables, tile_blocks,
+                                             walk_bounds)
+    bs, nb = 16, 24
+    q_pos = np.array([[4], [149], [319], [-1], [383]], np.int32)
+    B = len(q_pos)
+    tables = 1 + np.arange(B * nb, dtype=np.int32).reshape(B, nb)
+    tpb = tile_blocks(bs, nb)
+    lo, hi = walk_bounds(q_pos, bs, window)
+    walk = np.asarray(_walk_tables(jnp.asarray(tables), jnp.asarray(lo),
+                                   jnp.asarray(hi), tpb))
+    for j in range(tpb):
+        prev = None
+        for i in range(B):
+            fetched = []
+            for s in range(-(-nb // tpb)):
+                blk = walk[i, min(s * tpb + j, nb - 1)]
+                if blk != prev:
+                    fetched.append(blk)
+                prev = blk
+            live = set(tables[i, lo[i]:hi[i]])
+            if live:
+                assert set(fetched) <= live, (i, j, fetched)
+                own = {tables[i, c] for c in range(lo[i], hi[i])
+                       if c % tpb == j}
+                assert len(fetched) == max(1, len(own)), (i, j, fetched)
+            else:
+                assert len(fetched) <= 1
+    if window is not None:
+        assert lo[4] == (383 - window + 1) // bs > 0
+
+
+def test_pools_enter_the_kernel_as_stored():
+    """The pallas_call takes the K/V pools in their stored dtype and
+    element count, and the wrapper widens or transposes no pool-sized
+    array on the way in."""
+    q, kp, vp, tb, pp, q_pos = _setup(S=1, nb=24, block_size=16,
+                                      n_ctx=200, dtype=jnp.bfloat16)
+    pool_n = kp.size
+    jaxpr = jax.make_jaxpr(functools.partial(
+        sq_paged_attn, block_size=16, interpret=True))(
+            q, kp, vp, tb, pp, q_pos)
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            if e.primitive.name == "pallas_call":
+                continue                      # the kernel body is VMEM work
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+
+    calls = [e for e in eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    pool_ops = [v.aval for v in calls[0].invars
+                if v.aval.size == pool_n and v.aval.ndim == 4]
+    assert pool_ops and all(a.dtype == jnp.bfloat16 for a in pool_ops)
+    for e in eqns(jaxpr.jaxpr):
+        if e.primitive.name in ("convert_element_type", "transpose"):
+            assert all(v.aval.size < pool_n for v in e.invars), e
 
 
 def test_kernel_partial_table_and_null_blocks():
@@ -184,16 +335,37 @@ def test_select_route_generic_and_unknown_kind():
         routing.set_route_override("attn", {}, "kernel")
 
 
-def test_plan_paged_attn():
-    p = tuning.plan_paged_attn(8, 64, 16, pm_layout="mnk")
-    assert p.kc_qk == tuning.KC_MNK_MAX and p.kc_pv == 16
-    p = tuning.plan_paged_attn(8, 64, 16, pm_layout="mkn")
-    assert (p.kc_qk, p.kc_pv) == (64, 16)        # full-axis chunks
-    p = tuning.plan_paged_attn(8, 64, 16, kc_qk=16, kc_pv=4)
+def test_plan_paged_attn(monkeypatch, tmp_path):
+    # the third axis is the tile the kernel walks per grid step (tokens)
+    p = tuning.plan_paged_attn(8, 64, 128, pm_layout="mnk")
+    assert p.kc_qk == tuning.KC_MNK_MAX and p.kc_pv == tuning.KC_MNK_MAX
+    p = tuning.plan_paged_attn(8, 64, 128, pm_layout="mkn")
+    assert (p.kc_qk, p.kc_pv) == (64, 128)       # full-axis chunks
+    p = tuning.plan_paged_attn(8, 64, 128, kc_qk=16, kc_pv=4)
     assert (p.kc_qk, p.kc_pv) == (16, 4)
     # explicit knobs are clamped to divide their axes
-    p = tuning.plan_paged_attn(8, 48, 12, kc_qk=32, kc_pv=8)
-    assert 48 % p.kc_qk == 0 and 12 % p.kc_pv == 0
+    p = tuning.plan_paged_attn(8, 48, 96, kc_qk=32, kc_pv=64)
+    assert 48 % p.kc_qk == 0 and 96 % p.kc_pv == 0
+    # an entry keyed on the one-head, one-block kernel's geometry is never
+    # served; one keyed on the tile and head count is
+    path = tmp_path / "cache.json"
+    path.write_text(json.dumps({
+        "sq_paged_attn:8x64x128:float32":
+            {"kc_qk": 8, "kc_pv": 8, "pm_layout": "mkn"},
+        "sq_paged_attn:4kv:8x64x128:bfloat16":
+            {"kc_qk": 32, "kc_pv": 64, "pm_layout": "mkn"}}))
+    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
+    tuning.clear_cache()
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")        # the expected miss
+            p = tuning.plan_paged_attn(8, 64, 128, kv_heads=1)
+        assert (p.kc_qk, p.kc_pv) == (64, 128)
+        p = tuning.plan_paged_attn(8, 64, 128, jnp.bfloat16, kv_heads=4)
+        assert (p.kc_qk, p.kc_pv) == (32, 64)
+    finally:
+        tuning.clear_cache()
 
 
 # ----------------------------------------------------- dispatch wiring

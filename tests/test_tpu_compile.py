@@ -11,12 +11,16 @@ the worker that runs this file loads the TPU library.  Every test passes
 ``interpret=False`` and the TPU layout ``pm_layout="mkn"`` explicitly,
 because code that asks ``jax.default_backend()`` still sees the CPU.
 """
+import dataclasses
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import cost_model as cm
@@ -90,19 +94,122 @@ def test_sq_conv2d_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def _row_major(shape, dtype, sharding):
+    """A described argument in the layout the engine keeps its pools in."""
+    fmt = Format(Layout(tuple(range(len(shape)))), sharding)
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=fmt)
+
+
+def _pool_moves(hlo, slots, block_size, row_elems):
+    """Relayouts, widenings and transposes of a K/V pool: copies,
+    converts and transposes (fused or not) whose output has the pool's
+    slot or block axis and at least half a pool's elements (``slots *
+    row_elems``).  A read straight from the pool has no use for them;
+    the asynchronous moves between memory spaces keep the layout."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]"
+                     r"\S*\s+([\w\-]+)\(", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        name, op = m.group(1), m.group(3)
+        moves = op in ("copy", "convert", "transpose") or (
+            op == "fusion" and name.startswith(("copy", "convert",
+                                                "transpose")))
+        if moves and set(dims) & {slots, slots // block_size} \
+                and math.prod(dims) * 2 >= slots * row_elems:
+            found.append(line.strip()[:160])
+    return found
+
+
 def test_sq_paged_attn_compiles_at_danube_geometry(one_chip):
+    from bench import hlo as bench_hlo
     d = DANUBE
     B, KV, G, hd, bs = d["B"], d["KV"], d["G"], d["hd"], d["block_size"]
     nb = d["blocks_per_seq"]
     pool = (1 + B * nb) * bs                 # null block + every table
     fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False,
                            pm_layout="mkn")
-    hlo = _compile_hlo(fn, ((B, 1, KV, G, hd), jnp.bfloat16),
-                       ((pool, KV, hd), jnp.bfloat16),
-                       ((pool, KV, hd), jnp.bfloat16),
-                       ((B, nb), jnp.int32), ((pool,), jnp.int32),
-                       ((B, 1), jnp.int32), sharding=one_chip)
+    args = [jax.ShapeDtypeStruct((B, 1, KV, G, hd), jnp.float32,
+                                 sharding=one_chip),
+            _row_major((pool, KV, hd), jnp.bfloat16, one_chip),
+            _row_major((pool, KV, hd), jnp.bfloat16, one_chip),
+            jax.ShapeDtypeStruct((B, nb), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((pool,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    # the pools enter the kernel as stored: nothing pool-sized is copied,
+    # widened or transposed on the way
+    assert not _pool_moves(hlo, pool, bs, KV * hd)
+    # the benchmark's HLO reader still counts the kernel's whole work
+    attn = [c for c in bench_hlo.parse(hlo).contractions
+            if c.kind == "sq_paged_attn"]
+    assert len(attn) == 1 and attn[0].count == 1
+    assert attn[0].flops == 4 * B * KV * G * hd * nb * bs
+
+
+@pytest.mark.parametrize("B,S,KV,G,hd,bs,nb,dtype", [
+    (8, 1, 32, 1, 128, 16, 96, jnp.bfloat16),    # MHA decode: one-row tiles
+    (2, 1, 1, 4, 128, 16, 12, jnp.bfloat16),     # one KV head (MQA)
+    (2, 8, 2, 2, 64, 64, 8, jnp.float32),        # S = 8, 64-token blocks
+    (4, 2, 4, 2, 128, 32, 16, jnp.bfloat16),     # 32-token blocks
+])
+def test_sq_paged_attn_compiles_across_geometries(one_chip, B, S, KV, G,
+                                                  hd, bs, nb, dtype):
+    pool = (1 + B * nb) * bs
+    fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False,
+                           pm_layout="mkn", window=bs * 3)
+    hlo = _compile_hlo(fn, ((B, S, KV, G, hd), jnp.float32),
+                       ((pool, KV, hd), dtype), ((pool, KV, hd), dtype),
+                       ((B, nb), jnp.int32), ((pool,), jnp.int32),
+                       ((B, S), jnp.int32), sharding=one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+def test_danube_decode_step_widens_no_pool(one_chip, monkeypatch):
+    """The whole 24-layer danube decode step, compiled as the engine jits
+    it, widens and transposes no K/V pool on the way into the paged
+    kernel: what it still moves of a pool is bf16, the relayouts between
+    the stored layout XLA picks (slot axis minor) and the row-major one
+    of the K/V scatter and the kernel."""
+    from repro.configs import get_config
+    from repro.configs.base import SQUARE_GEMMS_POLICY
+    from repro.models.lm import build_model
+    cfg = dataclasses.replace(get_config("h2o-danube-3-4b"),
+                              matmul_mode="square_pallas",
+                              contraction_policy=SQUARE_GEMMS_POLICY)
+    model = build_model(cfg)
+    B, nb, bs, nblk = 8, 96, 16, 769
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(model.prepare_params,
+                                      model.abstract_params()))
+    cache = described(jax.eval_shape(
+        lambda: model.init_paged_cache(nblk * bs)))
+
+    def decode(params, cache, pos_pool, tables, tokens, positions):
+        h, cache, pos_pool = model.decode_paged(
+            params, cache, tokens, positions, tables, pos_pool,
+            block_size=bs)
+        return model.logits(params, h)[:, -1], cache, pos_pool
+
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    # the kernels lower for the chip, not the CPU this test runs on
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    hlo = jax.jit(decode).lower(
+        params, cache, i32((nblk * bs,)), i32((B, nb)), i32((B, 1)),
+        i32((B, 1))).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    moves = _pool_moves(hlo, nblk * bs, bs,
+                        cfg.n_kv_heads * cfg.resolved_head_dim)
+    assert all(re.search(r"= bf16\[[\d,]*\]\S* copy\(", m) for m in moves), \
+        moves
 
 
 def test_planner_budget_edge_plan_compiles(one_chip):
