@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import arch, weights
 from bench import reference as ref
-from bench import weights
 
 __all__ = ["served_gaps", "served_numbers", "reference_logits"]
 
@@ -33,38 +33,44 @@ def _bucket(n: int) -> int:
 def reference_logits(cfg: dict, key, seqs: Sequence[np.ndarray],
                      ar=ref.F32) -> List[jax.Array]:
     """Reference logits (S, V) of each token sequence, drawing each
-    layer's weights once for all of them."""
+    layer's weights once for all of them.  The layers run in the
+    architecture's order, each with the block it names for it."""
+    mod = arch.of(cfg)
     frozen = tuple(sorted(cfg.items(), key=lambda kv: kv[0]))
-    layer_fn = jax.jit(functools.partial(_layer, frozen))
-    block_fn = jax.jit(functools.partial(_block, frozen, ar))
+    layer_fn = jax.jit(functools.partial(_layer, frozen), static_argnums=1)
     top = jax.jit(functools.partial(_top, frozen))(key)
-    kept = ref.stored(ar, top)
+    kept = ref.stored(ar, top, mod.KEPT)
     xs = []
     for s in seqs:
         toks = np.zeros(_bucket(len(s)), np.int32)
         toks[:len(s)] = s
-        xs.append(ar.store(ref.embed(cfg, kept, jnp.asarray(toks))))
-    for l in range(cfg["n_layers"]):
-        p = layer_fn(key, jnp.int32(l))
-        xs = [block_fn(p, x) for x in xs]
+        xs.append(ar.store(mod.embed(cfg, kept, jnp.asarray(toks))))
+    block_fns = {}
+    for l, (prefix, i) in enumerate(mod.layers(cfg)):
+        p = layer_fn(key, prefix, None if i is None else jnp.int32(i))
+        fn = mod.block(cfg, l)
+        if fn not in block_fns:
+            block_fns[fn] = jax.jit(functools.partial(_block, frozen, ar, fn))
+        xs = [block_fns[fn](p, x) for x in xs]
     head_fn = jax.jit(functools.partial(_head, frozen, ar))
     return [head_fn(top, x)[:len(s)] for x, s in zip(xs, seqs)]
 
 
-def _layer(frozen, key, l):
-    return weights.layer(dict(frozen), key, l)
+def _layer(frozen, key, prefix, i):
+    return weights.layer(dict(frozen), key, prefix, i)
 
 
 def _top(frozen, key):
     return weights.top(dict(frozen), key)
 
 
-def _block(frozen, ar, p, x):
-    return ref.block(dict(frozen), p, x, ar)
+def _block(frozen, ar, fn, p, x):
+    return fn(dict(frozen), p, x, ar)
 
 
 def _head(frozen, ar, top, x):
-    return ref.head(dict(frozen), top, x, ar)
+    cfg = dict(frozen)
+    return arch.of(cfg).head(cfg, top, x, ar)
 
 
 def served_gaps(cfg: dict, key,

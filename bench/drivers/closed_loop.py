@@ -213,6 +213,9 @@ def run(ctx: common.Context) -> common.Record:
                      first_token_at_s=[t - t_open for t in first_at],
                      served=[(len(p), len(o)) for p, o in seqs],
                      logit_gap_max=float(np.max(gaps)),
+                     unknown_kernels=sorted(
+                         {k for prog, _ in (rec.programs or {}).values()
+                          for k in prog.unknown}),
                      check_s=time.perf_counter() - t_check,
                      errors=stats.errors[:5])
     return rec

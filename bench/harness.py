@@ -3,7 +3,9 @@
 Everything that belongs to one configuration, traffic mix, cell or metric
 is a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
-- configuration: the ``file`` its entry names (``bench/configs/<name>.json``);
+- configuration: the ``file`` its entry names (``bench/configs/<name>.json``),
+                 whose ``arch`` names its architecture,
+                 ``bench/arch/<arch>.py``;
 - traffic mix:   ``bench/traffic/<traffic>.json``, whose ``kind`` names the
                  driver, ``bench/drivers/<kind>.py``;
 - limits:        ``bench/limits/<workload>.json``, the limits of the numbers
@@ -25,6 +27,8 @@ import os
 import sys
 import time
 from typing import Callable, Dict, List, Optional
+
+from bench import arch
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -120,6 +124,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t_process = time.perf_counter() if t_process is None else t_process
     bench = benchmark()
     entry, cfg, mix, limits = cell or load_cell(workload)
+    arch.of(cfg)                     # a missing architecture fails here
     metrics = cell_metrics(bench, workload, trace)
     readers = {m["name"]: metric_reader(m["name"]) for m in metrics}
     import jax

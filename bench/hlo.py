@@ -8,27 +8,32 @@ layers, multiply by their trip count), so that the benchmark can count
 operations and bytes per kernel from shapes alone.
 
 A Pallas call's kernel is named from its serialized Mosaic body, which
-carries the kernel function's name (``sq_matmul_kernel`` ...).
+carries the kernel function's name (``<name>_kernel``).  Each kernel
+the benchmark counts has a file of its own, ``bench/kernels/<name>.py``,
+found by listing that directory: ``KERNEL``, the function's name as the
+body carries it, and ``flops(operand_shapes, out_shapes)``, the call's
+operations from its operands' and outputs' ``(dtype, dims)``, or None where
+they are not the kernel's.  A Pallas call whose kernel has no file, or
+whose file cannot read it, is listed in :attr:`Program.unknown` and counts
+no operations.
 """
 from __future__ import annotations
 
 import base64
 import dataclasses
+import importlib.util
 import math
+import os
 import re
 from collections import defaultdict
+from types import ModuleType
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["Contraction", "Program", "parse", "KERNELS"]
+__all__ = ["Contraction", "Program", "parse", "counts", "KERNELS_DIR"]
 
-#: Pallas kernel function name -> the name the benchmark reports it under.
-KERNELS = {
-    b"sq_paged_attn_kernel": "sq_paged_attn",
-    b"sq_matmul_kernel": "sq_matmul",
-    b"cpm3_kernel": "cpm3_matmul",
-    b"cpm4_kernel": "cpm4_matmul",
-    b"sq_conv": "sq_conv",
-}
+#: Where the kernels' count files are.
+KERNELS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "kernels")
 
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\)\s*->\s*.*\{\s*$")
 _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
@@ -40,7 +45,7 @@ _ARRAY_RE = re.compile(r"^([a-z0-9]+)\[([0-9,]*)\]")
 class Contraction:
     """One contraction instruction of a program."""
     name: str                        # HLO instruction name
-    kind: str                        # "mxu" or a KERNELS value
+    kind: str                        # "mxu" or a kernel's file name
     flops: float                     # 2 * multiply-adds of one execution
     operands: Tuple[Tuple[int, ...], ...]   # operand shapes
     count: int                       # executions per program execution
@@ -51,6 +56,8 @@ class Program:
     contractions: List[Contraction]
     kernel_of: Dict[str, str]        # custom-call instruction -> kernel
     unknown_trip_counts: int = 0     # while loops counted once
+    unknown: List[str] = dataclasses.field(default_factory=list)
+    #                                  Pallas kernels not counted, by name
 
     def flops(self, kind: Optional[str] = None) -> float:
         return sum(c.flops * c.count for c in self.contractions
@@ -106,14 +113,33 @@ def _shape(type_: str) -> Optional[Tuple[str, Tuple[int, ...]]]:
     return m.group(1), dims
 
 
-def _kernel_name(attrs: str) -> str:
+def counts() -> Dict[str, ModuleType]:
+    """{kernel name: its count file's module}, one per ``<name>.py``."""
+    out = {}
+    for f in sorted(os.listdir(KERNELS_DIR)):
+        if f.endswith(".py") and not f.startswith("_"):
+            name = f[:-3]
+            spec = importlib.util.spec_from_file_location(
+                f"bench_kernel_{name}", os.path.join(KERNELS_DIR, f))
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            out[name] = mod
+    return out
+
+
+def _kernel_name(attrs: str, kernels: Dict[str, ModuleType]
+                 ) -> Tuple[str, bool]:
+    """(name, whether a count file names it) of a Pallas call's kernel:
+    the file whose ``KERNEL`` its body carries (the longest, should
+    several), else the ``*_kernel`` name the body carries."""
     m = re.search(r'"body":"([A-Za-z0-9+/=]+)"', attrs)
-    if m:
-        body = base64.b64decode(m.group(1))
-        for needle, name in KERNELS.items():
-            if needle in body:
-                return name
-    return "pallas_other"
+    body = base64.b64decode(m.group(1)) if m else b""
+    found = [(len(k.KERNEL), name) for name, k in kernels.items()
+             if k.KERNEL.encode() in body]
+    if found:
+        return max(found)[1], True
+    named = re.search(rb"[a-z][a-z0-9_]*_kernel", body)
+    return (named.group(0).decode() if named else "pallas_other"), False
 
 
 def _trip_count(cond: List[_Instr]) -> Optional[int]:
@@ -169,24 +195,16 @@ def _window(attrs: str, key: str, n: int) -> List[int]:
     return [int(v) for v in m.group(1).split("x")] if m else [1] * n
 
 
-def _pallas_flops(kernel: str, ops) -> float:
-    """Operations of one Pallas call, from its operand shapes."""
-    if kernel == "sq_matmul" and len(ops) >= 2:
-        a, b = ops[0], ops[1]
-        batch = math.prod(a[:-2])
-        return 2.0 * batch * a[-2] * a[-1] * b[-1]
-    if kernel == "sq_paged_attn" and len(ops) >= 5:
-        # (tables, q (B, KV, rows, hd), qpos, kt (nblk, KV, hd, bs), ...):
-        # q.k and p.v over every block the table walks
-        tables, q, kt = ops[0], ops[1], ops[3]
-        B, KV, rows, hd = q
-        T = tables[1] * kt[-1]
-        return 4.0 * B * KV * rows * hd * T
-    return 0.0
+def _types(type_: str) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
+    """(dtype, dims) of each array in an array or tuple type."""
+    return tuple((m.group(1), tuple(int(d) for d in m.group(2).split(",") if d))
+                 for m in re.finditer(r"([a-z][a-z0-9]*)\[([0-9,]*)\]", type_))
 
 
 def parse(text: str) -> Program:
-    """Contractions of one compiled HLO module (``compiled.as_text()``)."""
+    """Contractions of one compiled HLO module (``compiled.as_text()``),
+    each Pallas call counted by its kernel's count file."""
+    kernels = counts()
     comps: Dict[str, List[_Instr]] = {}
     entry = None
     cur: Optional[List[_Instr]] = None
@@ -209,10 +227,10 @@ def parse(text: str) -> Program:
 
     # executions of each computation per execution of the program
     mult: Dict[str, int] = defaultdict(int)
-    unknown = 0
+    unknown_trips = 0
 
     def visit(comp: str, n: int):
-        nonlocal unknown
+        nonlocal unknown_trips
         mult[comp] += n
         for ins in comps.get(comp, []):
             if ins.opcode == "while":
@@ -221,7 +239,7 @@ def parse(text: str) -> Program:
                 trips = _trip_count(comps.get(cond.group(1), [])) \
                     if cond else None
                 if trips is None:
-                    unknown += 1
+                    unknown_trips += 1
                     trips = 1
                 if body:
                     visit(body.group(1), n * trips)
@@ -240,6 +258,7 @@ def parse(text: str) -> Program:
 
     out: List[Contraction] = []
     kernel_of: Dict[str, str] = {}
+    unknown = set()                  # Pallas kernels not counted
     for comp, instrs in comps.items():
         n = mult.get(comp, 0)
         shapes = {}
@@ -250,16 +269,20 @@ def parse(text: str) -> Program:
         for ins in instrs:
             if ins.opcode == "custom-call" and \
                     'custom_call_target="tpu_custom_call"' in ins.attrs:
-                kernel = _kernel_name(ins.attrs)
+                kernel, known = _kernel_name(ins.attrs, kernels)
                 kernel_of[ins.name] = kernel
-                ops = tuple(shapes[o][1] for o in ins.operands if o in shapes)
-                if n:
-                    out.append(Contraction(ins.name, kernel,
-                                           _pallas_flops(kernel, ops), ops, n))
+                typed = tuple(shapes[o] for o in ins.operands if o in shapes)
+                f = kernels[kernel].flops(typed, _types(ins.type_)) \
+                    if known else None
+                if f is None:
+                    unknown.add(kernel)
+                elif n:
+                    out.append(Contraction(ins.name, kernel, float(f),
+                                           tuple(d for _, d in typed), n))
             elif ins.opcode in ("dot", "convolution") and n:
                 f = _contraction_flops(ins, shapes)
                 if f is not None:
                     ops = tuple(shapes[o][1] for o in ins.operands
                                 if o in shapes)
                     out.append(Contraction(ins.name, "mxu", f, ops, n))
-    return Program(out, kernel_of, unknown)
+    return Program(out, kernel_of, unknown_trips, sorted(unknown))

@@ -1,9 +1,10 @@
 """The system under test, as the benchmark calls it.
 
 The only module of the benchmark that imports the program (``src/repro``).
-It turns a configuration file into the program's ``ModelConfig``, checks
-that the benchmark's weight layout is the program's, and lists the
-compiled programs a window drives.
+It turns a configuration file into the program's ``ModelConfig`` from the
+keywords its architecture gives (``bench/arch/<arch>.py``), checks that
+the benchmark's weight layout is the program's, and lists the compiled
+programs a window drives.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ if os.path.join(ROOT, "src") not in sys.path:
 
 import jax  # noqa: E402
 
-from bench import weights  # noqa: E402
+from bench import arch, weights  # noqa: E402
 
 
 def model_config(cfg: dict):
@@ -27,10 +28,9 @@ def model_config(cfg: dict):
         raise KeyError(f"unknown contraction policy "
                        f"{cfg['contraction_policy']!r}")
     return ModelConfig(
-        name=cfg["name"], family="dense",
-        matmul_mode=cfg["matmul_mode"],
+        name=cfg["name"], matmul_mode=cfg["matmul_mode"],
         contraction_policy=policies[cfg["contraction_policy"]],
-        **weights.model(cfg))
+        **arch.of(cfg).model_kwargs(cfg))
 
 
 def build(cfg: dict):
@@ -39,7 +39,7 @@ def build(cfg: dict):
     from repro.models.lm import build_model
     mcfg = model_config(cfg)
     model = build_model(mcfg)
-    want = weights.layout(weights.model(cfg))
+    want = weights.layout(cfg)
     got = {"/".join(str(getattr(k, "key", k)) for k in path):
            (tuple(a.shape), str(a.dtype))
            for path, a in jax.tree_util.tree_flatten_with_path(

@@ -1,6 +1,6 @@
-"""The plain float32 reference against the program's own forward pass, for
-both architectures it describes, on the CPU at the registry's reduced
-size."""
+"""The plain float32 reference of the dense architecture
+(``bench/arch/dense.py``) against the program's own forward pass, for both
+models it describes, on the CPU at the registry's reduced size."""
 import functools
 
 import jax
@@ -8,17 +8,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import checks, program, reference, weights
+from bench import arch, checks, program, reference, weights
 from repro.configs import get_config
 
 ARCHS = ["h2o-danube-3-4b", "starcoder2-3b"]
+DENSE = arch.load("dense")
 
 
-def _cfg(arch):
-    reg = get_config(arch).reduced()
-    cfg = {k: getattr(reg, k) for k in weights.MODEL_KEYS}
+def _cfg(name):
+    reg = get_config(name).reduced()
+    cfg = {k: getattr(reg, k) for k in DENSE.MODEL_KEYS}
     cfg["head_dim"] = reg.resolved_head_dim
-    return dict(cfg, name=arch, matmul_mode="standard",
+    return dict(cfg, arch="dense", name=name, matmul_mode="standard",
                 contraction_policy=None)
 
 
@@ -31,9 +32,10 @@ def test_layer_draws_match_the_whole_tree():
     cfg = weights.model(_cfg("starcoder2-3b"))
     key = weights.base_key(2 ** 34 + 9)
     tree = jax.jit(functools.partial(weights.init, cfg))(key)
-    layer = jax.jit(functools.partial(weights.layer, cfg))
-    for l in range(cfg["n_layers"]):
-        one = layer(key, l)
+    layer = jax.jit(functools.partial(weights.layer, cfg), static_argnums=1)
+    for l, (prefix, i) in enumerate(DENSE.layers(cfg)):
+        assert (prefix, i) == (DENSE.STACK, l)
+        one = layer(key, prefix, i)
         assert np.array_equal(one["attn/wq/w"],
                               tree["scan"]["pos0"]["attn"]["wq"]["w"][l])
         assert np.array_equal(one["ln2/bias"],
@@ -42,9 +44,9 @@ def test_layer_draws_match_the_whole_tree():
     assert np.array_equal(top["embed/table"], tree["embed"]["table"])
 
 
-@pytest.mark.parametrize("arch", ARCHS)
-def test_reference_logits_match_the_program(arch, monkeypatch):
-    cfg = _cfg(arch)
+@pytest.mark.parametrize("name", ARCHS)
+def test_reference_logits_match_the_program(name, monkeypatch):
+    cfg = _cfg(name)
     if cfg["norm"] == "rmsnorm":
         # the program's RMSNorm epsilon (1e-6) departs from the published
         # 1e-5 the reference uses; match it so the rest is compared tightly

@@ -13,7 +13,7 @@ import sys
 import numpy as np
 import pytest
 
-from bench import flops, harness, hlo, stats, trace, traffic, weights
+from bench import arch, flops, harness, hlo, program, stats, trace, traffic
 from repro.configs import get_config
 
 ROOT = harness.ROOT
@@ -145,10 +145,11 @@ def test_matmul_least_time_on_known_shapes():
 def test_model_operations_from_shapes():
     cfg = json.load(open(os.path.join(ROOT, "bench", "configs",
                                       "danube3-4b-sq.json")))
+    dense = arch.of(cfg)
     per_layer = 3840 * (32 + 16) * 120 + 32 * 120 * 3840 + 3 * 3840 * 10240
-    assert flops.matmul_params(cfg) == 24 * per_layer
-    assert flops.attention_flops(cfg, 100) == 4 * 24 * 32 * 120 * 100
-    assert flops.attention_flops(cfg, 10000) == flops.attention_flops(cfg,
+    assert dense.matmul_params(cfg) == 24 * per_layer
+    assert dense.attention_flops(cfg, 100) == 4 * 24 * 32 * 120 * 100
+    assert dense.attention_flops(cfg, 10000) == dense.attention_flops(cfg,
                                                                       4096)
     assert flops.forward_flops(cfg, 1, True) == pytest.approx(
         2 * 24 * per_layer + 4 * 24 * 32 * 120 + 2 * 3840 * 32000)
@@ -311,23 +312,26 @@ CONFIGS = sorted(os.listdir(os.path.join(ROOT, "bench", "configs")))
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_config_files_match_the_registry(name):
+    """Every key the architecture hands the program's ``ModelConfig`` is the
+    registry's, unless the configuration lists it in ``reduced`` with its
+    published value; no width is ever reduced."""
     cfg = json.load(open(os.path.join(ROOT, "bench", "configs", name)))
     reg = get_config(cfg["registry"])
+    mod = arch.of(cfg)
     assert name == cfg["name"] + ".json" and cfg["source"] and cfg["published"]
     for conf in BENCH["configs"]:
         if conf["name"] == cfg["name"]:
             assert conf["reduced"] == cfg["reduced"]
             assert conf["file"] == "bench/configs/" + name
-    for k in weights.MODEL_KEYS:
-        if k == "head_dim":
-            assert cfg[k] == reg.resolved_head_dim
-        elif k in cfg["reduced"]:
-            assert cfg[k] != getattr(reg, k) or k in cfg.get("published", {})
+    mc = program.model_config(cfg)
+    for k in mod.model_kwargs(cfg):
+        want = getattr(reg, "resolved_" + k, getattr(reg, k))
+        if k in cfg["reduced"]:
+            assert getattr(mc, k) != want or k in cfg.get("published", {})
         else:
-            assert cfg[k] == getattr(reg, k), k
-    widths = ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff")
-    assert not set(cfg["reduced"]) & set(widths)
+            assert getattr(mc, k) == want, k
     for k in cfg["reduced"]:
+        assert k not in mod.WIDTHS and not k.endswith(("_dim", "_rank")), k
         assert k in cfg.get("published", {})
 
 

@@ -1,11 +1,8 @@
 """The benchmark's cells cut to a size the CPU runs in seconds: the same
-traffic kinds, drivers, checks and limits, with a two-layer model of the
-same architecture (float32, so the program and the reference agree to
-rounding)."""
-from bench import harness
-
-SMALL = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-             d_ff=128, vocab=512, window=64, dtype="float32")
+traffic kinds, drivers, checks and limits, with a small model of the same
+architecture, its module's ``TINY`` (float32, so the program and the
+reference agree to rounding)."""
+from bench import arch, harness
 
 SERVE = "danube3-4b-sq.reason"
 
@@ -18,7 +15,7 @@ def serve_cell():
                engine=dict(max_slots=2, block_size=8, prefill_chunk=8,
                            blocks_per_seq=8, num_blocks=17,
                            max_new_tokens=24))
-    return entry, dict(cfg, **SMALL), mix, limits
+    return entry, dict(cfg, **arch.of(cfg).TINY), mix, limits
 
 
 def run(workload, cell, hooks=None, seed=2 ** 33 + 5, seconds=0.5):

@@ -1,48 +1,33 @@
-"""Seeded weights of a dense decoder LM, made by the benchmark.
-
-Matrices are normal with variance 1/fan-in.  The program ties its input
-and output embeddings and scales the input by sqrt(d), so a table at
-1/sqrt(d) would put each input token's own embedding a unit-RMS share of
-the residual stream and make that token the top logit by many standard
-deviations: greedy decoding would copy its input.  The table is drawn at
-sqrt(L)/d instead, which leaves the input embedding about 1/sqrt(d) of a
-stream that L unit-RMS layers build, and spreads the logits like the ones
-a model ranks (their standard deviation is sqrt(L/d)).  Every weight
-stays small enough that a bf16 leaf registers an AdamW step of 1e-4.
+"""Seeded weights, made by the benchmark, in the program's layout.
 
 Every leaf is drawn from its own key, ``fold_in(fold_in(key, crc(path)),
 layer)``, so one layer's weights can be drawn again on their own: the
 system under test gets the whole tree from one jitted call, in the dtype
 it serves, and the plain reference draws the same values a layer at a
-time.  Leaves are named as the program's parameter tree names them; the
-harness checks the layout below against the program's abstract tree, so a
-change of the program's layout fails loudly instead of feeding it other
-numbers.
+time.  ``layer`` is the leaf's position along its stack's layer axis, 0
+for a leaf that has none.  The layout, the layers and each leaf's
+distribution are the architecture's (``bench/arch/<arch>.py``); leaves
+are named as the program's parameter tree names them, and the harness
+checks the layout against the program's abstract tree, so a change of the
+program's layout fails loudly instead of feeding it other numbers.
 """
 from __future__ import annotations
 
-import math
 import zlib
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["base_key", "model", "layout", "init", "layer", "top", "STACK",
-           "MODEL_KEYS"]
+from bench import arch
 
-#: Prefix of the leaves stacked over layers (one period of one block kind).
-STACK = "scan/pos0/"
-
-#: The keys of a configuration file that fix the model's arithmetic.
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab", "activation", "norm", "rope_theta", "window",
-              "attn_bias", "ffn_bias", "tie_embeddings", "dtype")
+__all__ = ["base_key", "model", "layout", "init", "layer", "top"]
 
 
 def model(cfg: dict) -> dict:
-    """The model keys of a configuration file, and nothing else."""
-    return {k: cfg[k] for k in MODEL_KEYS}
+    """The keys of a configuration file that fix the model's arithmetic,
+    its architecture among them, and nothing else."""
+    return {"arch": cfg["arch"], **{k: cfg[k] for k in arch.of(cfg).MODEL_KEYS}}
 
 
 def base_key(seed: int):
@@ -56,33 +41,7 @@ def base_key(seed: int):
 def layout(cfg: dict) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     """path -> (shape, dtype) of every leaf, stacked leaves with the layer
     axis first."""
-    d, H, KV, hd = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"], \
-        cfg["head_dim"]
-    f, L, V = cfg["d_ff"], cfg["n_layers"], cfg["vocab"]
-    dt = cfg["dtype"]
-    V = V + (-V) % 256                   # the program pads its vocab table
-    norm = ("scale", "bias") if cfg["norm"] == "layernorm" else ("scale",)
-    out = {"embed/table": ((V, d), dt)}
-    for n in norm:
-        out[f"final_norm/{n}"] = ((d,), "float32")
-        out[f"{STACK}ln1/{n}"] = ((L, d), "float32")
-        out[f"{STACK}ln2/{n}"] = ((L, d), "float32")
-    heads = {"wq": H, "wk": KV, "wv": KV}
-    for nm, nh in heads.items():
-        out[f"{STACK}attn/{nm}/w"] = ((L, d, nh, hd), dt)
-        if cfg["attn_bias"]:
-            out[f"{STACK}attn/{nm}/b"] = ((L, nh, hd), dt)
-    out[f"{STACK}attn/wo/w"] = ((L, H, hd, d), dt)
-    if cfg["attn_bias"]:
-        out[f"{STACK}attn/wo/b"] = ((L, d), dt)
-    mats = {"w_up": (d, f), "w_down": (f, d)}
-    if cfg["activation"] in ("swiglu", "geglu"):
-        mats["w_gate"] = (d, f)
-    for nm, (a, b) in mats.items():
-        out[f"{STACK}ffn/{nm}/w"] = ((L, a, b), dt)
-        if cfg["ffn_bias"]:
-            out[f"{STACK}ffn/{nm}/b"] = ((L, b), dt)
-    return out
+    return arch.of(cfg).layout(cfg)
 
 
 def _draw(cfg: dict, key, path: str, layer_id, shape, dtype):
@@ -90,22 +49,7 @@ def _draw(cfg: dict, key, path: str, layer_id, shape, dtype):
     k = jax.random.fold_in(jax.random.fold_in(
         key, zlib.crc32(path.encode()) & 0x7FFFFFFF), layer_id)
     z = jax.random.normal(k, shape, jnp.float32)
-    name = path.rsplit("/", 2)
-    if path.endswith("/scale"):          # norm weight is 1 + scale (rms)
-        z = z * 0.1 + (1.0 if cfg["norm"] == "layernorm" else 0.0)
-    elif path.endswith("/bias") or path.endswith("/b"):
-        z = z * 0.02
-    elif path == "embed/table":
-        z = z * math.sqrt(cfg["n_layers"]) / cfg["d_model"]
-    elif name[-2] == "wo":
-        z = z / math.sqrt(cfg["n_heads"] * cfg["head_dim"])
-    elif name[-2] == "w_down":
-        z = z / math.sqrt(cfg["d_ff"])
-    elif path.endswith("/w"):
-        z = z / math.sqrt(cfg["d_model"])
-    else:
-        raise KeyError(f"no draw rule for leaf {path!r}")
-    return z.astype(dtype)
+    return arch.of(cfg).draw(cfg, path, z).astype(dtype)
 
 
 def _nest(flat: Dict[str, jax.Array]) -> dict:
@@ -119,14 +63,22 @@ def _nest(flat: Dict[str, jax.Array]) -> dict:
     return tree
 
 
+def _prefixes(cfg: dict):
+    """(prefixes of stacked layers, prefixes of layers of their own)."""
+    lay = arch.of(cfg).layers(cfg)
+    return ({p for p, i in lay if i is not None},
+            {p for p, i in lay if i is None})
+
+
 def init(cfg: dict, key) -> dict:
     """The whole tree in the program's layout and stored dtypes.  Call
     under ``jax.jit`` (with ``cfg`` static) to make it on the device in
     one program; stacked leaves are drawn a layer at a time, so no leaf is
     ever whole in float32."""
+    stacked, _ = _prefixes(cfg)
     flat = {}
     for path, (shape, dt) in layout(cfg).items():
-        if path.startswith(STACK):
+        if path.startswith(tuple(stacked)):
             one = lambda l, p=path, s=shape[1:], d=dt: _draw(cfg, key, p, l, s, d)
             flat[path] = jax.lax.map(one, jnp.arange(shape[0]))
         else:
@@ -134,14 +86,21 @@ def init(cfg: dict, key) -> dict:
     return _nest(flat)
 
 
-def layer(cfg: dict, key, l) -> Dict[str, jax.Array]:
-    """Layer ``l``'s leaves, float32, keyed by path without the stack
-    prefix (``attn/wq/w`` ...)."""
-    return {p[len(STACK):]: _draw(cfg, key, p, l, s[1:], dt).astype(jnp.float32)
-            for p, (s, dt) in layout(cfg).items() if p.startswith(STACK)}
+def layer(cfg: dict, key, prefix: str, i: Optional[jax.Array] = None
+          ) -> Dict[str, jax.Array]:
+    """One layer's leaves, float32, keyed by path without ``prefix``
+    (``attn/wq/w`` ...): position ``i`` of the stack under ``prefix``, or
+    with ``i`` None the leaves of a layer of its own.  ``(prefix, i)`` is
+    the layer's entry in the architecture's ``layers``."""
+    return {p[len(prefix):]: _draw(cfg, key, p, 0 if i is None else i,
+                                   s if i is None else s[1:], dt
+                                   ).astype(jnp.float32)
+            for p, (s, dt) in layout(cfg).items() if p.startswith(prefix)}
 
 
 def top(cfg: dict, key) -> Dict[str, jax.Array]:
-    """The leaves outside the layer stack, float32."""
+    """The leaves outside every layer, float32."""
+    stacked, own = _prefixes(cfg)
+    inside = tuple(stacked | own)
     return {p: _draw(cfg, key, p, 0, s, dt).astype(jnp.float32)
-            for p, (s, dt) in layout(cfg).items() if not p.startswith(STACK)}
+            for p, (s, dt) in layout(cfg).items() if not p.startswith(inside)}
