@@ -43,6 +43,9 @@ CONTRACTION_SITES = (
     "recurrent_mix",    # recurrent state-mix contractions (scan bodies)
     "recurrent_proj",   # recurrent block dense projections
     "attn_paged",       # fused paged-attention read (serving decode path)
+    "mla_absorb",       # latent attention: q_nope through W_UK into the latent
+    "mla_unabsorb",     # latent attention: latent output through W_UV
+    "moe_shared",       # MoE shared experts (one SwiGLU)
 )
 
 # The custom VJP of fs_einsum re-enters the dispatcher for both backward
@@ -91,7 +94,7 @@ class ContractionPolicy:
     >>> ContractionPolicy.of(attn_scroes="standard")   # typo fails loudly
     Traceback (most recent call last):
         ...
-    ValueError: unknown contraction site(s) ['attn_scroes']; expected names from ('dense', 'attn_qkv', 'attn_out', 'attn_scores', 'attn_pv', 'ffn', 'moe_router', 'moe_expert', 'logits', 'loss', 'recurrent_gates', 'recurrent_mix', 'recurrent_proj', 'attn_paged'), optionally suffixed with ('.bwd_x', '.bwd_w')
+    ValueError: unknown contraction site(s) ['attn_scroes']; expected names from ('dense', 'attn_qkv', 'attn_out', 'attn_scores', 'attn_pv', 'ffn', 'moe_router', 'moe_expert', 'logits', 'loss', 'recurrent_gates', 'recurrent_mix', 'recurrent_proj', 'attn_paged', 'mla_absorb', 'mla_unabsorb', 'moe_shared'), optionally suffixed with ('.bwd_x', '.bwd_w')
     """
     overrides: Tuple[Tuple[str, str], ...] = ()
     default: Optional[str] = None
@@ -155,10 +158,31 @@ class ModelConfig:
     ffn_bias: bool = False
     attn_logit_softcap: float = 0.0
     tie_embeddings: bool = True
+    embed_scale: bool = True         # multiply input embeddings by sqrt(d)
+    norm_eps: float = 1e-6           # RMSNorm epsilon
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # the router's width (published count)
     topk: int = 0
     capacity_factor: float = 1.25
+    # experts this device holds (0: all n_experts, the capacity path of
+    # models.moe.moe_apply_local); > 0 selects the dropless held path
+    # (models.moe.moe_apply_held), which computes only the picks that land
+    # on experts [0, experts_held) and leaves the rest to other devices
+    experts_held: int = 0
+    # softmax (the capacity path) | sigmoid with a choice-only bias (the
+    # held path, DeepSeek-V3's noaux_tc)
+    router_scoring: str = "softmax"
+    norm_topk: bool = True           # renormalise the chosen gate weights
+    routed_scale: float = 1.0        # routed experts' output scale
+    n_shared_experts: int = 0        # shared experts, one SwiGLU of n * d_ff
+    # --- leading dense layers before the scanned period ---
+    first_k_dense: int = 0
+    dense_d_ff: int = 0              # their SwiGLU width (0: d_ff)
+    # --- latent attention (MLA); kv_lora_rank 0 = plain attention ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- layer pattern (cycled): attn | moe | mlstm | slstm | rglru | lattn ---
     block_pattern: Tuple[str, ...] = ("attn",)
     # --- recurrent (rg-lru / conv) ---
@@ -194,13 +218,21 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def padded_vocab(self) -> int:
         return pad_vocab(self.vocab)
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
+        """Every layer's kind: ``first_k_dense`` leading ``attn`` layers,
+        then the pattern cycled over the rest."""
         pat = self.block_pattern
-        return tuple(pat[i % len(pat)] for i in range(self.n_layers))
+        k = self.first_k_dense
+        return ("attn",) * k + tuple(pat[i % len(pat)]
+                                     for i in range(self.n_layers - k))
 
     @property
     def is_subquadratic(self) -> bool:
@@ -220,9 +252,10 @@ class ModelConfig:
         """Smoke-test config of the same family (runs a fwd/train step on CPU)."""
         pat_len = len(self.block_pattern)
         n_layers = max(pat_len, 2 if pat_len == 1 else pat_len)
+        mla = self.is_mla
         return dataclasses.replace(
             self,
-            n_layers=n_layers,
+            n_layers=n_layers + min(self.first_k_dense, 1),
             d_model=64,
             n_heads=max(2, min(4, self.n_heads)),
             n_kv_heads=max(1, min(2, self.n_kv_heads)),
@@ -231,6 +264,13 @@ class ModelConfig:
             vocab=512,
             n_experts=4 if self.n_experts else 0,
             topk=2 if self.topk else 0,
+            experts_held=4 if self.experts_held else 0,
+            first_k_dense=min(self.first_k_dense, 1),
+            dense_d_ff=128 if self.dense_d_ff else 0,
+            kv_lora_rank=32 if mla else 0,
+            qk_nope_head_dim=16 if mla else 0,
+            qk_rope_head_dim=8 if mla else 0,
+            v_head_dim=16 if mla else 0,
             # drop-free at smoke scale: capacity drops would make
             # prefill+decode legitimately diverge from the full forward
             capacity_factor=8.0 if self.n_experts else self.capacity_factor,

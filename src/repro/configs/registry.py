@@ -58,12 +58,24 @@ whisper_large_v3 = ModelConfig(
     encoder_layers=32, encoder_seq=1500,     # conv frontend stubbed: frames in
     source="arXiv:2212.04356 (enc-dec; conv frontend stub per spec)")
 
-moonshot_v1_16b_a3b = ModelConfig(
-    name="moonshot-v1-16b-a3b", family="moe",
-    n_layers=48, d_model=2048, n_heads=16, n_kv_heads=16, d_ff=1408,
-    vocab=163840, head_dim=128, activation="swiglu",
-    n_experts=64, topk=6, block_pattern=("moe",),
-    source="hf:moonshotai/Moonlight-16B-A3B (64e top-6)")
+# Moonlight-16B-A3B (DeepSeek-V3 layout): latent attention with no query
+# LoRA, one leading dense SwiGLU layer, then MoE layers of 64 routed
+# experts picked top-6 by sigmoid scores (a choice-only correction bias,
+# normalised, scaled by 2.446) plus 2 shared experts.
+moonlight_16b_a3b = ModelConfig(
+    name="moonlight-16b-a3b", family="moe",
+    n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, d_ff=1408,
+    vocab=163840, activation="swiglu", rope_theta=50000.0,
+    tie_embeddings=False, embed_scale=False, norm_eps=1e-5,
+    max_seq=8192,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128,
+    first_k_dense=1, dense_d_ff=11264,
+    n_experts=64, experts_held=64, topk=6, router_scoring="sigmoid",
+    norm_topk=True, routed_scale=2.446, n_shared_experts=2,
+    block_pattern=("moe",),
+    source="hf:moonshotai/Moonlight-16B-A3B config.json (deepseek_v3: "
+           "MLA, 64 routed experts top-6 sigmoid noaux_tc, 2 shared)")
 
 mixtral_8x7b = ModelConfig(
     name="mixtral-8x7b", family="moe",
@@ -90,7 +102,7 @@ fairsquare_demo = ModelConfig(
 
 ARCHS = {c.name: c for c in [
     paligemma_3b, xlstm_350m, h2o_danube_3_4b, command_r_35b, deepseek_7b,
-    starcoder2_3b, whisper_large_v3, moonshot_v1_16b_a3b, mixtral_8x7b,
+    starcoder2_3b, whisper_large_v3, moonlight_16b_a3b, mixtral_8x7b,
     recurrentgemma_2b, fairsquare_demo,
 ]}
 

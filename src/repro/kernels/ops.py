@@ -32,12 +32,13 @@ from repro.core import squares as sq
 from repro.core.prepared import PreparedOperand
 from repro.kernels import tuning
 from repro.kernels.sq_matmul import sq_matmul_pallas, sq_matmul_batched_pallas
+from repro.kernels.sq_grouped_matmul import sq_grouped_matmul_pallas
 from repro.kernels.cpm3_matmul import cpm3_matmul_pallas
 from repro.kernels.cpm4_matmul import cpm4_matmul_pallas
 from repro.kernels.sq_conv import sq_conv_pallas
 from repro.kernels.sq_conv2d import sq_conv2d_pallas
 
-__all__ = ["sq_matmul", "cpm3_matmul", "cpm4_matmul", "sq_conv", "sq_conv2d",
+__all__ = ["sq_matmul", "sq_grouped_matmul", "cpm3_matmul", "cpm4_matmul", "sq_conv", "sq_conv2d",
            "sq_conv2d_im2col", "sq_conv2d_routed", "prepare_matmul_rhs",
            "prepare_conv2d_weights", "default_interpret"]
 
@@ -299,6 +300,57 @@ def sq_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
         b = (jnp.swapaxes(prep.source, -1, -2) if prep.transposed
              else prep.source)
     return _sq_matmul_impl(a, b, plan, interpret_r)
+
+
+def _lane_divisor(b: int, extent: int) -> int:
+    """The largest whole-lane tile <= ``b`` that divides ``extent``, else
+    the whole extent (a block that spans its dim is always legal)."""
+    if extent % b == 0:
+        return b
+    for c in range(b - b % tuning.LANE, 0, -tuning.LANE):
+        if extent % c == 0:
+            return c
+    return extent
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "plan", "interpret"))
+def _sq_grouped_exec(x, w, tile_expert, n_used, bm, plan, interpret):
+    xw = x.astype(jnp.float32)
+    sa = sq.row_correction(xw, axis=-1)[:, None]                 # (R, 1)
+    sb = sq.col_correction(w.astype(jnp.float32), axis=-2)[:, None, :]
+    return sq_grouped_matmul_pallas(
+        xw, w, sa, sb, tile_expert, n_used, bm=bm, bn=plan.bn, bk=plan.bk,
+        kc=plan.kc, pm_layout=plan.pm_layout, interpret=interpret)
+
+
+def sq_grouped_matmul(x, w, tile_expert, n_used, *, bm: int,
+                      interpret: bool | None = None):
+    """Grouped square matmul: row tile ``t`` of ``x`` times the weights of
+    expert ``tile_expert[t]`` (:mod:`repro.kernels.sq_grouped_matmul`).
+
+    x: (R, K) rows sorted by expert in ``bm``-row tiles
+    (:func:`repro.kernels.sq_grouped_matmul.group_rows`); w: (E, K, N)
+    float weights, read in their stored dtype; tile_expert: (R // bm,)
+    int32; n_used: (1,) int32 tiles that hold rows.  Returns (R, N) f32;
+    rows of tiles past ``n_used`` are undefined.  Column and K tiles come
+    from the matmul planner at ``m = bm``, moved to divisors of N and K
+    so the weights are never padded.
+    """
+    interpret = default_interpret() if interpret is None else interpret
+    if not jnp.issubdtype(w.dtype, jnp.floating):
+        raise ValueError(f"sq_grouped_matmul is float-only, got {w.dtype}")
+    R, K = x.shape
+    N = w.shape[-1]
+    if R % bm:
+        raise ValueError(f"{R} rows are not whole {bm}-row tiles")
+    base = _resolve_plan(bm, N, K, jnp.float32, bm=None, bn=None, bk=None,
+                         kc=None, pm_layout=None, interpret=interpret,
+                         kind="sq_grouped_matmul")
+    bn, bk = _lane_divisor(base.bn, N), _lane_divisor(base.bk, K)
+    kc = bk if base.pm_layout == "mkn" and base.kc == base.bk \
+        else tuning._align_kc(base.kc, bk)
+    plan = tuning.TilePlan(bm, bn, bk, kc, base.pm_layout)
+    return _sq_grouped_exec(x, w, tile_expert, n_used, bm, plan, interpret)
 
 
 # --------------------------------------------------------------------------

@@ -58,6 +58,14 @@ position from ``pos_pool`` (causal ``kv_pos <= q_pos``, the never-attend
 sentinel bound, and the optional sliding-window distance) -- identical
 semantics to the gather path for every row with a valid key.
 
+Latent mode (``v_pool=None``, multi-head latent attention): one pool
+holds each token's ``Dk``-wide row, read as the key of every query row
+and, in its first ``Dv`` columns, as the value -- V is taken from the K
+tile already in VMEM, so each latent block crosses HBM once.  The query
+rows of all heads ride one latent "KV head" (``KV = 1``, ``G`` = heads),
+q.k runs over ``Dk`` and p.v over ``Dv``, and the output's last dim is
+``Dv``.  With a V pool, ``Dv`` is the head dim and nothing changes.
+
 Float-only: the softmax path is inherently floating-point (the int8
 square datapath stops at the logits).  Operands are taken in any float
 dtype and computed in f32, matching the gather path's accumulation.
@@ -125,24 +133,28 @@ def _walk_tables(tables, lo, hi, tpb: int):
 def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, *refs, tpb: int,
                          kv_heads: int, kc_qk: int, kc_pv: int,
                          pm_layout: str, window: Optional[int],
-                         softcap: float, attend_limit: int):
+                         softcap: float, attend_limit: int,
+                         latent_dv: Optional[int] = None):
     """One (sequence, tile) grid step.
 
     ``q_ref``: (1, KV, rows, hd) queries, rows ordered (query, group) and
     pre-scaled by ``hd**-0.5``; ``qpos_ref``: (1, rows, 1) their positions
     (-1 padding).  ``refs`` holds, per table column ``j`` of the tile,
     its positions (1, 1, bs), then its K blocks (1, bs, KV, hd), then its
-    V blocks, all as the index maps resolved them; then the (B, 2) SMEM
-    walk bounds, the (1, KV, rows, hd) output, and the scratch: per-head
-    keys transposed (KV, hd, T) and values (KV, T, hd) in f32, the
-    probability tile (rows, T), and the running max/normalizer (KV, rows,
-    1) and output accumulator (KV, rows, hd) carried across the walk.
+    V blocks (none in latent mode, ``latent_dv`` set: V is the first
+    ``latent_dv`` columns of K), all as the index maps resolved them; then
+    the (B, 2) SMEM walk bounds, the (1, KV, rows, Dv) output, and the
+    scratch: per-head keys transposed (KV, hd, T) and values (KV, T, Dv)
+    in f32, the probability tile (rows, T), and the running
+    max/normalizer (KV, rows, 1) and output accumulator (KV, rows, Dv)
+    carried across the walk.
     """
     del tables_ref                    # consumed by the BlockSpec index maps
+    n_v = 0 if latent_dv else tpb
     pos_refs, k_refs, v_refs = refs[:tpb], refs[tpb:2 * tpb], \
-        refs[2 * tpb:3 * tpb]
+        refs[2 * tpb:2 * tpb + n_v]
     (bounds_ref, out_ref, kt_ref, v_ref, p_ref, m_ref, l_ref,
-     acc_ref) = refs[3 * tpb:]
+     acc_ref) = refs[2 * tpb + n_v:]
     i, step = pl.program_id(0), pl.program_id(1)
     lo, hi = bounds_ref[i, 0], bounds_ref[i, 1]
     f32 = jnp.float32
@@ -162,9 +174,12 @@ def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, *refs, tpb: int,
         kf = jnp.concatenate(
             [jnp.where(live[j], k_refs[j][0].astype(f32), 0.0)
              for j in range(tpb)], axis=0)                  # (T, KV, hd)
-        vf = jnp.concatenate(
-            [jnp.where(live[j], v_refs[j][0].astype(f32), 0.0)
-             for j in range(tpb)], axis=0)
+        if latent_dv:
+            vf = kf[:, :, :latent_dv]
+        else:
+            vf = jnp.concatenate(
+                [jnp.where(live[j], v_refs[j][0].astype(f32), 0.0)
+                 for j in range(tpb)], axis=0)
         for h in range(kv_heads):
             kt_ref[h] = kf[:, h, :].T                       # (hd, T)
             v_ref[h] = vf[:, h, :]                          # (T, hd)
@@ -230,7 +245,8 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
                   softcap: float = 0.0, attend_limit: int = 2 ** 29,
                   kc_qk: Optional[int] = None, kc_pv: Optional[int] = None,
                   pm_layout: Optional[str] = None,
-                  interpret: Optional[bool] = None):
+                  interpret: Optional[bool] = None,
+                  dv: Optional[int] = None):
     """Fused paged attention: softmax(q @ K^T) @ V over block tables.
 
     ``q``: (B, S, KV, G, hd) queries, already scaled by ``hd**-0.5``
@@ -239,8 +255,9 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
     int32 block tables with absolute column addressing (column ``c``
     holds positions ``[c*bs, (c+1)*bs)``); ``pos_pool``: (P,) absolute
     positions (EMPTY sentinel on unwritten slots); ``q_pos``: (B, S) query
-    positions with -1 marking padding.  Returns (B, S, KV, G, hd)
-    float32.  The new K/V must already be scattered into the pools (the
+    positions with -1 marking padding.  Returns (B, S, KV, G, Dv)
+    float32, ``Dv`` = hd.  Latent mode: ``v_pool=None`` and ``dv`` <= hd,
+    V being the first ``dv`` columns of each K row (module docstring).  The new K/V must already be scattered into the pools (the
     engine scatters once per step).
 
     ``kc_qk`` chunks the head_dim reduction of the score PM block,
@@ -265,6 +282,11 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
     if pm_layout not in PM_LAYOUTS:
         raise ValueError(f"unknown pm_layout {pm_layout!r}; expected one "
                          f"of {PM_LAYOUTS}")
+    latent = v_pool is None
+    if latent and not (dv and 0 < dv <= hd):
+        raise ValueError(f"latent mode (no V pool) needs 0 < dv <= {hd}, "
+                         f"got dv={dv}")
+    Dv = dv if latent else hd
     tpb = tile_blocks(block_size, nb)
     T = tpb * block_size
     kc_qk = hd if kc_qk is None else kc_qk
@@ -286,7 +308,7 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
     walk = _walk_tables(tables.astype(i32), lo, hi, tpb)
     # free reshapes of the stored layouts: no widening, no transpose
     kr = k_pool.reshape(num_blocks, block_size, KV, hd)
-    vr = v_pool.reshape(num_blocks, block_size, KV, hd)
+    vr = None if latent else v_pool.reshape(num_blocks, block_size, KV, hd)
     posr = pos_pool.astype(i32).reshape(num_blocks, 1, block_size)
 
     def col(j):
@@ -305,7 +327,7 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
     kernel = functools.partial(
         sq_paged_attn_kernel, tpb=tpb, kv_heads=KV, kc_qk=kc_qk,
         kc_pv=kc_pv, pm_layout=pm_layout, window=window, softcap=softcap,
-        attend_limit=attend_limit)
+        attend_limit=attend_limit, latent_dv=Dv if latent else None)
     pos_specs = [pl.BlockSpec((1, 1, block_size), pos_map(j))
                  for j in range(tpb)]
     kv_specs = [pl.BlockSpec((1, block_size, KV, hd), kv_map(j))
@@ -316,26 +338,27 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
         in_specs=[
             pl.BlockSpec((1, KV, rows, hd), lambda i, s, t: (i, 0, 0, 0)),
             pl.BlockSpec((1, rows, 1), lambda i, s, t: (i, 0, 0)),
-            *pos_specs, *kv_specs, *kv_specs,
+            *pos_specs, *kv_specs, *([] if latent else kv_specs),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, KV, rows, hd),
+        out_specs=pl.BlockSpec((1, KV, rows, Dv),
                                lambda i, s, t: (i, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, hd, T), f32),         # keys, per head, k^T
-            pltpu.VMEM((KV, T, hd), f32),         # values, per head
+            pltpu.VMEM((KV, T, Dv), f32),         # values, per head
             pltpu.VMEM((rows, T), f32),           # probability tile
             pltpu.VMEM((KV, rows, 1), f32),       # running max
             pltpu.VMEM((KV, rows, 1), f32),       # running normalizer
-            pltpu.VMEM((KV, rows, hd), f32),      # output accumulator
+            pltpu.VMEM((KV, rows, Dv), f32),      # output accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), f32),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rows, Dv), f32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(walk, qf, qpos, *([posr] * tpb), *([kr] * tpb), *([vr] * tpb), bounds)
-    return out.reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4)
+    )(walk, qf, qpos, *([posr] * tpb), *([kr] * tpb),
+      *([] if latent else [vr] * tpb), bounds)
+    return out.reshape(B, KV, S, G, Dv).transpose(0, 2, 1, 3, 4)

@@ -585,11 +585,14 @@ def plan_conv2d(h: int, w: int, kh: int, kw: int, cin: int, cout: int,
     return plan
 
 
-def _paged_key(rows: int, hd: int, tile: int, kv_heads: int, dtype) -> str:
+def _paged_key(rows: int, hd: int, tile: int, kv_heads: int, dtype,
+               dv: Optional[int] = None) -> str:
     """Cache key of a paged-attention plan: the KV head count leads, so no
     entry of the one-head-one-block kernel's ``rows x hd x block_size``
-    keys is ever served to the whole-tile kernel."""
-    return (f"sq_paged_attn:{kv_heads}kv:{rows}x{hd}x{tile}:"
+    keys is ever served to the whole-tile kernel.  A value width other
+    than the head dim (latent mode) follows the head dim as ``/<dv>``."""
+    d = hd if dv is None or dv == hd else f"{hd}/{dv}"
+    return (f"sq_paged_attn:{kv_heads}kv:{rows}x{d}x{tile}:"
             f"{jnp.dtype(dtype).name}")
 
 
@@ -597,13 +600,15 @@ def plan_paged_attn(rows: int, hd: int, tile: int,
                     dtype=jnp.float32, *, kv_heads: int = 1,
                     kc_qk: Optional[int] = None,
                     kc_pv: Optional[int] = None,
-                    pm_layout: str = "mkn") -> PagedAttnPlan:
+                    pm_layout: str = "mkn",
+                    dv: Optional[int] = None) -> PagedAttnPlan:
     """Pick the (kc_qk, kc_pv, pm_layout) plan for a fused paged-attention
     call.  ``rows`` is the score-tile row count (``S * G``: query tile x
     GQA group), ``hd`` the head dim, ``tile`` the tokens one grid step
     walks (:func:`repro.kernels.sq_paged_attn.tile_blocks` table entries
-    of ``block_size``), ``dtype`` the pools' stored dtype and
-    ``kv_heads`` the heads one step serves.
+    of ``block_size``), ``dtype`` the pools' stored dtype,
+    ``kv_heads`` the heads one step serves and ``dv`` the value width
+    (default ``hd``; the latent kernel's p.v runs over ``dv < hd``).
 
     Same precedence as :func:`plan_matmul`: explicit knobs > autotune
     cache (keyed ``sq_paged_attn:<kv_heads>kv:<rows>x<hd>x<tile>:<dtype>``,
@@ -626,7 +631,7 @@ def plan_paged_attn(rows: int, hd: int, tile: int,
         return PagedAttnPlan(_align_kc(kc_qk, hd), _align_kc(kc_pv, tile),
                              pm_layout)
     use_cache = autotune_enabled()
-    key = _paged_key(rows, hd, tile, kv_heads, dtype)
+    key = _paged_key(rows, hd, tile, kv_heads, dtype, dv)
     cached = load_cache().get(key) if use_cache else None
     if cached is not None and kc_qk is None and kc_pv is None \
             and str(cached.get("pm_layout", pm_layout)) == pm_layout:

@@ -26,6 +26,7 @@ from repro.layers.param import ParamSpec
 
 __all__ = ["attn_spec", "attn_forward", "attn_decode", "chunked_attention",
            "init_paged_kv_cache", "paged_slots", "paged_gather_indices",
+           "paged_read",
            "EMPTY_POS", "ATTEND_POS_LIMIT"]
 
 # Sentinel position of an unwritten / freed / padded physical cache slot.
@@ -133,8 +134,9 @@ def chunked_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
                       mode: Optional[str] = None, policy=None):
     """Online-softmax attention, O(chunk_q * chunk_kv) live scores.
 
-    q: (B, S, KV, G, hd); k, v: (B, T, KV, hd); positions are absolute.
-    Returns (B, S, KV, G, hd) in q.dtype.
+    q: (B, S, KV, G, hd); k: (B, T, KV, hd); v: (B, T, KV, hv) (``hv``
+    may differ from ``hd``, as latent attention's does); positions are
+    absolute.  Returns (B, S, KV, G, hv) in q.dtype.
 
     ``block_skip``: causal block-diagonal skipping -- q block i only visits
     kv chunks 0..i (a STATIC triangular schedule: each q block gets its own
@@ -144,6 +146,7 @@ def chunked_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
     """
     B, S, KV, G, hd = q.shape
     T = k.shape[1]
+    hv = v.shape[-1]
     cq = min(chunk_q, S)
     ck = min(chunk_kv, T)
     pad_q = (-S) % cq
@@ -159,7 +162,7 @@ def chunked_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
     qb = jnp.moveaxis(qp.reshape(B, nq, cq, KV, G, hd), 1, 0)   # (nq,B,cq,KV,G,hd)
     qposb = qpos.reshape(nq, cq)
     kb = jnp.moveaxis(kp.reshape(B, nk, ck, KV, hd), 1, 0)      # (nk,B,ck,KV,hd)
-    vb = jnp.moveaxis(vp.reshape(B, nk, ck, KV, hd), 1, 0)
+    vb = jnp.moveaxis(vp.reshape(B, nk, ck, KV, hv), 1, 0)
     kposb = kpos.reshape(nk, ck)
 
     def q_block(qc, qpc, n_kv: Optional[int] = None):
@@ -197,13 +200,13 @@ def chunked_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
 
         m0 = jnp.full((B, KV, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, cq), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, cq, hd), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, cq, hv), jnp.float32)
         xs = ((kb, vb, kposb) if n_kv is None
               else (kb[:n_kv], vb[:n_kv], kposb[:n_kv]))
         with counting.count_scale(nk if n_kv is None else n_kv):
             (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), xs)
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return jnp.moveaxis(out, 3, 1)                          # (B,cq,KV,G,hd)
+        return jnp.moveaxis(out, 3, 1)                          # (B,cq,KV,G,hv)
 
     if fold_q:
         # Fold the q-chunk axis into a vmapped batch dim and shard it over
@@ -231,7 +234,7 @@ def chunked_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
     else:
         with counting.count_scale(nq):
             outs = jax.lax.map(lambda args: q_block(*args), (qb, qposb))
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * cq, KV, G, hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * cq, KV, G, hv)
     return out[:, :S].astype(q.dtype)
 
 
@@ -446,7 +449,6 @@ def _attn_paged_step(p, x, cache, pos, *, cfg, window, mode, policy, paged):
     v_pool = cache["v"].at[phys].set(v1.reshape(B * S, KV, hd)
                                      .astype(cache["v"].dtype))
 
-    T = paged["tables"].shape[1] * paged["block_size"]
     qf = qr.reshape(B, S, KV, G, hd).astype(jnp.float32) * hd ** -0.5
 
     def gather_attend():
@@ -466,6 +468,27 @@ def _attn_paged_step(p, x, cache, pos, *, cfg, window, mode, policy, paged):
         return fs_einsum("bkgqt,btkh->bqkgh", w, v.astype(jnp.float32),
                          mode=mode, policy=policy, site="attn_pv")
 
+    out = paged_read(qf, k_pool, v_pool, pos, paged, dt=dt, window=window,
+                     softcap=cfg.attn_logit_softcap, mode=mode,
+                     policy=policy, gather_attend=gather_attend)
+    out = out.reshape(B, S, H, hd).astype(dt)
+    return _proj_out(p["wo"], out, mode, x.dtype,
+                     tp_reduce=cfg.tp_bf16_reduce, policy=policy), \
+        {"k": k_pool, "v": v_pool}
+
+
+def paged_read(qf, k_pool, v_pool, pos, paged, *, dt, window, softcap,
+               mode, policy, gather_attend, dv=None):
+    """Attention of pre-scaled queries ``qf`` (B, S, KV, G, hd) over the
+    paged pools, by the route :mod:`repro.kernels.routing` picks when the
+    ``attn_paged`` site resolves to ``square_pallas``: the fused kernel
+    (``v_pool`` None: latent mode, V the first ``dv`` columns of K), or
+    ``gather_attend()``, the caller's dense read.  Returns (B, S, KV, G,
+    Dv) f32.  Guarded like every square-routed contraction: a non-finite
+    kernel output (eager only) trips the ``attn_paged`` route-health
+    breaker and recomputes on the gather route."""
+    B, S, KV, G, hd = qf.shape
+    T = paged["tables"].shape[1] * paged["block_size"]
     from repro.core.einsum import resolve_mode     # lazy: import cycle
     use_kernel = False
     if resolve_mode(mode, policy, "attn_paged") == "square_pallas" \
@@ -476,45 +499,38 @@ def _attn_paged_step(p, x, cache, pos, *, cfg, window, mode, policy, paged):
         hkey = routing.health_key("attn_paged", (B, S, KV, G, hd, T), dt)
         use_kernel = (route.name == "kernel"
                       and not routing.route_health().is_demoted(hkey))
+    if not use_kernel:
+        return gather_attend()
 
-    if use_kernel:
-        from repro.core import guards
-        from repro.kernels import tuning
-        from repro.kernels.ops import default_interpret
-        from repro.kernels.sq_paged_attn import sq_paged_attn, tile_blocks
-        interp = default_interpret()
-        bs = paged["block_size"]
-        plan = tuning.plan_paged_attn(
-            S * G, hd, tile_blocks(bs, paged["tables"].shape[1]) * bs,
-            k_pool.dtype, kv_heads=KV, pm_layout="mnk" if interp else "mkn")
-        out = sq_paged_attn(
-            qf, k_pool, v_pool, paged["tables"], paged["pos_pool"], pos,
-            block_size=paged["block_size"], window=window,
-            softcap=cfg.attn_logit_softcap, attend_limit=ATTEND_POS_LIMIT,
-            kc_qk=plan.kc_qk, kc_pv=plan.kc_pv, pm_layout=plan.pm_layout,
-            interpret=interp)
-        gp = guards.guard_policy()
-        if gp.enabled and guards.check_finite(out) is False:
-            # eager-only (check_finite is None under a jit trace): trip
-            # the breaker and recompute on the gather route, whose
-            # fs_einsums do their own counting
-            from repro.kernels import routing
-            routing.route_health().record_trip(hkey, limit=gp.trip_limit)
-            out = gather_attend()
-        else:
-            # the kernel subsumes both softmax-path contractions; count
-            # them at the sites the audit already knows
-            for site in ("attn_scores", "attn_pv"):
-                counting.note_contraction(
-                    site=site, spec="paged_attn_kernel",
-                    mode="square_pallas", mults=B * KV * G * S * T * hd)
-    else:
-        out = gather_attend()
-
-    out = out.reshape(B, S, H, hd).astype(dt)
-    return _proj_out(p["wo"], out, mode, x.dtype,
-                     tp_reduce=cfg.tp_bf16_reduce, policy=policy), \
-        {"k": k_pool, "v": v_pool}
+    from repro.core import guards
+    from repro.kernels import tuning
+    from repro.kernels.ops import default_interpret
+    from repro.kernels.sq_paged_attn import sq_paged_attn, tile_blocks
+    interp = default_interpret()
+    bs = paged["block_size"]
+    plan = tuning.plan_paged_attn(
+        S * G, hd, tile_blocks(bs, paged["tables"].shape[1]) * bs,
+        k_pool.dtype, kv_heads=KV, dv=dv,
+        pm_layout="mnk" if interp else "mkn")
+    out = sq_paged_attn(
+        qf, k_pool, v_pool, paged["tables"], paged["pos_pool"], pos,
+        block_size=bs, window=window, softcap=softcap,
+        attend_limit=ATTEND_POS_LIMIT, kc_qk=plan.kc_qk, kc_pv=plan.kc_pv,
+        pm_layout=plan.pm_layout, interpret=interp, dv=dv)
+    gp = guards.guard_policy()
+    if gp.enabled and guards.check_finite(out) is False:
+        # eager-only (check_finite is None under a jit trace): trip the
+        # breaker and recompute on the gather route, whose fs_einsums do
+        # their own counting
+        routing.route_health().record_trip(hkey, limit=gp.trip_limit)
+        return gather_attend()
+    # the kernel subsumes both softmax-path contractions; count them at
+    # the sites the audit already knows
+    for site, width in (("attn_scores", hd), ("attn_pv", out.shape[-1])):
+        counting.note_contraction(
+            site=site, spec="paged_attn_kernel", mode="square_pallas",
+            mults=B * KV * G * S * T * width)
+    return out
 
 
 def init_paged_kv_cache(cfg, pool_slots: int):

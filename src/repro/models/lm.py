@@ -3,7 +3,10 @@
 Layer stacks are grouped into *periods* (one cycle of ``cfg.block_pattern``)
 and scanned with ``jax.lax.scan`` over stacked params -- HLO size and compile
 time stay O(period) instead of O(layers), the standard MaxText approach.
-Pattern tails that don't fill a period are unrolled.
+Pattern tails that don't fill a period are unrolled, and so are the
+``cfg.first_k_dense`` leading dense layers that run before the scan
+(``params["lead"]["layer<i>"]``, DeepSeek-V3's dense layer 0): plain
+``attn`` blocks whose SwiGLU has width ``cfg.dense_d_ff``.
 
 The same period/scan machinery drives decode: caches are stacked trees with
 a leading period axis and are threaded through the scan.
@@ -26,14 +29,32 @@ __all__ = ["LM", "build_model"]
 
 
 def _period_split(cfg) -> Tuple[int, Tuple[str, ...], Tuple[str, ...]]:
-    """n_scan periods of the full pattern + unrolled tail kinds."""
-    kinds = cfg.layer_kinds
+    """n_scan periods of the full pattern + unrolled tail kinds, over the
+    layers after the leading dense ones (:func:`_lead_cfg`)."""
+    kinds = cfg.layer_kinds[cfg.first_k_dense:]
     plen = len(cfg.block_pattern)
     if not cfg.scan_layers:
         return 0, (), kinds
     n_scan = len(kinds) // plen
     tail = kinds[n_scan * plen:]
     return n_scan, cfg.block_pattern, tail
+
+
+def _lead_cfg(cfg):
+    """The config the leading dense layers run with: their SwiGLU width."""
+    return dataclasses.replace(cfg, d_ff=cfg.dense_d_ff or cfg.d_ff)
+
+
+def _final_norm(cfg, params, x):
+    if cfg.norm == "layernorm":
+        return basic.layernorm_apply(params["final_norm"], x)
+    return basic.rmsnorm_apply(params["final_norm"], x, eps=cfg.norm_eps)
+
+
+def _head_table(params):
+    """The output table: the untied head's, else the tied embedding."""
+    return params["head"]["table"] if "head" in params \
+        else params["embed"]["table"]
 
 
 @dataclasses.dataclass
@@ -52,6 +73,12 @@ class LM:
                            else basic.rmsnorm_spec(cfg.d_model)),
         }
         dec_kind = {"attn": "xdec"} if cfg.encoder_layers else {}
+        if not cfg.tie_embeddings:
+            s["head"] = basic.embed_spec(cfg.padded_vocab, cfg.d_model,
+                                         jnp.dtype(cfg.dtype))
+        if cfg.first_k_dense:
+            s["lead"] = {f"layer{i}": blk.block_spec("attn", _lead_cfg(cfg))
+                         for i in range(cfg.first_k_dense)}
         if n_scan:
             s["scan"] = {f"pos{i}": blk.block_spec(dec_kind.get(k, k), cfg, n_scan)
                          for i, k in enumerate(period)}
@@ -82,20 +109,29 @@ class LM:
         total = self.n_params()
         if not cfg.n_experts:
             return total
-        expert_p = 3 * cfg.d_model * cfg.d_ff     # gate+up+down per expert
-        per_layer_inactive = (cfg.n_experts - cfg.topk) * expert_p
+        # gate+up+down per expert; a held share keeps experts_held of them
+        # and its tokens use, in expectation, topk * held / n_experts
+        expert_p = 3 * cfg.d_model * cfg.d_ff
+        held = cfg.experts_held or cfg.n_experts
+        per_layer_inactive = (held - cfg.topk * held / cfg.n_experts) \
+            * expert_p
         n_moe_layers = sum(1 for k in cfg.layer_kinds if k == "moe")
-        return total - n_moe_layers * per_layer_inactive
+        return int(round(total - n_moe_layers * per_layer_inactive))
 
     # ------------------------------------------------------- embedding
     def _embed_in(self, params, batch):
         cfg = self.cfg
-        x = basic.embed_apply(params["embed"], batch["tokens"])
-        x = x * (cfg.d_model ** 0.5)
-        x = x.astype(jnp.dtype(cfg.dtype))
+        x = self._embed_tokens(params, batch["tokens"])
         if cfg.prefix_tokens:
             x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
         return x
+
+    def _embed_tokens(self, params, tokens):
+        cfg = self.cfg
+        x = basic.embed_apply(params["embed"], tokens)
+        if cfg.embed_scale:
+            x = x * (cfg.d_model ** 0.5)
+        return x.astype(jnp.dtype(cfg.dtype))
 
     def _encode(self, params, batch, mode):
         """Whisper-style encoder over precomputed frame embeddings (stub
@@ -142,6 +178,13 @@ class LM:
         aux_total = jnp.zeros((), jnp.float32)
         caches = {}
 
+        lead_ctx = dict(ctx, cfg=_lead_cfg(cfg))
+        for i in range(cfg.first_k_dense):
+            x, c, _ = blk.block_forward("attn", params["lead"][f"layer{i}"],
+                                        x, lead_ctx)
+            if collect_cache:
+                caches.setdefault("lead", {})[f"layer{i}"] = c
+
         if n_scan:
             def body(x, pslice):
                 aux_p = jnp.zeros((), jnp.float32)
@@ -175,10 +218,7 @@ class LM:
             if collect_cache:
                 caches.setdefault("tail", {})[f"layer{i}"] = c
 
-        if cfg.norm == "layernorm":
-            x = basic.layernorm_apply(params["final_norm"], x)
-        else:
-            x = basic.rmsnorm_apply(params["final_norm"], x)
+        x = _final_norm(cfg, params, x)
         if cfg.encoder_layers and collect_cache:
             caches["enc_out"] = ctx["cross_x"]
         return x, aux_total, caches
@@ -192,7 +232,7 @@ class LM:
         cfg = self.cfg
         table = params.get("logits_prep")
         if table is None:
-            table = params["embed"]["table"].astype(jnp.float32)
+            table = _head_table(params).astype(jnp.float32)
         return fs_einsum("bsd,vd->bsv", hidden.astype(jnp.float32),
                          table, mode=cfg.matmul_mode,
                          policy=cfg.contraction_policy, site="logits")
@@ -238,7 +278,28 @@ class LM:
                                      prepare_grads=pg)
             return q
 
+        def prep_mla(p):
+            if p["wq"]["w"].ndim != 3:
+                return p                      # stacked: keep the block raw
+            dq = p["wq"]["w"].shape[-1]
+            q = dict(p)
+            sites = {"wq": "attn_qkv", "wkv_a": "attn_qkv",
+                     "wk_b": "mla_absorb", "wv_b": "mla_unabsorb",
+                     "wo": "attn_out"}
+            for nm, site in sites.items():
+                w = p[nm]["w"]
+                if nm == "wq":
+                    w = w.reshape(w.shape[0], H * dq)
+                elif nm == "wo":
+                    w = w.reshape(-1, w.shape[-1])
+                q[nm] = dict(p[nm], w=prepare_operand(
+                    w, site=site, interpret=interp,
+                    prepare_grads=pg and w.ndim == 2))
+            return q
+
         def prep_attn(p):
+            if "wkv_a" in p:
+                return prep_mla(p)
             q = dict(p)
             for nm, nh in (("wq", H), ("wk", KV), ("wv", KV)):
                 w = q[nm]["w"]
@@ -284,9 +345,11 @@ class LM:
             return q
 
         new = dict(params)
-        if "tail" in new:
-            new["tail"] = {k: prep_block(v) for k, v in new["tail"].items()}
-        table = params["embed"]["table"]
+        for stack in ("lead", "tail"):
+            if stack in new:
+                new[stack] = {k: prep_block(v)
+                              for k, v in new[stack].items()}
+        table = _head_table(params)
         new["logits_prep"] = prepare_operand(table.astype(jnp.float32),
                                              transpose=True, site="logits",
                                              interpret=interp,
@@ -300,6 +363,10 @@ class LM:
         dec_kind = {"attn": "xdec"} if cfg.encoder_layers else {}
         enc_len = cfg.encoder_seq
         cache: Dict[str, Any] = {}
+        if cfg.first_k_dense:
+            cache["lead"] = {f"layer{i}": blk.block_init_cache(
+                "attn", cfg, batch_size, cache_len, enc_len)
+                for i in range(cfg.first_k_dense)}
         if n_scan:
             def stack(kind):
                 one = blk.block_init_cache(kind, cfg, batch_size, cache_len, enc_len)
@@ -315,12 +382,17 @@ class LM:
 
     # ------------------------------------------------------- paged decode
     def init_paged_cache(self, pool_slots: int):
-        """Per-layer paged KV pools (the serving engine's cache): every
-        attention layer gets a ``(pool_slots, KV, hd)`` k/v pool shared by
-        all sequences; block tables (held by the engine) map each
-        sequence's logical positions onto pool slots.  Raises for archs
-        with non-KV decode state (recurrent / encoder-decoder) -- those
-        serve through the dense reference ``Server``."""
+        """Per-layer paged pools (the serving engine's cache): every
+        attention layer gets a ``(pool_slots, KV, hd)`` k/v pool, or with
+        latent attention one ``(pool_slots, 1, kv_lora_rank +
+        qk_rope_head_dim)`` latent pool, shared by all sequences; block
+        tables (held by the engine) map each sequence's logical positions
+        onto pool slots.  A model with held experts also carries
+        ``moe_counters``: the (``moe.N_STATS``,) int32 running sums of its
+        layers' pick statistics, accumulated by every step program on the
+        device (:meth:`decode_paged`).  Raises for archs with non-KV
+        decode state (recurrent / encoder-decoder) -- those serve through
+        the dense reference ``Server``."""
         cfg = self.cfg
         if cfg.encoder_layers or cfg.prefix_tokens:
             raise ValueError(
@@ -328,6 +400,11 @@ class LM:
                 "and prefix-token archs use the dense reference Server")
         n_scan, period, tail = _period_split(cfg)
         cache: Dict[str, Any] = {}
+        if cfg.first_k_dense:
+            cache["lead"] = {
+                f"layer{i}": blk.block_init_paged_cache("attn", cfg,
+                                                        pool_slots)
+                for i in range(cfg.first_k_dense)}
         if n_scan:
             def stack(kind):
                 one = blk.block_init_paged_cache(kind, cfg, pool_slots)
@@ -340,7 +417,50 @@ class LM:
             cache["tail"] = {
                 f"layer{i}": blk.block_init_paged_cache(k, cfg, pool_slots)
                 for i, k in enumerate(tail)}
+        if cfg.experts_held:
+            from repro.models import moe as moe_mod
+            cache["moe_counters"] = jnp.zeros((moe_mod.N_STATS,), jnp.int32)
         return cache
+
+    def _decode_layers(self, params, cache, x, ctx, dec_kind):
+        """Run every layer's cached step: the leading dense layers, the
+        scanned periods, the tail.  Returns (x, new cache, the layers'
+        summed MoE statistics)."""
+        cfg = self.cfg
+        n_scan, period, tail = _period_split(cfg)
+        cache = dict(cache)
+        stats = []
+        lead_ctx = dict(ctx, cfg=_lead_cfg(cfg))
+        for i in range(cfg.first_k_dense):
+            x, nc, st = blk.block_decode("attn", params["lead"][f"layer{i}"],
+                                         x, cache["lead"][f"layer{i}"],
+                                         lead_ctx)
+            cache["lead"] = dict(cache["lead"], **{f"layer{i}": nc})
+            stats.append(st)
+        if n_scan:
+            def body(x, sl):
+                pslice, cslice = sl
+                new_c, st = {}, 0
+                for i, k in enumerate(period):
+                    kk = dec_kind.get(k, k)
+                    x, nc, s = blk.block_decode(kk, pslice[f"pos{i}"], x,
+                                                cslice[f"pos{i}"], ctx)
+                    new_c[f"pos{i}"] = nc
+                    st = st + s
+                return x, (new_c, st)
+
+            with counting.count_scale(n_scan):
+                x, (new_scan, st) = jax.lax.scan(
+                    body, x, (params["scan"], cache["scan"]))
+            cache["scan"] = new_scan
+            stats.append(jnp.sum(st, axis=0))
+        for i, k in enumerate(tail):
+            kk = dec_kind.get(k, k)
+            x, nc, st = blk.block_decode(kk, params["tail"][f"layer{i}"], x,
+                                         cache["tail"][f"layer{i}"], ctx)
+            cache["tail"] = dict(cache["tail"], **{f"layer{i}": nc})
+            stats.append(st)
+        return x, cache, sum(stats)
 
     def decode_paged(self, params, cache, tokens, positions, tables,
                      pos_pool, *, block_size: int):
@@ -354,50 +474,26 @@ class LM:
         ONCE here (not per layer -- the position layout is identical across
         layers).  Returns (hidden (B, S, D), new_cache, new_pos_pool);
         logits are the caller's call (decode wants every step, chunked
-        prefill only the last chunk).
+        prefill only the last chunk).  A cache with ``moe_counters`` gets
+        this step's MoE statistics added to them, on the device.
         """
         from repro.models import attention as attn_mod
         cfg = self.cfg
         mode = cfg.matmul_mode
-        n_scan, period, tail = _period_split(cfg)
         phys = attn_mod.paged_slots(tables, positions, block_size)
         pos_pool = pos_pool.at[phys.reshape(-1)].set(
             jnp.where(positions >= 0, positions,
                       attn_mod.EMPTY_POS).reshape(-1).astype(pos_pool.dtype))
-        x = basic.embed_apply(params["embed"], jnp.maximum(tokens, 0))
-        x = (x * (cfg.d_model ** 0.5)).astype(jnp.dtype(cfg.dtype))
+        x = self._embed_tokens(params, jnp.maximum(tokens, 0))
         ctx = {"cfg": cfg, "mode": mode, "policy": cfg.contraction_policy,
                "pos": positions,
                "paged": {"tables": tables, "pos_pool": pos_pool,
                          "phys": phys, "block_size": block_size}}
-
-        if n_scan:
-            def body(x, sl):
-                pslice, cslice = sl
-                new_c = {}
-                for i, k in enumerate(period):
-                    x, nc = blk.block_decode(k, pslice[f"pos{i}"], x,
-                                             cslice[f"pos{i}"], ctx)
-                    new_c[f"pos{i}"] = nc
-                return x, new_c
-
-            with counting.count_scale(n_scan):
-                x, new_scan = jax.lax.scan(body, x,
-                                           (params["scan"], cache["scan"]))
-            cache = dict(cache)
-            cache["scan"] = new_scan
-        for i, k in enumerate(tail):
-            x, nc = blk.block_decode(k, params["tail"][f"layer{i}"], x,
-                                     cache["tail"][f"layer{i}"], ctx)
-            cache = dict(cache)
-            cache["tail"] = dict(cache.get("tail", {}))
-            cache["tail"][f"layer{i}"] = nc
-
-        if cfg.norm == "layernorm":
-            x = basic.layernorm_apply(params["final_norm"], x)
-        else:
-            x = basic.rmsnorm_apply(params["final_norm"], x)
-        return x, cache, pos_pool
+        layers = {k: v for k, v in cache.items() if k != "moe_counters"}
+        x, new, stats = self._decode_layers(params, layers, x, ctx, {})
+        if "moe_counters" in cache:
+            new["moe_counters"] = cache["moe_counters"] + stats
+        return _final_norm(cfg, params, x), new, pos_pool
 
     # ------------------------------------------------------------ decode
     def decode_step(self, params, cache, tokens, pos):
@@ -405,74 +501,45 @@ class LM:
         Returns (logits (B, V), new_cache)."""
         cfg = self.cfg
         mode = cfg.matmul_mode
-        n_scan, period, tail = _period_split(cfg)
         dec_kind = {"attn": "xdec"} if cfg.encoder_layers else {}
-        x = basic.embed_apply(params["embed"], tokens)
-        x = (x * (cfg.d_model ** 0.5)).astype(jnp.dtype(cfg.dtype))
+        x = self._embed_tokens(params, tokens)
         ctx = {"cfg": cfg, "mode": mode, "policy": cfg.contraction_policy,
                "pos": pos}
-
-        if n_scan:
-            def body(x, sl):
-                pslice, cslice = sl
-                new_c = {}
-                for i, k in enumerate(period):
-                    kk = dec_kind.get(k, k)
-                    x, nc = blk.block_decode(kk, pslice[f"pos{i}"], x,
-                                             cslice[f"pos{i}"], ctx)
-                    new_c[f"pos{i}"] = nc
-                return x, new_c
-
-            with counting.count_scale(n_scan):
-                x, new_scan = jax.lax.scan(body, x,
-                                           (params["scan"], cache["scan"]))
-            cache = dict(cache)
-            cache["scan"] = new_scan
-        for i, k in enumerate(tail):
-            kk = dec_kind.get(k, k)
-            x, nc = blk.block_decode(kk, params["tail"][f"layer{i}"], x,
-                                     cache["tail"][f"layer{i}"], ctx)
-            cache = dict(cache)
-            cache["tail"] = dict(cache["tail"])
-            cache["tail"][f"layer{i}"] = nc
-
-        if cfg.norm == "layernorm":
-            x = basic.layernorm_apply(params["final_norm"], x)
-        else:
-            x = basic.rmsnorm_apply(params["final_norm"], x)
+        x, cache, _ = self._decode_layers(params, cache, x, ctx, dec_kind)
+        x = _final_norm(cfg, params, x)
         logits = self.logits(params, x)[:, 0]
         return logits, cache
 
     # ----------------------------------------------------------- prefill
     def prefill(self, params, batch, cache_len: int):
         """Process a prompt, return (last_hidden, decode-ready cache)."""
-        cfg = self.cfg
         hidden, _, seeds = self.forward(params, batch, collect_cache=True)
         B = hidden.shape[0]
         cache = self.init_cache(B, cache_len)
 
         def fill(dst, seed):
-            if isinstance(seed, dict) and "k" in seed:      # attention seed
-                S = seed["k"].shape[1]
-                T = dst["k"].shape[1]
-                out = dict(dst)
-                if S >= T:
-                    # ring roll-in: keep the last T entries at slot pos % T
-                    ks, vs = seed["k"][:, -T:], seed["v"][:, -T:]
-                    ps = jnp.arange(S - T, S)
-                    idx = ps % T
-                    out["k"] = dst["k"].at[:, idx].set(ks)
-                    out["v"] = dst["v"].at[:, idx].set(vs)
-                    out["pos"] = dst["pos"].at[:, idx].set(ps[None, :])
-                else:
-                    out["k"] = dst["k"].at[:, :S].set(seed["k"])
-                    out["v"] = dst["v"].at[:, :S].set(seed["v"])
-                    out["pos"] = dst["pos"].at[:, :S].set(
-                        jnp.arange(S)[None, :])
-                if "xk" in dst:
-                    out["xk"], out["xv"] = seed["xk"], seed["xv"]
-                return out
-            return seed                                     # recurrent state
+            if not (isinstance(seed, dict) and "pos" in dst):
+                return seed                                 # recurrent state
+            # attention seed: every positional entry (k/v, or the latent
+            # rows) goes in at its token's slot
+            keys = [k for k in seed if k in dst and k not in ("xk", "xv")]
+            S = seed[keys[0]].shape[1]
+            T = dst[keys[0]].shape[1]
+            out = dict(dst)
+            if S >= T:
+                # ring roll-in: keep the last T entries at slot pos % T
+                ps = jnp.arange(S - T, S)
+                idx = ps % T
+                for k in keys:
+                    out[k] = dst[k].at[:, idx].set(seed[k][:, -T:])
+                out["pos"] = dst["pos"].at[:, idx].set(ps[None, :])
+            else:
+                for k in keys:
+                    out[k] = dst[k].at[:, :S].set(seed[k])
+                out["pos"] = dst["pos"].at[:, :S].set(jnp.arange(S)[None, :])
+            if "xk" in dst:
+                out["xk"], out["xv"] = seed["xk"], seed["xv"]
+            return out
 
         new_cache: Dict[str, Any] = {}
         if "scan" in cache:
@@ -480,15 +547,16 @@ class LM:
             for key in cache["scan"]:
                 dst = cache["scan"][key]
                 seed = seeds["scan"][key]
-                if isinstance(seed, dict) and "k" in seed:
+                if isinstance(seed, dict) and "pos" in dst:
                     # both stacked on leading period axis
                     new_cache["scan"][key] = jax.vmap(fill)(dst, seed)
                 else:
                     new_cache["scan"][key] = seed
-        if "tail" in cache:
-            new_cache["tail"] = {
-                key: fill(cache["tail"][key], seeds["tail"][key])
-                for key in cache["tail"]}
+        for stack in ("lead", "tail"):
+            if stack in cache:
+                new_cache[stack] = {
+                    key: fill(cache[stack][key], seeds[stack][key])
+                    for key in cache[stack]}
         return hidden, new_cache
 
 

@@ -78,9 +78,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import enum
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -240,6 +241,16 @@ class EngineMetrics:
     util_steps: int = 0
     ttft_s: Dict[int, float] = dataclasses.field(default_factory=dict)
     wall_s: float = 0.0
+    # held-expert MoE statistics, summed over layers and steps on the
+    # device (the cache's ``moe_counters``) and read only when asked:
+    # top-k picks of valid tokens, picks on held experts, rows the grouped
+    # expert GEMMs computed.  ``device_read`` (set by the engine of such a
+    # model) refreshes them; ``summary()`` calls it.
+    moe_picks: Optional[int] = None
+    moe_picks_held: Optional[int] = None
+    moe_rows_padded: Optional[int] = None
+    device_read: Optional[Callable[[], None]] = dataclasses.field(
+        default=None, repr=False, compare=False)
     # Histogram-backed latency percentiles (fixed buckets: O(1) state,
     # same bounded-bookkeeping rule as the running sums above).  The mean
     # hides the preemption/retry tail; p95/p99 expose it.  ``ttft_hist``
@@ -287,7 +298,9 @@ class EngineMetrics:
                 if self.decode_steps else 0.0)
 
     def summary(self) -> Dict[str, float]:
-        return {
+        if self.device_read is not None:
+            self.device_read()
+        out = {
             "tokens_out": self.tokens_out,
             "tokens_per_s": self.tokens_per_s,
             "mean_ttft_s": self.mean_ttft_s,
@@ -316,6 +329,11 @@ class EngineMetrics:
             "decode_step_p95_s": self.decode_step_hist.quantile(0.95),
             "decode_step_p99_s": self.decode_step_hist.quantile(0.99),
         }
+        if self.moe_picks is not None:
+            out.update(moe_picks=self.moe_picks,
+                       moe_picks_held=self.moe_picks_held,
+                       moe_rows_padded=self.moe_rows_padded)
+        return out
 
 
 @dataclasses.dataclass
@@ -426,6 +444,17 @@ class Engine:
             "guard_trips": reg.counter("engine_guard_trips_total"),
             "guard_rejits": reg.counter("engine_guard_rejits_total"),
         }
+        if "moe_counters" in self.cache:
+            self._c_moe = [
+                reg.counter("engine_moe_picks_total",
+                            help="top-k router picks of served tokens, "
+                                 "every MoE layer"),
+                reg.counter("engine_moe_picks_held_total",
+                            help="picks on experts this engine holds"),
+                reg.counter("engine_moe_rows_padded_total",
+                            help="rows the grouped expert GEMMs computed "
+                                 "(held picks padded to row tiles)")]
+            self.metrics.device_read = self.read_device_counters
         # the registry's latency histograms ARE the EngineMetrics ones
         # (one observe feeds both views)
         self.metrics.ttft_hist = reg.histogram("engine_ttft_seconds")
@@ -436,6 +465,7 @@ class Engine:
         self._deadline: Dict[int, float] = {}     # rid -> absolute engine time
         self._preempts: Dict[int, int] = {}       # rid -> times preempted
         self._tick = 0
+        self._heap_frozen = False                 # see _freeze_heap
         self._skew = 0.0                          # fault-injected clock skew
         self._idle_ticks = 0                      # watchdog state
         self._fail_streak = {"prefill": 0, "decode": 0}
@@ -463,10 +493,17 @@ class Engine:
         return jnp.asarray(self.tables.table[rows].copy())
 
     def _reset_pos(self, blocks: List[int]) -> None:
+        """Return ``blocks``' slots of the position ledger to EMPTY_POS.
+        The list is padded with the null block (whose positions are always
+        EMPTY_POS) to a power of two, so the scatter compiles once per
+        bucket rather than once per count of freed blocks."""
         if blocks:
             with obs_trace.span("engine.reset_pos", cat="engine",
                                 n=len(blocks)):
-                idx = self.tables.reset_slots_index(blocks)
+                n = 1 << (len(blocks) - 1).bit_length()
+                idx = self.tables.reset_slots_index(
+                    list(blocks)
+                    + [paged_mod.NULL_BLOCK] * (n - len(blocks)))
                 self.pos_pool = self.pos_pool.at[jnp.asarray(idx)].set(
                     EMPTY_POS)
 
@@ -1026,7 +1063,24 @@ class Engine:
                     pending = False
             else:
                 self._idle_ticks = 0
+        if not self._heap_frozen and self.metrics.decode_steps \
+                and self.metrics.prefill_chunks:
+            self._freeze_heap()
         return did or pending
+
+    def _freeze_heap(self) -> None:
+        """Once a decode step and a prefill chunk have run (so the step
+        programs are compiled), collect once and move every live object
+        into the collector's permanent generation (``gc.freeze``).  A full
+        collection otherwise walks the whole heap -- JAX's modules, traces
+        and caches, the weights' tree -- and stalls the tick it lands in
+        (68 ms for ~215k objects with Moonlight-16B-A3B served, on a TPU
+        v5e host); afterwards it walks only what serving allocated since.
+        Frozen objects are still freed by reference counting (the engine
+        itself holds no cycles)."""
+        self._heap_frozen = True
+        gc.collect()
+        gc.freeze()
 
     def run(self, requests: List[Request]) -> Dict[int, RequestResult]:
         """Serve ``requests`` until every one reaches a terminal status;
@@ -1051,6 +1105,24 @@ class Engine:
         return dict(self.results)
 
     # ------------------------------------------------------- observability
+    def read_device_counters(self) -> None:
+        """Read the step programs' on-device MoE counters (one sync, only
+        when asked: never per tick) into :class:`EngineMetrics` and the
+        registry counters ``engine_moe_*_total``.  The device sums are
+        int32 and wrap past 2**31 picks."""
+        if "moe_counters" not in self.cache:
+            return
+        with obs_trace.span("engine.sync", cat="engine",
+                            what="moe_counters"):
+            picks, held, rows = (int(v) for v in
+                                 np.asarray(self.cache["moe_counters"]))
+        m = self.metrics
+        for c, old, new in zip(self._c_moe, (m.moe_picks, m.moe_picks_held,
+                                             m.moe_rows_padded),
+                               (picks, held, rows)):
+            c.inc(max(0, new - (old or 0)))
+        m.moe_picks, m.moe_picks_held, m.moe_rows_padded = picks, held, rows
+
     def publish_metrics(self) -> None:
         """Mirror the :class:`EngineMetrics` summary into the registry as
         ``engine_*`` gauges (throughput, mean/percentile latencies, peak

@@ -50,7 +50,8 @@ def make_loss_fn(model, tcfg: TrainConfig):
         if cfg.prefix_tokens:
             hidden = hidden[:, cfg.prefix_tokens:]    # only text positions
         loss, metrics = loss_mod.chunked_xent(
-            hidden, labels, params["embed"]["table"],
+            hidden, labels, (params["head"] if "head" in params
+                             else params["embed"])["table"],
             mask=_batch_mask(model, batch), chunk=cfg.loss_chunk,
             mode=cfg.matmul_mode, policy=cfg.contraction_policy)
         total = loss + tcfg.aux_loss_weight * aux

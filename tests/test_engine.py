@@ -170,8 +170,9 @@ def test_engine_prepared_weights_match_raw():
     assert _toks(r_raw) == _toks(r_prep)
 
 
-def test_engine_moe_arch():
-    cfg, model, params = _model("moonshot-v1-16b-a3b")
+@pytest.mark.parametrize("arch", ["moonlight-16b-a3b", "mixtral-8x7b"])
+def test_engine_moe_arch(arch):
+    cfg, model, params = _model(arch)
     reqs = _ragged_requests(cfg, 4, seed=9)
     eng = Engine(model, params, EngineConfig(
         max_slots=4, block_size=8, num_blocks=32, blocks_per_seq=6,
@@ -617,3 +618,68 @@ def test_engine_counts_paged_columns_walked(window, walked):
     assert m.decode_slot_steps == 6
     assert (m.paged_cols_walked, m.paged_cols_spanned) == (walked, 48)
     assert m.summary()["paged_walk_share"] == pytest.approx(walked / 48)
+
+
+def test_position_resets_pad_to_a_power_of_two():
+    """A reset of n freed blocks scatters a power-of-two bucket of blocks,
+    padded with the null block, so only log2 of the counts compile; the
+    freed blocks' positions return to EMPTY_POS and no other slot moves."""
+    from repro.models.attention import EMPTY_POS
+    from repro.serve.paged import NULL_BLOCK
+
+    cfg, model, params = _model()
+    eng = Engine(model, params, EngineConfig(
+        max_slots=2, block_size=4, num_blocks=16, blocks_per_seq=4,
+        prefill_chunk=4, max_new_tokens=3))
+    seen = []
+    index = eng.tables.reset_slots_index
+
+    def spy(blocks):
+        seen.append(list(blocks))
+        return index(blocks)
+
+    eng.tables.reset_slots_index = spy
+    before = np.arange(16 * 4, dtype=np.int32)
+    eng.pos_pool = jax.numpy.asarray(before)
+    for freed in ([3], [5, 6, 7], [1, 2, 8, 9, 10]):
+        eng._reset_pos(freed)
+    assert [len(b) for b in seen] == [1, 4, 8]
+    assert all(b[len(f):] == [NULL_BLOCK] * (len(b) - len(f))
+               for b, f in zip(seen, ([3], [5, 6, 7], [1, 2, 8, 9, 10])))
+    want = before.copy()
+    for b in (0, 1, 2, 3, 5, 6, 7, 8, 9, 10):
+        want[b * 4:(b + 1) * 4] = EMPTY_POS
+    np.testing.assert_array_equal(np.asarray(eng.pos_pool), want)
+
+
+def test_heap_frozen_once_the_step_programs_have_run():
+    """After the first tick in which both a decode step and a prefill
+    chunk have run, the engine has collected once and frozen the heap, so
+    a later full collection walks only what serving allocated since; the
+    engine is still freed by reference counting once dropped."""
+    import gc
+    import weakref
+
+    cfg, model, params = _model()
+    eng = Engine(model, params, EngineConfig(
+        max_slots=2, block_size=8, num_blocks=16, blocks_per_seq=4,
+        prefill_chunk=8, max_new_tokens=3))
+    reqs = _ragged_requests(cfg, 2, lo=3, hi=12)
+    try:
+        eng.submit([Request(r.rid, r.tokens) for r in reqs])
+        while not (eng.metrics.decode_steps and eng.metrics.prefill_chunks):
+            assert not eng._heap_frozen
+            eng.step()
+        assert eng._heap_frozen and gc.get_freeze_count() > 0
+        while eng.step():
+            pass
+        assert all(r.ok for r in eng.drain_finished())
+        ref = weakref.ref(eng)
+        gc.disable()
+        try:
+            del eng
+            assert ref() is None
+        finally:
+            gc.enable()
+    finally:
+        gc.unfreeze()

@@ -159,12 +159,14 @@ def _decode_paged(model, params, prompt, n_new, *, block_size, num_blocks,
 
 
 @pytest.mark.parametrize("arch", ["deepseek-7b", "starcoder2-3b",
-                                  "moonshot-v1-16b-a3b"])
+                                  "moonlight-16b-a3b", "mixtral-8x7b"])
 def test_paged_matches_dense_decode(arch):
     """Gather-based paged attention (chunked prefill + paged decode) must
     agree with the dense prefill + per-slot decode path: same greedy
     tokens, logits within accumulation noise.  Covers MHA (deepseek), GQA
-    + sliding window + layernorm/bias (starcoder2), and MoE (moonshot)."""
+    + sliding window + layernorm/bias (starcoder2), latent attention over
+    one latent pool with a leading dense layer and held sigmoid-routed
+    experts (moonlight), and softmax top-k MoE (mixtral)."""
     cfg = get_config(arch).reduced()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(1))

@@ -229,3 +229,48 @@ def test_planner_budget_edge_plan_compiles(one_chip):
                        ((mp, 1), jnp.float32), ((1, np_), jnp.float32),
                        sharding=one_chip)
     assert "tpu_custom_call" in hlo
+
+
+# Moonlight-16B-A3B's cell: 32 decode rows, 16 heads over one latent head
+# (Dk = 512 + 64, Dv = 512), 160-column tables of 16-token blocks; 8 held
+# experts of d 2048 <-> 1408 at 32 tokens x top-6 picks (decode and the
+# 32-token prefill chunk alike)
+MOONLIGHT = dict(B=32, H=16, Dk=576, Dv=512, block_size=16,
+                 blocks_per_seq=160, d=2048, f=1408, held=8, tokens=32,
+                 topk=6)
+
+
+def test_latent_sq_paged_attn_compiles_at_moonlight_geometry(one_chip):
+    from bench import hlo as bench_hlo
+    m = MOONLIGHT
+    B, H, Dk, Dv, bs, nb = (m["B"], m["H"], m["Dk"], m["Dv"],
+                            m["block_size"], m["blocks_per_seq"])
+    pool = (1 + B * nb) * bs
+    fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False,
+                           pm_layout="mkn", dv=Dv)
+
+    def latent(q, kv, tables, pos_pool, q_pos):
+        return fn(q, kv, None, tables, pos_pool, q_pos)
+
+    hlo = _compile_hlo(latent, ((B, 1, 1, H, Dk), jnp.float32),
+                       ((pool, 1, Dk), jnp.bfloat16), ((B, nb), jnp.int32),
+                       ((pool,), jnp.int32), ((B, 1), jnp.int32),
+                       sharding=one_chip)
+    assert "tpu_custom_call" in hlo
+    attn = [c for c in bench_hlo.parse(hlo).contractions
+            if c.kind == "sq_paged_attn"]
+    assert len(attn) == 1
+    assert attn[0].flops == 2 * B * H * nb * bs * (Dk + Dv)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_sq_grouped_matmul_compiles_at_moonlight_geometry(one_chip, k, n):
+    from repro.kernels.sq_grouped_matmul import max_tiles
+    m = MOONLIGHT
+    tiles = max_tiles(m["tokens"] * m["topk"], m["held"], 8)
+    fn = functools.partial(ops.sq_grouped_matmul, bm=8, interpret=False)
+    hlo = _compile_hlo(fn, ((tiles * 8, k), jnp.float32),
+                       ((m["held"], k, n), jnp.bfloat16),
+                       ((tiles,), jnp.int32), ((1,), jnp.int32),
+                       sharding=one_chip)
+    assert "tpu_custom_call" in hlo
