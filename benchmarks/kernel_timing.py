@@ -95,6 +95,98 @@ def time_plan(kind, m, n, k, dtype, plan, *, reps=3, batch=1):
     raise ValueError(f"unknown kernel kind {kind!r}")
 
 
+def time_sq_matmul_kernel(m, n, k, plan, *, batch=1, reps=5, inner=10):
+    """Seconds per call of the ``sq_matmul`` Pallas kernel alone under an
+    explicit ``plan``: f32 operands already padded to the plan's tiles,
+    zero corrections, ``inner`` calls issued back to back per
+    synchronisation, the least over ``reps``.  On the chip this is the
+    kernel's device time plus a share of one dispatch; the operand prep
+    that ``ops.sq_matmul`` adds (widen, corrections, padding) is left
+    out.  Chip timings only: it refuses to run off the TPU."""
+    from repro.kernels.sq_matmul import sq_matmul_batched_pallas
+
+    _require_tpu()
+    rng = np.random.default_rng(0)
+    mp, np_, kp = (-(-m // plan.bm) * plan.bm, -(-n // plan.bn) * plan.bn,
+                   -(-k // plan.bk) * plan.bk)
+    a = jnp.asarray(rng.normal(size=(batch, mp, kp)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(batch, kp, np_)), jnp.float32)
+    sa = jnp.zeros((batch, mp, 1), jnp.float32)
+    sb = jnp.zeros((batch, 1, np_), jnp.float32)
+    fn = jax.jit(lambda a, b, sa, sb: sq_matmul_batched_pallas(
+        a, b, sa, sb, bm=plan.bm, bn=plan.bn, bk=plan.bk, kc=plan.kc,
+        pm_layout=plan.pm_layout, interpret=False))
+    jax.block_until_ready(fn(a, b, sa, sb))
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(inner):
+            out = fn(a, b, sa, sb)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / inner)
+    return best
+
+
+# The benchmark cells' sq_matmul calls, (batch, m, k, n): danube decodes 8
+# rows and prefills 32-row chunks (q/o 3840, k/v 1024 padded, MLP 10240,
+# head 32000); Moonlight decodes 32 rows (q 3072, latent 576, o 2048,
+# shared experts 2816, dense MLP 11264, head 20480) and runs its absorbed
+# latent projections as 16-head batched calls.
+CELL_SQ_MATMUL_SHAPES = (
+    [(1, m, 3840, n) for m in (8, 32) for n in (1024, 3840, 10240, 32000)]
+    + [(1, 8, 10240, 3840), (1, 32, 10240, 3840)]
+    + [(1, 32, 2048, n) for n in (3072, 576, 2816, 11264, 20480)]
+    + [(1, 32, 2048, 2048), (1, 32, 2816, 2048), (1, 32, 11264, 2048)]
+    + [(16, 32, 128, 512), (16, 32, 512, 128)])
+
+
+def _require_tpu():
+    if jax.default_backend() != "tpu":
+        raise SystemExit(f"sq_matmul kernel timings need the TPU; this "
+                         f"process runs on {jax.default_backend()!r}")
+
+
+def sweep_sq_matmul(shapes=CELL_SQ_MATMUL_SHAPES, *, budgets=(16, 8, 4),
+                    emit=print):
+    """Time the TPU planner's pick for each shape against alternatives:
+    the best "mkn" plan (the layout the kernel ran before "kmn") and, for
+    each accumulator budget in ``budgets`` (vregs), the cost model's best
+    "kmn" plan whose tile fits it -- the smaller tiles that leave more of
+    the register file to the partial sum and the broadcasts.  ``emit``
+    receives one dict per plan; returns them all."""
+    from repro.core import cost_model as cm
+    from repro.kernels import tuning
+
+    _require_tpu()
+    rows = []
+    for (batch, m, k, n) in shapes:
+        pick = tuning.plan_matmul(m, n, k, pm_layout="kmn", batch=batch)
+        plans = [pick, tuning.plan_matmul(m, n, k, pm_layout="mkn",
+                                          batch=batch)]
+        ranked = sorted(tuning.candidate_plans(m, n, k, pm_layout="kmn"),
+                        key=lambda p: cm.pm_walk_grid_cost(
+                            m, n, k, *p.astuple()).weighted)
+        for budget in budgets:
+            fit = [p for p in ranked
+                   if cm.kmn_acc_vregs(p.bm, p.bn) <= budget]
+            if fit and fit[0] not in plans:
+                plans.append(fit[0])
+        for plan in plans:
+            row = {"shape": [batch, m, k, n], "plan": list(plan.astuple()),
+                   "pm_layout": plan.pm_layout, "pick": plan == pick,
+                   "acc_vregs": cm.kmn_acc_vregs(plan.bm, plan.bn),
+                   "model": cm.pm_plan_cost(plan.pm_layout, m, n, k,
+                                            *plan.astuple()).weighted}
+            try:
+                row["us"] = time_sq_matmul_kernel(m, n, k, plan,
+                                                  batch=batch) * 1e6
+            except Exception as e:          # a plan the chip refuses
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            emit(row)
+            rows.append(row)
+    return rows
+
+
 def time_conv2d_plan(h, w, kh, kw, cin, cout, dtype, plan, *, stride=(1, 1),
                      reps=3, batch=1):
     """Wall-time one fused-conv2d call under an explicit plan (autotune hook).
@@ -313,3 +405,25 @@ def pallas_kernels():
          "us_per_call": im2col_b4_us,
          "shape": "b4 32x32x64->64 k3x3", "mode": "f32/im2col"},
     ]
+
+
+if __name__ == "__main__":
+    import argparse
+    import json
+
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--sweep-sq-matmul", metavar="OUT_JSONL", required=True,
+                    help="time the planner's sq_matmul picks against "
+                         "alternatives at the cells' shapes, one JSON "
+                         "line a plan")
+    args = ap.parse_args()
+    _require_tpu()
+    dev = jax.devices()[0]
+    with open(args.sweep_sq_matmul, "w") as f:
+        def emit(row):
+            row["device_kind"] = dev.device_kind
+            line = json.dumps(row)
+            print(line, flush=True)
+            f.write(line + "\n")
+            f.flush()
+        sweep_sq_matmul(emit=emit)

@@ -29,8 +29,9 @@ __all__ = ["ArithCost", "mac_cost", "pm_mac_cost", "complex_mac_cost",
            "cpm4_cost", "cpm3_cost", "systolic_array_cost",
            "tensor_core_cost", "savings_table",
            "TileCost", "vmem_tile_elems", "pm_tile_vmem_bytes",
-           "pm_tile_vpu_ops",
-           "pm_grid_cost", "conv2d_window_elems", "conv2d_patch_bytes",
+           "pm_tile_vpu_ops", "pm_grid_cost", "pm_walk_grid_cost",
+           "pm_plan_cost", "kmn_acc_vregs", "KMN_ACC_VREGS",
+           "conv2d_window_elems", "conv2d_patch_bytes",
            "conv2d_grid_cost", "paged_attn_gather_bytes"]
 
 
@@ -145,6 +146,14 @@ def tensor_core_cost(m: int, n_dim: int, k: int, n: int, square: bool,
 # style as the FA-equivalent model, but in VMEM bytes and VPU lane-ops.
 # --------------------------------------------------------------------------
 
+# Accumulator vregs a "kmn" tile may hold, of the 64-vreg register file.
+# With the group's partial sum as large again, a 32-vreg tile fills the
+# file and spills.  Timed on a TPU v5e (PERF.md §5, the sweep in
+# ``benchmarks/kernel_timing.py``): at M = 32 the 20-32-vreg tiles are the
+# fastest; at M = 8 a 30-vreg tile ran up to 1.13x a 15-vreg one.
+KMN_ACC_VREGS = 32
+
+
 @dataclasses.dataclass(frozen=True)
 class TileCost:
     """Cost of one (bm, bn, bk, kc) kernel plan over a full (m, n, k) call."""
@@ -222,6 +231,56 @@ def pm_grid_cost(m: int, n: int, k: int, bm: int, bn: int, bk: int, kc: int,
                               n_col_ops, n_acc)
     return TileCost(vmem_bytes=vmem, vpu_ops=pm, grid_steps=grid,
                     chunk_steps=chunks)
+
+
+def kmn_acc_vregs(bm: int, bn: int, fb: int = 1) -> int:
+    """Vregs a "kmn" accumulator tile of ``fb`` x (bm, bn) words holds."""
+    return fb * -(-bm // 8) * -(-bn // 128)
+
+
+def pm_walk_grid_cost(m: int, n: int, k: int, bm: int, bn: int, bk: int,
+                      kc: int, itemsize: int = 4) -> TileCost:
+    """Full-call cost of a "kmn" plan: the (bm, bn) tile in vregs, K
+    walked one index at a time in ``kc``-wide groups
+    (:func:`repro.kernels.pm_blocks.pm_row_walk`).
+
+    Per K index and grid step it pays, besides the three lane-ops of
+    every PM term, one lane broadcast of ``a``'s column (``bm / 8``
+    vregs) and one sublane broadcast of ``b``'s row (``bn / 128``
+    vregs); per group, one add of the partial into the accumulator.
+    Wide ``bn`` amortizes the first and tall ``bm`` the second, up to
+    :data:`KMN_ACC_VREGS` (the planner's bound, :func:`kmn_acc_vregs`).
+    VMEM holds the streamed slabs, the accumulator and the output block,
+    and nothing block-sized.
+    """
+    gm = -(-m // bm)
+    gn = -(-n // bn)
+    gk = -(-k // bk)
+    grid = gm * gn * gk
+    groups = grid * (-(-bk // kc))
+    rows = -(-bm // 8) * 8
+    lanes = -(-bn // 128) * 128
+    terms = float(gm * bm) * (gn * bn) * (gk * bk)
+    bcast = float(grid) * bk * (rows * 128 + 8 * lanes)
+    fold = float(groups) * rows * lanes
+    vmem = (2 * (vmem_tile_elems(bm, bk) + vmem_tile_elems(bk, bn))
+            + 2 * vmem_tile_elems(bm, bn)
+            + 2 * (vmem_tile_elems(bm, 1) + vmem_tile_elems(1, bn))
+            ) * itemsize
+    return TileCost(vmem_bytes=vmem, vpu_ops=3.0 * terms + bcast + fold,
+                    grid_steps=grid, chunk_steps=groups)
+
+
+def pm_plan_cost(pm_layout: str, m: int, n: int, k: int, bm: int, bn: int,
+                 bk: int, kc: int, itemsize: int = 4, n_row_ops: int = 1,
+                 n_col_ops: int = 1, n_acc: int = 1) -> TileCost:
+    """The cost of a matmul plan under its layout: :func:`pm_walk_grid_cost`
+    for "kmn" (one row and one column operand), :func:`pm_grid_cost` for
+    the chunked layouts."""
+    if pm_layout == "kmn":
+        return pm_walk_grid_cost(m, n, k, bm, bn, bk, kc, itemsize)
+    return pm_grid_cost(m, n, k, bm, bn, bk, kc, itemsize, n_row_ops,
+                        n_col_ops, n_acc)
 
 
 def conv2d_window_elems(bh: int, bw: int, kh: int, kw: int, bk: int,

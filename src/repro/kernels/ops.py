@@ -16,9 +16,10 @@ prepare-then-execute per call; passing a
 :func:`repro.core.prepared.prepare_operand`) reuses the prepared half, so
 both entry styles share one code path and are bit-identical by
 construction.  Corrections are computed BEFORE padding (padded zeros
-contribute zero anyway).  The PM-block layout ("mnk" on interpret/CPU,
-"mkn" on TPU -- see kernels.sq_matmul) is resolved here and baked into
-the plan.
+contribute zero anyway).  The PM layout is resolved here and baked into
+the plan (see kernels.sq_matmul): "mnk" on interpret/CPU; on the TPU,
+the "kmn" row walk for ``sq_matmul`` and "mkn" PM blocks for every other
+kernel.
 """
 from __future__ import annotations
 
@@ -28,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import conv as conv_core
+from repro.core import cost_model as cm
 from repro.core import squares as sq
 from repro.core.prepared import PreparedOperand
 from repro.kernels import tuning
@@ -80,7 +82,8 @@ def _pad_operands(plan, row_ops, col_ops, row_corrs, col_corrs):
 def _resolve_plan(m, n, k, dtype, *, bm, bn, bk, kc, pm_layout, interpret,
                   kind, n_row_ops=1, n_col_ops=1, n_acc=1, batch=1):
     """Backend-aware plan resolution (see module docstring)."""
-    layout = pm_layout or ("mnk" if interpret else "mkn")
+    layout = pm_layout or ("mnk" if interpret else
+                           "kmn" if kind == "sq_matmul" else "mkn")
     return tuning.plan_matmul(
         m, n, k, sq.accum_dtype(dtype), bm=bm, bn=bn, bk=bk, kc=kc,
         pm_layout=layout, kind=kind, n_row_ops=n_row_ops,
@@ -210,8 +213,12 @@ def _sq_matmul_batched_impl(a, b, fb, plan, interpret):
 def _pick_fb(plan, nb: int) -> int:
     """Batch-fold width: enough elements per grid step that the folded row
     tile reaches ~FOLD_ROW_TARGET rows (the small-(M, N) large-B regime;
-    see kernels.routing)."""
-    return max(1, min(nb, FOLD_ROW_TARGET // max(1, plan.bm)))
+    see kernels.routing); a "kmn" fold holds no more accumulator vregs
+    than one tile may."""
+    fb = FOLD_ROW_TARGET // max(1, plan.bm)
+    if plan.pm_layout == "kmn":
+        fb = min(fb, cm.KMN_ACC_VREGS // cm.kmn_acc_vregs(plan.bm, plan.bn))
+    return max(1, min(nb, fb))
 
 
 def sq_matmul(a, b, *, bm: int | None = None, bn: int | None = None,

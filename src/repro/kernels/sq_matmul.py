@@ -15,37 +15,45 @@ K axis (grid minor dimension), exactly like a weight-stationary pass:
 - every K step accumulates PM terms ``(a_ik + b_kj)^2`` (the PE array);
 - the final K step applies the paper's "simple right shift" (x0.5 / >>1).
 
-Dataflow (block-level PM accumulation)
---------------------------------------
-The contraction is **chunked, not rank-1**: each (bm, bk) x (bk, bn) grid
-step processes its K slab in ``kc``-wide chunks of rank-2 broadcast
-squaring.  One chunk forms the rank-3 PM block
+Dataflow
+--------
+Each (bm, bk) x (bk, bn) grid step accumulates its K slab under one of
+three static PM layouts (:mod:`repro.kernels.pm_blocks`), set by the
+backend (:mod:`repro.kernels.ops`); the planner in
+:mod:`repro.kernels.tuning` picks ``(bm, bn, bk, kc)`` for it:
 
-    s[i, c, j] = a[i, c] + b[c, j]          # (bm, kc, bn) operand adders
-    acc[i, j] += sum_c s[i, c, j]^2         # squarers + block reduction
+``"kmn"`` -- the TPU layout of this kernel
+    The (bm, bn) partial sum stays in vregs while K is walked one index
+    at a time::
 
-so a (256, 256, 128) tile is a handful of block-wide VPU passes rather
-than 128 serialized rank-1 sweeps.  ``kc`` (which must divide ``bk``) is
-the knob trading the live intermediate's footprint (bm * kc * bn
-accumulator-dtype words) against loop-issue overhead; a ``kc == bk`` plan
-degenerates to a single unrolled chunk with no inner loop at all.
+        part[i, j] += (a[i, k] + b[k, j])^2    # k = 0 .. kc-1, unrolled
 
-Two PM-block layouts are compiled, selected by the static ``pm_layout``:
-
+    with ``a``'s column broadcast over lanes and ``b``'s row over
+    sublanes; a ``fori_loop`` walks the slab in ``kc``-wide lane groups
+    (128 on the chip) and adds each group's partial to the carried
+    accumulator.  Nothing block-sized is formed: no (bm, kc, bn) value to
+    spill, no cross-sublane reduction.  One lane broadcast serves
+    ``bn / 128`` vregs of PM terms and one row broadcast ``bm / 8``, so
+    the planner takes wide ``bn`` and all rows of a small M in one tile
+    (the weights are then streamed once), bounded by the vreg budget of
+    :func:`repro.core.cost_model.pm_walk_grid_cost`.
 ``"mkn"``
-    The block is (bm, kc, bn), reduced over the middle axis.  ``bn`` stays
-    on the 128-lane minor axis, so Mosaic keeps native vreg layouts -- the
-    TPU-native schedule.
+    Chunked: one ``kc``-wide chunk forms the rank-3 PM block
+    ``s[i, c, j] = a[i, c] + b[c, j]`` (bm, kc, bn) and reduces it over
+    the middle axis.  At any useful ``kc`` the block outgrows the
+    register file and Mosaic spills each block-wide value to VMEM, so
+    this kernel runs it on the chip only when a caller asks for it.
+    The paged-attention and grouped-expert kernels, which share
+    :func:`pm_block_accum`, stay on it: their tiles were tuned and timed
+    on it and the row walk never was.
 ``"mnk"``
-    Each ``b`` chunk is transposed and the block is (bm, bn, kc), reduced
-    over the *minor* axis.  Minor-axis reduction fuses into a
-    dot-product-shaped loop nest, which is what CPU interpret mode (and
-    the XLA CPU backend generally) executes fastest -- ~6x over the seed
-    rank-1 kernel at 128^3 f32.
+    Chunked, with each ``b`` chunk transposed to a (bm, bn, kc) block
+    reduced over the *minor* axis: a dot-product-shaped loop nest, which
+    is what CPU interpret mode (and the XLA CPU backend generally)
+    executes fastest -- ~6x over the seed rank-1 kernel at 128^3 f32.
 
-Both are the same arithmetic (one operand add + one square per PM term);
-the planner in :mod:`repro.kernels.tuning` picks ``(bm, bn, bk, kc)`` and
-the layout per call site (cost-model ranked, optionally autotuned).
+All three are the same arithmetic (one operand add + one square per PM
+term); integer operands stay bit-exact under each.
 
 One kernel serves the plain, batched and batch-folded forms: its refs
 carry ``fb`` batch elements on a leading axis (``fb == 1`` for a plain or
@@ -56,9 +64,10 @@ accumulator is only live along K), so Mosaic may pipeline and reorder
 them freely; only the K axis is sequential.
 
 The squares execute on the VPU; on the paper's silicon they are the
-half-area squarer circuits.  This kernel is the bit-faithful *emulation*
-used for verification (float and int8 paths); the production MXU-routed
-path is ``core.matmul`` mode ``square_virtual``.
+half-area squarer circuits.  This kernel is the bit-faithful emulation
+of that datapath (float and int8 paths).  ``core.matmul`` mode
+``square_virtual`` is the standard MXU matmul under another name, not a
+faster route to the same squares.
 """
 from __future__ import annotations
 
@@ -69,20 +78,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pm_blocks import PM_LAYOUTS, pm_chunked_reduce
+from repro.kernels.pm_blocks import (KMN_GROUP, PM_LAYOUTS,
+                                     pm_chunked_reduce, pm_row_walk)
 
 __all__ = ["sq_matmul_kernel", "sq_matmul_pallas", "sq_matmul_batched_pallas",
            "pm_block_accum", "PM_LAYOUTS"]
 
 
 def pm_block_accum(acc, a_ref, b_ref, *, kc: int, pm_layout: str):
-    """Chunked block PM accumulation: ``acc + sum_k (a[i,k] + b[k,j])^2``.
+    """PM accumulation of one K slab: ``acc + sum_k (a[i,k] + b[k,j])^2``.
 
     a_ref: (..., bm, bk) and b_ref: (..., bk, bn) VMEM refs holding values
     pre-widened to the accumulator dtype; acc: the carried (..., bm, bn)
-    accumulator.  The K slab is processed in ``kc``-wide chunks via the
-    shared machinery in kernels.pm_blocks.
+    accumulator.  The K slab is walked in ``kc``-wide groups by the
+    shared machinery in kernels.pm_blocks: the row walk for "kmn", PM
+    blocks for the chunked layouts.
     """
+    if pm_layout == "kmn":
+        return pm_row_walk(acc, a_ref, b_ref, kc=kc)
+
     def body(rs, cs, axis, acc):
         s = rs[0] + cs[0]                    # PE operand adders
         return acc + jnp.sum(s * s, axis)    # squarers + block reduction
@@ -93,7 +107,7 @@ def pm_block_accum(acc, a_ref, b_ref, *, kc: int, pm_layout: str):
 
 def sq_matmul_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref, *,
                      nk: int, kc: int, pm_layout: str, is_int: bool):
-    """One (batch-block, i, j, k) grid step of the chunked square matmul.
+    """One (batch-block, i, j, k) grid step of the square matmul.
 
     a_ref: (fb, bm, bk); b_ref: (fb, bk, bn); sa_ref: (fb, bm, 1);
     sb_ref: (fb, 1, bn); out_ref and the scratch acc_ref: (fb, bm, bn).
@@ -133,14 +147,15 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
     wrapper zero-pads, and zero batch elements are exact no-ops).
     Operands must be pre-widened to the accumulator dtype and pre-padded
     to tile multiples (see kernels.ops).  ``kc`` must divide ``bk``
-    (defaults to ``bk``: one chunk)."""
+    (defaults to ``bk``: one chunk; for "kmn", one lane group)."""
     nb, m, k = a.shape
     nb2, k2, n = b.shape
     assert nb == nb2 and k == k2
     assert sa.shape == (nb, m, 1) and sb.shape == (nb, 1, n)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     assert nb % fb == 0, (nb, fb)
-    kc = bk if kc is None else kc
+    if kc is None:
+        kc = min(bk, KMN_GROUP) if pm_layout == "kmn" else bk
     assert bk % kc == 0, (bk, kc)
     nk = k // bk
     is_int = jnp.issubdtype(a.dtype, jnp.integer)

@@ -80,7 +80,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pm_blocks import PM_LAYOUTS
+from repro.kernels.pm_blocks import CHUNKED_LAYOUTS
 from repro.kernels.sq_matmul import pm_block_accum
 
 __all__ = ["sq_paged_attn", "sq_paged_attn_kernel", "tile_blocks",
@@ -279,9 +279,9 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
         interpret = default_interpret()
     if pm_layout is None:
         pm_layout = "mnk" if interpret else "mkn"
-    if pm_layout not in PM_LAYOUTS:
+    if pm_layout not in CHUNKED_LAYOUTS:
         raise ValueError(f"unknown pm_layout {pm_layout!r}; expected one "
-                         f"of {PM_LAYOUTS}")
+                         f"of {CHUNKED_LAYOUTS}")
     latent = v_pool is None
     if latent and not (dv and 0 < dv <= hd):
         raise ValueError(f"latent mode (no V pool) needs 0 < dv <= {hd}, "

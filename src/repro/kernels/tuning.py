@@ -7,16 +7,18 @@ Picks the ``(bm, bn, bk, kc)`` block plan for every kernel call site:
   operand is large enough to allow it);
 - ``bk`` is the K-slab streamed per grid step;
 - ``kc`` is the chunk width of the rank-2 broadcast squaring inside a step
-  (the live PM intermediate is (bm, kc, bn)).  A TPU ("mkn") plan chunks
-  in whole 128-lane groups or takes the slab in one chunk: Mosaic only
-  lowers a dynamic chunk start on the lane axis when it is a multiple of
-  128 (see kernels.pm_blocks).
+  (the live PM intermediate is (bm, kc, bn)), or for a "kmn" plan the K
+  group one loop iteration unrolls.  A TPU plan chunks in whole 128-lane
+  groups or takes the slab in one chunk: Mosaic only lowers a dynamic
+  chunk start on the lane axis when it is a multiple of 128 (see
+  kernels.pm_blocks).
 
 Two modes:
 
 **Model mode (default).**  Candidates are ranked by the analytical cost in
-:mod:`repro.core.cost_model` (``pm_grid_cost``): VPU lane-ops plus per-grid-
-step and per-chunk issue overheads, subject to a VMEM budget.  Deterministic,
+:mod:`repro.core.cost_model` (``pm_grid_cost``, ``pm_walk_grid_cost`` for
+"kmn"): VPU lane-ops plus per-grid-step and per-chunk issue overheads,
+subject to a VMEM budget (and, for "kmn", a vreg budget).  Deterministic,
 zero-warmup, good enough to avoid pathological plans.
 
 **Empirical mode.**  :func:`autotune_matmul` sweeps candidate plans through
@@ -45,6 +47,7 @@ import jax.numpy as jnp
 
 from repro.core import cost_model as cm
 from repro.core import squares as sq
+from repro.kernels.pm_blocks import KMN_GROUP
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -82,7 +85,9 @@ class TilePlan:
     bn: int
     bk: int
     kc: int
-    pm_layout: str = "mkn"      # "mkn": TPU-native; "mnk": minor-axis reduce
+    # "kmn": row walk, tile in vregs; "mkn": PM blocks, bn on lanes;
+    # "mnk": PM blocks reduced over the minor axis (CPU)
+    pm_layout: str = "mkn"
 
     def astuple(self):
         return (self.bm, self.bn, self.bk, self.kc)
@@ -156,10 +161,20 @@ def _align_kc(kc: int, bk: int) -> int:
     return kc
 
 
+def _lane_divisors(extent: int, cap: int) -> set[int]:
+    """Whole-lane tiles up to ``cap`` that divide the lane-multiple
+    ``extent`` exactly."""
+    return {c for c in range(LANE, min(cap, extent) + 1, LANE)
+            if extent % c == 0}
+
+
 def _kc_ladder(bk: int, pm_layout: str) -> list[int]:
     """The chunk widths a plan may take for a ``bk``-wide slab: every
     :data:`KC_CANDIDATES` width (aligned to divide ``bk``) for "mnk";
-    whole lane groups dividing ``bk``, or the whole slab, for "mkn"."""
+    whole lane groups dividing ``bk``, or the whole slab, for "mkn"; one
+    lane group (the whole slab when it is narrower) for "kmn"."""
+    if pm_layout == "kmn":
+        return [KMN_GROUP if bk % KMN_GROUP == 0 else bk]
     if pm_layout == "mkn":
         return sorted({bk} | {c for c in range(LANE, bk, LANE)
                               if bk % c == 0})
@@ -173,7 +188,11 @@ def candidate_plans(m: int, n: int, k: int,
                     vmem_budget: int = VMEM_BUDGET) -> list[TilePlan]:
     """Enumerate aligned, budget-feasible plans for an (m, n, k) contraction.
 
-    Every plan respects the VMEM budget; "mnk"-layout plans additionally
+    Every plan respects the VMEM budget; "kmn" plans also keep their
+    accumulator tile within :data:`repro.core.cost_model.KMN_ACC_VREGS`
+    vregs, and take as ``bn``/``bk`` the whole-lane divisors of their
+    lane-aligned axis, ``bn`` up to 4096 lanes (one lane broadcast of
+    ``a`` then serves more PM vregs).  "mnk"-layout plans additionally
     cap ``kc`` at :data:`KC_MNK_MAX` and keep the hot loop-nest panel (the
     transposed (bn, kc) column slab plus a sublane row stripe) inside
     :data:`CACHE_BUDGET`.  (An earlier rule bounded the whole (bm, bn, kc)
@@ -192,18 +211,31 @@ def candidate_plans(m: int, n: int, k: int,
                  | {_align_lane(n, n)})
     bks = sorted({_align_lane(c, k) for c in (128, 256, 512)}
                  | {_align_lane(k, k)})
+    if pm_layout == "kmn":
+        # whole-lane tiles that pad nothing past the lane granule: the
+        # walk's cost is per padded term, and a prepared weight laid out
+        # at that granule is then reused as it is
+        bms = sorted(set(bms) | {_align_bm(16, m)})
+        bns = sorted(_lane_divisors(_round_up(n, LANE), 4096)) \
+            if n >= LANE else [n]
+        bks = sorted(_lane_divisors(_round_up(k, LANE), _round_up(k, LANE))) \
+            if k >= LANE else [k]
     plans = []
     for bm in bms:
         for bn in bns:
+            if pm_layout == "kmn" and \
+                    cm.kmn_acc_vregs(bm, bn) > cm.KMN_ACC_VREGS:
+                continue
             for bk in bks:
                 for kc in _kc_ladder(bk, pm_layout):
                     if pm_layout == "mnk" and kc > 1 and (
                             kc > KC_MNK_MAX or
                             (bn + SUBLANE) * kc * itemsize > CACHE_BUDGET):
                         continue
-                    cost = cm.pm_grid_cost(
-                        m, n, k, bm, bn, bk, kc, itemsize=itemsize,
-                        n_row_ops=n_row_ops, n_col_ops=n_col_ops, n_acc=n_acc)
+                    cost = cm.pm_plan_cost(
+                        pm_layout, m, n, k, bm, bn, bk, kc,
+                        itemsize=itemsize, n_row_ops=n_row_ops,
+                        n_col_ops=n_col_ops, n_acc=n_acc)
                     if cost.vmem_bytes <= vmem_budget:
                         plans.append(TilePlan(bm, bn, bk, kc, pm_layout))
     if not plans:      # degenerate shapes: fall back to a single minimal plan
@@ -308,9 +340,9 @@ def _model_pick(m: int, n: int, k: int, *, itemsize: int, n_row_ops: int,
                             n_col_ops=n_col_ops, n_acc=n_acc,
                             pm_layout=pm_layout)
     costs = {
-        p: cm.pm_grid_cost(m, n, k, *p.astuple(), itemsize=itemsize,
-                           n_row_ops=n_row_ops, n_col_ops=n_col_ops,
-                           n_acc=n_acc).weighted
+        p: cm.pm_plan_cost(pm_layout, m, n, k, *p.astuple(),
+                           itemsize=itemsize, n_row_ops=n_row_ops,
+                           n_col_ops=n_col_ops, n_acc=n_acc).weighted
         for p in plans
     }
     return min(plans, key=lambda p: costs[p])
@@ -334,6 +366,9 @@ _HIT_COUNTER = obs_metrics.default_registry().counter(
 _MISS_COUNTER = obs_metrics.default_registry().counter(
     "tuning_cache_misses_total",
     help="autotune-cache lookups that fell back to the cost model")
+_KMN_COUNTER = obs_metrics.default_registry().counter(
+    "tuning_sq_matmul_kmn_plans_total",
+    help="sq_matmul plans resolved to the kmn row walk")
 
 
 def autotune_enabled() -> bool:
@@ -427,8 +462,9 @@ def plan_matmul(m: int, n: int, k: int, dtype=jnp.float32, *,
     """Pick the (bm, bn, bk, kc, pm_layout) plan for a matmul-shaped call.
 
     ``pm_layout`` is backend-driven, not cost-modelled: callers pass "mnk"
-    for interpret/CPU execution and "mkn" for real TPU lowering (see
-    kernels.sq_matmul for what each means).
+    for interpret/CPU execution, and for real TPU lowering "kmn"
+    (``sq_matmul``) or "mkn" (every other kernel); see kernels.sq_matmul
+    for what each means.
 
     ``batch`` > 1 plans a batched GEMM (leading batch grid axis, one
     element per grid step).  The per-step working set is identical to the
@@ -442,6 +478,9 @@ def plan_matmul(m: int, n: int, k: int, dtype=jnp.float32, *,
     disables cache consultation (and the warning) entirely.  Explicit
     values are still clamped to the (padded) operand extent and aligned to
     the hardware granules, which may round them up (see module docstring).
+    A "kmn" ``kc`` is further held to one lane group (it is unrolled).
+    Every ``sq_matmul`` plan that resolves to "kmn" counts in the
+    ``tuning_sq_matmul_kmn_plans_total`` counter.
 
     Fully-specified plans skip cache and model (alignment still applies,
     e.g. bm=100 rounds up to the next sublane multiple)::
@@ -456,9 +495,10 @@ def plan_matmul(m: int, n: int, k: int, dtype=jnp.float32, *,
         # Fully specified: no enumeration, no cache consult.  Kept cheap on
         # purpose -- benchmark/autotune loops plan on every call.
         pbk = _align_lane(bk, k)
-        return TilePlan(_align_bm(bm, m), _align_lane(bn, n), pbk,
-                        _align_kc(kc if kc is not None else pbk, pbk),
-                        pm_layout)
+        return _counted(TilePlan(
+            _align_bm(bm, m), _align_lane(bn, n), pbk,
+            _plan_kc(kc if kc is not None else pbk, pbk, pm_layout),
+            pm_layout), kind)
     itemsize = jnp.dtype(dtype).itemsize
     use_cache = autotune_enabled()
     key = _key(kind, m, n, k, dtype, batch)
@@ -469,20 +509,35 @@ def plan_matmul(m: int, n: int, k: int, dtype=jnp.float32, *,
         # Serve the cache only for the requested layout: an autotune run on
         # a CPU host must not dictate "mnk" to a TPU caller.
         _note_cache_lookup(key, hit=True)
-        return TilePlan(*(int(cached[f]) for f in ("bm", "bn", "bk", "kc")),
-                        pm_layout)
+        return _counted(TilePlan(
+            *(int(cached[f]) for f in ("bm", "bn", "bk", "kc")),
+            pm_layout), kind)
     base = _model_pick(m, n, k, itemsize=itemsize, n_row_ops=n_row_ops,
                        n_col_ops=n_col_ops, n_acc=n_acc, pm_layout=pm_layout)
     pbm = _align_bm(bm if bm is not None else base.bm, m)
     pbn = _align_lane(bn if bn is not None else base.bn, n)
     pbk = _align_lane(bk if bk is not None else base.bk, k)
-    pkc = _align_kc(kc if kc is not None else base.kc, pbk)
+    pkc = _plan_kc(kc if kc is not None else base.kc, pbk, pm_layout)
     plan = TilePlan(pbm, pbn, pbk, pkc, pm_layout)
     if use_cache and cached is None and bm is None and bn is None \
             and bk is None and kc is None:
         _note_cache_lookup(key, hit=False)
         _warn_cache_miss(key, {"bm": plan.bm, "bn": plan.bn, "bk": plan.bk,
                                "kc": plan.kc, "pm_layout": plan.pm_layout})
+    return _counted(plan, kind)
+
+
+def _plan_kc(kc: int, bk: int, pm_layout: str) -> int:
+    """``kc`` aligned to divide ``bk``; a "kmn" group at most one lane
+    group wide, since the walk unrolls it."""
+    if pm_layout == "kmn":
+        kc = min(kc, KMN_GROUP)
+    return _align_kc(kc, bk)
+
+
+def _counted(plan: TilePlan, kind: str) -> TilePlan:
+    if kind == "sq_matmul" and plan.pm_layout == "kmn":
+        _KMN_COUNTER.inc()
     return plan
 
 
@@ -693,9 +748,9 @@ def autotune_matmul(shapes: Iterable[tuple[int, int, int]],
             plans = candidate_plans(m, n, k, itemsize=itemsize,
                                     n_row_ops=nro, n_col_ops=nco,
                                     n_acc=nacc, pm_layout=layout)
-            plans.sort(key=lambda p: cm.pm_grid_cost(
-                m, n, k, *p.astuple(), itemsize=itemsize, n_row_ops=nro,
-                n_col_ops=nco, n_acc=nacc).weighted)
+            plans.sort(key=lambda p: cm.pm_plan_cost(
+                p.pm_layout, m, n, k, *p.astuple(), itemsize=itemsize,
+                n_row_ops=nro, n_col_ops=nco, n_acc=nacc).weighted)
             for plan in plans[:max_candidates]:
                 us = kt.time_plan(kind, m, n, k, dtype, plan, reps=reps,
                                   batch=batch)

@@ -27,6 +27,11 @@ PLANS = [
     dict(bm=64, bn=128, bk=128, kc=1, pm_layout="mkn"),    # rank-1 (seed)
     dict(bm=64, bn=128, bk=128, kc=32, pm_layout="mnk"),
     dict(bm=8, bn=128, bk=64, kc=16, pm_layout="mkn"),
+    # "kmn" row walk: one unrolled group; two lane groups over a K of 200
+    # padded to 256; bm 32 with a bn of 512 (clamped to these shapes)
+    dict(bm=8, bn=128, bk=64, kc=64, pm_layout="kmn"),
+    dict(bm=8, bn=128, bk=256, kc=128, pm_layout="kmn"),
+    dict(bm=32, bn=512, bk=256, kc=128, pm_layout="kmn"),
 ]
 
 
@@ -319,3 +324,158 @@ def test_autotune_batched_writes_batch_key(tmp_path, monkeypatch):
                               pm_layout=cache[key]["pm_layout"])
     assert plan.bm == cache[key]["bm"] and plan.kc == cache[key]["kc"]
     tuning.clear_cache()
+
+
+# ---------------------------------------------------------------------------
+# The "kmn" row walk: wide tiles, the batched/fold path, TPU planning
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+@pytest.mark.parametrize("bm,bn", [(8, 512), (32, 512), (32, 128)])
+def test_kmn_wide_tiles_agree(dtype, bm, bn):
+    """Tiles as wide as the chip's plans: bn 512 over N = 600 (padded to
+    1024), a ragged K = 300 walked in two 128-wide groups of a padded
+    256-wide slab."""
+    m, k, n = 40, 300, 600
+    if dtype == "int8":
+        a = jnp.asarray(RNG.integers(-128, 128, (m, k)).astype(np.int8))
+        b = jnp.asarray(RNG.integers(-128, 128, (k, n)).astype(np.int8))
+    else:
+        a = jnp.asarray(RNG.normal(size=(m, k)).astype(np.float32))
+        b = jnp.asarray(RNG.normal(size=(k, n)).astype(np.float32))
+    out = np.asarray(ops.sq_matmul(a, b, bm=bm, bn=bn, bk=256, kc=128,
+                                   pm_layout="kmn"))
+    ref = np.asarray(pm_matmul_scan(a, b))
+    if dtype == "int8":
+        np.testing.assert_array_equal(out, ref)
+    else:
+        np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-2)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+@pytest.mark.parametrize("fold", [False, True])
+def test_kmn_batched_agrees(dtype, fold):
+    """The batched and batch-folded kernels under "kmn" compute what the
+    chunked layout does (int8 bit-exact)."""
+    nb, m, k, n = 5, 16, 128, 256
+    if dtype == "int8":
+        a = jnp.asarray(RNG.integers(-128, 128, (nb, m, k)).astype(np.int8))
+        b = jnp.asarray(RNG.integers(-128, 128, (nb, k, n)).astype(np.int8))
+    else:
+        a = jnp.asarray(RNG.normal(size=(nb, m, k)).astype(np.float32))
+        b = jnp.asarray(RNG.normal(size=(nb, k, n)).astype(np.float32))
+    out = np.asarray(ops.sq_matmul(a, b, pm_layout="kmn", fold=fold))
+    ref = np.asarray(ops.sq_matmul(a, b, pm_layout="mnk"))
+    if dtype == "int8":
+        np.testing.assert_array_equal(out, ref)
+    else:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-3)
+
+
+def test_kmn_fold_width_keeps_vreg_budget():
+    from repro.core import cost_model as cm
+    plan = tuning.TilePlan(8, 512, 128, 128, "kmn")
+    fb = ops._pick_fb(plan, 64)
+    assert fb * cm.kmn_acc_vregs(8, 512) <= cm.KMN_ACC_VREGS
+    assert ops._pick_fb(tuning.TilePlan(8, 512, 128, 128, "mkn"), 64) == 32
+
+
+@pytest.mark.parametrize("m", [8, 32])
+def test_tpu_sq_matmul_plan_is_kmn(monkeypatch, m):
+    """On the TPU, sq_matmul resolves the row walk at the decode shapes,
+    with every row of the call in one tile (the weights stream once) and
+    the tile within the vreg budget; the grouped kernel still resolves
+    "mkn"."""
+    from repro.core import cost_model as cm
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    plan = ops._resolve_plan(m, 10240, 3840, jnp.bfloat16, bm=None, bn=None,
+                             bk=None, kc=None, pm_layout=None,
+                             interpret=False, kind="sq_matmul")
+    assert plan.pm_layout == "kmn"
+    assert plan.bm == m and plan.bn >= 512 and plan.kc == 128
+    assert cm.kmn_acc_vregs(plan.bm, plan.bn) <= cm.KMN_ACC_VREGS
+    grouped = ops._resolve_plan(8, 1408, 2048, jnp.float32, bm=None,
+                                bn=None, bk=None, kc=None, pm_layout=None,
+                                interpret=False, kind="sq_grouped_matmul")
+    assert grouped.pm_layout == "mkn"
+
+
+def test_grouped_and_paged_kernels_stay_chunked(monkeypatch):
+    """sq_grouped_matmul's plan resolves "mkn" on the TPU, and the paged
+    kernel refuses the row walk."""
+    from repro.kernels import sq_paged_attn as spa
+    seen = []
+    real = ops._resolve_plan
+
+    def spy(*args, **kw):
+        plan = real(*args, **kw)
+        seen.append(plan.pm_layout)
+        return plan
+
+    monkeypatch.setattr(ops, "_resolve_plan", spy)
+    monkeypatch.setattr(ops, "_sq_grouped_exec",
+                        lambda *a, **k: None)
+    x = jnp.zeros((16, 256), jnp.float32)
+    w = jnp.zeros((2, 256, 128), jnp.float32)
+    ops.sq_grouped_matmul(x, w, jnp.zeros((2,), jnp.int32),
+                          jnp.ones((1,), jnp.int32), bm=8, interpret=False)
+    assert seen == ["mkn"]
+    with pytest.raises(ValueError, match="pm_layout"):
+        spa.sq_paged_attn(jnp.zeros((1, 1, 1, 1, 8)), jnp.zeros((16, 1, 8)),
+                          jnp.zeros((16, 1, 8)), jnp.zeros((1, 1), jnp.int32),
+                          jnp.zeros((16,), jnp.int32),
+                          jnp.zeros((1, 1), jnp.int32), block_size=16,
+                          pm_layout="kmn")
+
+
+def test_kmn_plan_counter(monkeypatch):
+    """tuning_sq_matmul_kmn_plans_total counts sq_matmul plans resolved to
+    "kmn", and nothing else."""
+    from repro.obs import metrics as obs_metrics
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    ctr = obs_metrics.default_registry().counter(
+        "tuning_sq_matmul_kmn_plans_total")
+    before = ctr.value
+    tuning.plan_matmul(8, 10240, 3840, pm_layout="kmn")
+    tuning.plan_matmul(32, 10240, 3840, pm_layout="kmn")
+    assert ctr.value == before + 2
+    tuning.plan_matmul(8, 10240, 3840, pm_layout="mkn")
+    tuning.plan_matmul(8, 1408, 2048, pm_layout="mkn",
+                       kind="sq_grouped_matmul")
+    tuning.plan_matmul(64, 64, 64, pm_layout="mnk")
+    assert ctr.value == before + 2
+    tuning.plan_matmul(64, 64, 64, bm=8, bn=128, bk=64, pm_layout="kmn")
+    assert ctr.value == before + 3
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((8, 1408, 2048), (8, 128, 2048, 2048)),
+    ((8, 2048, 1408), (8, 128, 1408, 1408)),
+    ((32, 10240, 3840), (8, 128, 3840, 1920)),
+    ((256, 256, 256), (32, 256, 256, 256)),
+])
+def test_chunked_layouts_plan_as_before(monkeypatch, shape, want):
+    """A single chunked layout is still ranked by the unchanged cost: the
+    "mkn" picks of the grouped kernel are the ones it had before "kmn"
+    existed (Moonlight's expert shapes among them)."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    m, n, k = shape
+    plan = tuning.plan_matmul(m, n, k, pm_layout="mkn",
+                              kind="sq_grouped_matmul")
+    assert plan == tuning.TilePlan(*want, "mkn")
+
+
+@pytest.mark.parametrize("m", [8, 32, 256])
+def test_kmn_plans_pad_only_to_the_lane_granule(monkeypatch, m):
+    """A "kmn" plan pads K and N no further than the 128-lane granule, so
+    the bench's HLO counts the call's true shape and a prepared weight
+    (laid out at that granule) is reused without a per-call re-pad."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+
+    def up(x):
+        return -(-x // tuning.LANE) * tuning.LANE
+
+    for (k, n) in [(3840, 1024), (3840, 10240), (10240, 3840), (3840, 32000),
+                   (2048, 2816), (2048, 576), (2816, 2048), (2048, 20480)]:
+        plan = tuning.plan_matmul(m, n, k, pm_layout="kmn")
+        assert up(k) % plan.bk == 0 and up(n) % plan.bn == 0, (k, n, plan)

@@ -8,8 +8,9 @@ the scoped VMEM), so these tests guard the kernels the chip runs.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.  Every test passes
-``interpret=False`` and the TPU layout ``pm_layout="mkn"`` explicitly,
-because code that asks ``jax.default_backend()`` still sees the CPU.
+``interpret=False`` and a TPU layout (``pm_layout="mkn"``, or "kmn" for
+``sq_matmul``) explicitly, because code that asks
+``jax.default_backend()`` still sees the CPU.
 """
 import dataclasses
 import functools
@@ -61,22 +62,71 @@ def _compile_hlo(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("m,k,n", [(8, 3840, 10240), (256, 3840, 3840)])
-def test_sq_matmul_compiles(one_chip, m, k, n):
-    fn = functools.partial(ops.sq_matmul, interpret=False, pm_layout="mkn")
+def _names_sq_matmul_kernel(hlo):
+    """The compiled body still carries the kernel's name, which the
+    benchmark's HLO reader counts it by (``bench/kernels/sq_matmul.py``)."""
+    from bench import hlo as bench_hlo
+    return any(c.kind == "sq_matmul"
+               for c in bench_hlo.parse(hlo).contractions)
+
+
+# danube's decode and chunk GEMMs, its MLP down-projection and head, and
+# Moonlight's head (an eighth of the vocabulary); the "mkn" cases keep
+# the ids they had before the layout was a parameter
+@pytest.mark.parametrize("m,k,n,pm_layout", [
+    pytest.param(m, k, n, layout,
+                 id=f"{m}-{k}-{n}" + ("" if layout == "mkn" else "-kmn"))
+    for layout in ("mkn", "kmn")
+    for (m, k, n) in [(8, 3840, 10240), (256, 3840, 3840), (8, 10240, 3840),
+                      (32, 3840, 32000), (32, 2048, 20480)]])
+def test_sq_matmul_compiles(one_chip, m, k, n, pm_layout):
+    fn = functools.partial(ops.sq_matmul, interpret=False,
+                           pm_layout=pm_layout)
     hlo = _compile_hlo(fn, ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
                        sharding=one_chip)
     assert "tpu_custom_call" in hlo
+    if pm_layout == "kmn":
+        assert _names_sq_matmul_kernel(hlo)
 
 
-@pytest.mark.parametrize("fold", [False, True])
-def test_sq_matmul_batched_compiles(one_chip, fold):
-    # attention-shaped batched GEMM: B*H heads of (S, hd) @ (hd, T)
-    fn = functools.partial(ops.sq_matmul, interpret=False, pm_layout="mkn",
-                           fold=fold)
-    hlo = _compile_hlo(fn, ((32, 16, 128), jnp.bfloat16),
-                       ((32, 128, 256), jnp.bfloat16), sharding=one_chip)
+# attention-shaped batched GEMM (B*H heads of (S, hd) @ (hd, T)), folded
+# or not, and Moonlight's absorbed latent projections, 16 heads of 32
+# rows, which route to the batched (unfolded) kernel
+@pytest.mark.parametrize("b,m,k,n,fold,pm_layout", [
+    pytest.param(b, m, k, n, fold, layout,
+                 id=("" if (b, m, k, n) == (32, 16, 128, 256)
+                     else f"{b}-{m}-{k}-{n}-") + str(fold)
+                 + ("" if layout == "mkn" else "-kmn"))
+    for layout in ("mkn", "kmn")
+    for (b, m, k, n, fold) in [(32, 16, 128, 256, False),
+                               (32, 16, 128, 256, True),
+                               (16, 32, 128, 512, False),
+                               (16, 32, 512, 128, False)]])
+def test_sq_matmul_batched_compiles(one_chip, fold, pm_layout, b, m, k, n):
+    fn = functools.partial(ops.sq_matmul, interpret=False,
+                           pm_layout=pm_layout, fold=fold)
+    hlo = _compile_hlo(fn, ((b, m, k), jnp.bfloat16),
+                       ((b, k, n), jnp.bfloat16), sharding=one_chip)
     assert "tpu_custom_call" in hlo
+    if pm_layout == "kmn":
+        assert _names_sq_matmul_kernel(hlo)
+
+
+def test_sq_matmul_tpu_plans_compile(one_chip, monkeypatch):
+    """The planner's own TPU picks (as ``ops._resolve_plan`` resolves
+    them on the chip) compile at the cells' decode shapes, and take the
+    row walk."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    for (m, k, n) in [(8, 3840, 10240), (32, 3840, 3840), (32, 2048, 2048)]:
+        plan = ops._resolve_plan(m, n, k, jnp.bfloat16, bm=None, bn=None,
+                                 bk=None, kc=None, pm_layout=None,
+                                 interpret=False, kind="sq_matmul")
+        assert plan.pm_layout == "kmn", plan
+        fn = functools.partial(ops.sq_matmul, interpret=False,
+                               **dataclasses.asdict(plan))
+        hlo = _compile_hlo(fn, ((m, k), jnp.bfloat16),
+                           ((k, n), jnp.bfloat16), sharding=one_chip)
+        assert _names_sq_matmul_kernel(hlo)
 
 
 @pytest.mark.parametrize("op", [ops.cpm3_matmul, ops.cpm4_matmul])
