@@ -7,9 +7,8 @@ and ``mode`` (plus optional extras) -- the same fields ``benchmarks/run.py
 --json`` writes to ``BENCH_kernels.json`` so kernel speedups are trackable
 across PRs.
 
-``time_plan`` is the hook the empirical autotuner
-(:func:`repro.kernels.tuning.autotune_matmul`) drives: it times one kernel
-call under an explicit tile plan.
+``--sweep-sq-matmul OUT_JSONL`` times the planner's ``sq_matmul`` picks
+on the TPU against other row-walk plans at the benchmark cells' shapes.
 """
 from __future__ import annotations
 
@@ -55,46 +54,6 @@ def _time_pair(fn_a, fn_b, *, reps=5, warmup=2):
     return best_a * 1e6, best_b * 1e6
 
 
-def _plan_kwargs(plan):
-    """kwargs for kernels.ops wrappers from a TilePlan (or a bare tuple)."""
-    if hasattr(plan, "astuple"):
-        bm, bn, bk, kc = plan.astuple()
-        return dict(bm=bm, bn=bn, bk=bk, kc=kc,
-                    pm_layout=getattr(plan, "pm_layout", None))
-    bm, bn, bk, kc = plan
-    return dict(bm=bm, bn=bn, bk=bk, kc=kc)
-
-
-def time_plan(kind, m, n, k, dtype, plan, *, reps=3, batch=1):
-    """Wall-time one kernel call under an explicit tile plan (autotune hook).
-
-    kind: "sq_matmul" | "cpm3_matmul" | "cpm4_matmul".  ``batch`` > 1
-    times the batched (leading-batch-grid-axis) kernel -- sq_matmul only.
-    """
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(0)
-    kwargs = _plan_kwargs(plan)
-    if kind == "sq_matmul":
-        lead = (batch,) if batch > 1 else ()
-        a = jnp.asarray(rng.normal(size=lead + (m, k)).astype(np.dtype(dtype)))
-        b = jnp.asarray(rng.normal(size=lead + (k, n)).astype(np.dtype(dtype)))
-        fn = lambda a, b: ops.sq_matmul(a, b, **kwargs)
-        return _time(fn, a, b, reps=reps)
-    if batch > 1:
-        raise ValueError(f"batched timing is only supported for sq_matmul, "
-                         f"not {kind!r}")
-    if kind in ("cpm3_matmul", "cpm4_matmul"):
-        x = jnp.asarray((rng.normal(size=(m, k))
-                         + 1j * rng.normal(size=(m, k))).astype(np.complex64))
-        y = jnp.asarray((rng.normal(size=(k, n))
-                         + 1j * rng.normal(size=(k, n))).astype(np.complex64))
-        op = getattr(ops, kind)
-        fn = lambda x, y: op(x, y, **kwargs)[0]
-        return _time(fn, x, y, reps=reps)
-    raise ValueError(f"unknown kernel kind {kind!r}")
-
-
 def time_sq_matmul_kernel(m, n, k, plan, *, batch=1, reps=5, inner=10):
     """Seconds per call of the ``sq_matmul`` Pallas kernel alone under an
     explicit ``plan``: f32 operands already padded to the plan's tiles,
@@ -115,7 +74,7 @@ def time_sq_matmul_kernel(m, n, k, plan, *, batch=1, reps=5, inner=10):
     sb = jnp.zeros((batch, 1, np_), jnp.float32)
     fn = jax.jit(lambda a, b, sa, sb: sq_matmul_batched_pallas(
         a, b, sa, sb, bm=plan.bm, bn=plan.bn, bk=plan.bk, kc=plan.kc,
-        pm_layout=plan.pm_layout, interpret=False))
+        interpret=False))
     jax.block_until_ready(fn(a, b, sa, sb))
     best = float("inf")
     for _ in range(reps):
@@ -148,11 +107,10 @@ def _require_tpu():
 
 def sweep_sq_matmul(shapes=CELL_SQ_MATMUL_SHAPES, *, budgets=(16, 8, 4),
                     emit=print):
-    """Time the TPU planner's pick for each shape against alternatives:
-    the best "mkn" plan (the layout the kernel ran before "kmn") and, for
+    """Time the planner's pick for each shape against alternatives: for
     each accumulator budget in ``budgets`` (vregs), the cost model's best
-    "kmn" plan whose tile fits it -- the smaller tiles that leave more of
-    the register file to the partial sum and the broadcasts.  ``emit``
+    row-walk plan whose tile fits it -- the smaller tiles that leave more
+    of the register file to the partial sum and the broadcasts.  ``emit``
     receives one dict per plan; returns them all."""
     from repro.core import cost_model as cm
     from repro.kernels import tuning
@@ -160,10 +118,9 @@ def sweep_sq_matmul(shapes=CELL_SQ_MATMUL_SHAPES, *, budgets=(16, 8, 4),
     _require_tpu()
     rows = []
     for (batch, m, k, n) in shapes:
-        pick = tuning.plan_matmul(m, n, k, pm_layout="kmn", batch=batch)
-        plans = [pick, tuning.plan_matmul(m, n, k, pm_layout="mkn",
-                                          batch=batch)]
-        ranked = sorted(tuning.candidate_plans(m, n, k, pm_layout="kmn"),
+        pick = tuning.plan_matmul(m, n, k)
+        plans = [pick]
+        ranked = sorted(tuning.candidate_plans(m, n, k),
                         key=lambda p: cm.pm_walk_grid_cost(
                             m, n, k, *p.astuple()).weighted)
         for budget in budgets:
@@ -173,10 +130,10 @@ def sweep_sq_matmul(shapes=CELL_SQ_MATMUL_SHAPES, *, budgets=(16, 8, 4),
                 plans.append(fit[0])
         for plan in plans:
             row = {"shape": [batch, m, k, n], "plan": list(plan.astuple()),
-                   "pm_layout": plan.pm_layout, "pick": plan == pick,
+                   "pick": plan == pick,
                    "acc_vregs": cm.kmn_acc_vregs(plan.bm, plan.bn),
-                   "model": cm.pm_plan_cost(plan.pm_layout, m, n, k,
-                                            *plan.astuple()).weighted}
+                   "model": cm.pm_walk_grid_cost(m, n, k,
+                                                 *plan.astuple()).weighted}
             try:
                 row["us"] = time_sq_matmul_kernel(m, n, k, plan,
                                                   batch=batch) * 1e6
@@ -185,25 +142,6 @@ def sweep_sq_matmul(shapes=CELL_SQ_MATMUL_SHAPES, *, budgets=(16, 8, 4),
             emit(row)
             rows.append(row)
     return rows
-
-
-def time_conv2d_plan(h, w, kh, kw, cin, cout, dtype, plan, *, stride=(1, 1),
-                     reps=3, batch=1):
-    """Wall-time one fused-conv2d call under an explicit plan (autotune hook).
-
-    ``h``/``w`` are the padded input spatial extents (VALID geometry --
-    exactly what :func:`repro.kernels.tuning.plan_conv2d` keys on).
-    """
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(0)
-    lead = (batch,) if batch > 1 else ()
-    x = jnp.asarray(rng.normal(size=lead + (cin, h, w)).astype(np.dtype(dtype)))
-    wt = jnp.asarray(rng.normal(size=(cout, cin, kh, kw)).astype(np.dtype(dtype)))
-    fn = lambda x, wt: ops.sq_conv2d(
-        x, wt, stride=stride, bh=plan.bh, bw=plan.bw, bk=plan.bk,
-        kc=plan.kc, bf=plan.bf, pm_layout=plan.pm_layout)
-    return _time(fn, x, wt, reps=reps)
 
 
 def prepared_rows():
@@ -252,8 +190,7 @@ def routed_conv2d_rows():
     ROADMAP conv-route-selection item.  The ``route`` field pins the
     choice so run.py --check flags a route flip.  (At B=1 this shape is
     near route-parity in wall clock -- the regime boundary encodes the
-    PR 3 tuned trajectory and the patch-blowup asymptotics; per-shape
-    measured winners can be pinned via routing.set_route_override.)"""
+    interpret-mode measurements and the patch-blowup asymptotics.)"""
     from repro.core import conv as conv_core
     from repro.kernels import routing
 
@@ -331,9 +268,9 @@ def matmul_modes(m=256, k=256, n=256):
 
 def pallas_kernels():
     """The tracked Pallas kernel timings (planner-default tile plans), plus
-    a rank-1 reference row (kc=1, "mkn" -- the seed kernels' dataflow) so
-    the chunked-vs-rank-1 speedup is measured in-process, immune to
-    machine-load drift between benchmark runs."""
+    a rank-1 reference row (kc=1: one K index per loop iteration, the seed
+    kernels' dataflow) so the planned-vs-rank-1 speedup is measured
+    in-process, immune to machine-load drift between benchmark runs."""
     from repro.kernels import ops
 
     rng = np.random.default_rng(1)
@@ -372,7 +309,7 @@ def pallas_kernels():
          "shape": "128x128x128", "mode": "f32"},
         {"name": "pallas_sq_matmul_rank1[interp]",
          "us_per_call": _time(
-             lambda a, b: ops.sq_matmul(a, b, kc=1, pm_layout="mkn"),
+             lambda a, b: ops.sq_matmul(a, b, kc=1),
              a, b, reps=reps),
          "shape": "128x128x128", "mode": "f32/rank1-ref"},
         {"name": "pallas_cpm3_matmul[interp]",
@@ -414,8 +351,8 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--sweep-sq-matmul", metavar="OUT_JSONL", required=True,
                     help="time the planner's sq_matmul picks against "
-                         "alternatives at the cells' shapes, one JSON "
-                         "line a plan")
+                         "other row-walk plans at the cells' shapes, one "
+                         "JSON line a plan")
     args = ap.parse_args()
     _require_tpu()
     dev = jax.devices()[0]

@@ -8,7 +8,7 @@ and its vector adds and multiplies.  The counts are the compiler's static
 schedule, not a timing: they rank plans, and the chip sweep
 (``kernel_timing.py --sweep-sq-matmul``) says what they are worth.
 
-    PYTHONPATH=src python benchmarks/llo_bundles.py BM BN BK KC LAYOUT [FB]
+    PYTHONPATH=src python benchmarks/llo_bundles.py BM BN BK KC [FB]
 
 prints one JSON line.  ``BK`` of two groups (2 * KC) is enough to see the
 loop body; a single-group slab has no loop, and the whole kernel's
@@ -25,7 +25,7 @@ import sys
 import tempfile
 
 
-def _compile(bm, bn, bk, kc, layout, fb):
+def _compile(bm, bn, bk, kc, fb):
     """Child process: lower and compile the kernel for a described v5e."""
     import jax
     import jax.numpy as jnp
@@ -44,15 +44,14 @@ def _compile(bm, bn, bk, kc, layout, fb):
 
     def sq_matmul_plan(a, b, sa, sb):
         return sq_matmul_batched_pallas(a, b, sa, sb, fb=fb, bm=bm, bn=bn,
-                                        bk=bk, kc=kc, pm_layout=layout,
-                                        interpret=False)
+                                        bk=bk, kc=kc, interpret=False)
 
     jax.jit(sq_matmul_plan).lower(sds((fb, bm, bk)), sds((fb, bk, bn)),
                                   sds((fb, bm, 1)),
                                   sds((fb, 1, bn))).compile()
 
 
-def bundle_stats(bm, bn, bk, kc, layout, fb=1):
+def bundle_stats(bm, bn, bk, kc, fb=1):
     with tempfile.TemporaryDirectory() as d:
         env = dict(os.environ, LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={d}",
                    JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled")
@@ -60,7 +59,7 @@ def bundle_stats(bm, bn, bk, kc, layout, fb=1):
         # its exit code is not read: the dump is
         p = subprocess.run(
             [sys.executable, __file__, "--compile", str(bm), str(bn),
-             str(bk), str(kc), layout, str(fb)],
+             str(bk), str(kc), str(fb)],
             env=env, capture_output=True, text=True, timeout=900)
         files = [f for f in glob.glob(os.path.join(d, "*-final_bundles.txt"))
                  if re.search(r"-sq_matmul_plan\.\d+-\d+-final_bundles", f)]
@@ -75,7 +74,7 @@ def bundle_stats(bm, bn, bk, kc, layout, fb=1):
         return sum(len(re.findall(pattern, ln)) for ln in loop)
 
     pm_vregs = kc * fb * -(-bm // 8) * -(-bn // 128)
-    out = {"plan": [bm, bn, bk, kc], "pm_layout": layout, "fb": fb,
+    out = {"plan": [bm, bn, bk, kc], "fb": fb,
            "bundles": len(bundles), "loop_bundles": len(loop),
            "spill_st": count(r"vst \[vmem:\[#allocation\d+_spill"),
            "spill_ld": count(r"vld \[vmem:\[#allocation\d+_spill"),
@@ -92,9 +91,7 @@ def bundle_stats(bm, bn, bk, kc, layout, fb=1):
 if __name__ == "__main__":
     if sys.argv[1] == "--compile":
         a = sys.argv[2:]
-        _compile(int(a[0]), int(a[1]), int(a[2]), int(a[3]), a[4], int(a[5]))
+        _compile(*(int(x) for x in a[:5]))
     else:
         a = sys.argv[1:]
-        print(json.dumps(bundle_stats(int(a[0]), int(a[1]), int(a[2]),
-                                      int(a[3]), a[4],
-                                      int(a[5]) if len(a) > 5 else 1)))
+        print(json.dumps(bundle_stats(*(int(x) for x in a[:5]))))

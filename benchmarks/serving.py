@@ -199,7 +199,7 @@ def serving_rows(reps: int = 2) -> List[Dict]:
         == c["engine_requests_submitted_total"])
 
     # one warmup per remaining config: the first run of each pays one-time
-    # costs (plan-cache fills, tuning-cache consults, allocator warmup)
+    # costs (plan-cache fills, allocator warmup)
     # that would otherwise bias whichever config runs first
     _run_once(model_sq, params, prepared=True)
     _run_once(model_sq, params, prepared=True, guard=True)

@@ -10,8 +10,7 @@ One small-but-real LM runs N fixed-seed AdamW steps under three modes:
                         dL/dW as ``<site>.bwd_x`` / ``<site>.bwd_w``
                         (the gated pair);
 - ``square_pallas``  -- the Pallas kernel route (informational on this
-                        interpret host; exercises the training-shaped
-                        tuning-cache entries so the row runs warning-free).
+                        interpret host).
 
 A fourth row, ``train_step_square_guarded[jit]``, runs the same
 square-virtual step through :class:`repro.train.step.GuardedStep` -- the

@@ -30,7 +30,7 @@ __all__ = ["ArithCost", "mac_cost", "pm_mac_cost", "complex_mac_cost",
            "tensor_core_cost", "savings_table",
            "TileCost", "vmem_tile_elems", "pm_tile_vmem_bytes",
            "pm_tile_vpu_ops", "pm_grid_cost", "pm_walk_grid_cost",
-           "pm_plan_cost", "kmn_acc_vregs", "KMN_ACC_VREGS",
+           "kmn_acc_vregs", "KMN_ACC_VREGS",
            "conv2d_window_elems", "conv2d_patch_bytes",
            "conv2d_grid_cost", "paged_attn_gather_bytes"]
 
@@ -269,18 +269,6 @@ def pm_walk_grid_cost(m: int, n: int, k: int, bm: int, bn: int, bk: int,
             ) * itemsize
     return TileCost(vmem_bytes=vmem, vpu_ops=3.0 * terms + bcast + fold,
                     grid_steps=grid, chunk_steps=groups)
-
-
-def pm_plan_cost(pm_layout: str, m: int, n: int, k: int, bm: int, bn: int,
-                 bk: int, kc: int, itemsize: int = 4, n_row_ops: int = 1,
-                 n_col_ops: int = 1, n_acc: int = 1) -> TileCost:
-    """The cost of a matmul plan under its layout: :func:`pm_walk_grid_cost`
-    for "kmn" (one row and one column operand), :func:`pm_grid_cost` for
-    the chunked layouts."""
-    if pm_layout == "kmn":
-        return pm_walk_grid_cost(m, n, k, bm, bn, bk, kc, itemsize)
-    return pm_grid_cost(m, n, k, bm, bn, bk, kc, itemsize, n_row_ops,
-                        n_col_ops, n_acc)
 
 
 def conv2d_window_elems(bh: int, bw: int, kh: int, kw: int, bk: int,

@@ -112,8 +112,7 @@ def pm_matmul_scan(a, b, block: int = 16):
     stream in: each scan step contracts a ``block``-wide K slab in ONE
     broadcast squaring pass over the (..., M, N, block) cube, reduced on
     the *minor* axis (``b`` transposed once, outside the scan) -- the
-    dot-product-shaped loop nest XLA CPU vectorizes best, same layout
-    finding as the "mnk" Pallas kernels.  ``block`` trades the live
+    dot-product-shaped loop nest XLA CPU vectorizes best.  ``block`` trades the live
     cube's footprint against scan-step count; ~16 keeps it inside the
     cache working set at model-sized (256^3) shapes (measured ~19x over
     the old full-K-slab (M, 128, N) layout: 41 ms -> 2.2 ms).

@@ -27,8 +27,8 @@ the raw weight array:
   amortization.
 
 Plan resolution (which needs only shapes/dtypes) is memoized on the
-operand's cache key ``(kind, shape, dtype, layout, site)``: under jit the
-whole prepare is traced once per cache entry; under eager/interpret
+operand's cache key ``(kind, shape, dtype, transposed, site)``: under
+jit the whole prepare is traced once per cache entry; under eager/interpret
 execution reusing one PreparedOperand across calls skips the O(K*N)
 widen/correct/pad work entirely -- the measurable amortization
 benchmarked in ``benchmarks/run.py`` (prepared-vs-raw rows).
@@ -48,16 +48,17 @@ __all__ = ["PreparedOperand", "prepare_operand", "unwrap", "is_prepared",
 
 # Default row-extent hint used to resolve the prepare-time tile plan when
 # the activation extent is unknown.  Execution re-plans for the ACTUAL M
-# (identically to raw dispatch -- that is what makes prepared and raw
-# bit-identical); when the prepared (bk, bn) padding multiples match that
-# plan's, the canon/corr arrays are reused as-is, otherwise the zero
-# padding is re-laid (a copy, but never the O(K*N) widen/correct work --
-# see kernels.ops._match_rhs_padding).  Pass the real M as ``m_hint`` to
-# make the match exact.
+# through the same planner (identically to raw dispatch -- that is what
+# makes prepared and raw bit-identical); when the prepared (bk, bn)
+# padding multiples match that plan's, the canon/corr arrays are reused
+# as-is, otherwise the zero padding is re-laid (a copy, but never the
+# O(K*N) widen/correct work -- see kernels.ops._match_rhs_padding).  The
+# row walk's planned tiles pad K and N to the lane granule at every M, so
+# only explicit execute-time tiles re-lay.
 DEFAULT_M_HINT = 128
 
 # Prepare-time plan memo, keyed by the operand cache key.  Keeps repeated
-# eager prepares (and re-traces) from re-consulting the tuning cache.
+# eager prepares (and re-traces) from re-running the planner.
 _PLAN_CACHE: dict = {}
 
 
@@ -81,7 +82,7 @@ class PreparedOperand:
     plan: Any                         # prepare-time TilePlan (matmul kinds)
     transposed: bool                  # canon built from source.T
     site: Optional[str]
-    key: Tuple                        # (kind, shape, dtype, layout, site)
+    key: Tuple                  # (kind, shape, dtype, transposed, site)
 
     # -- array-protocol conveniences (shape checks in the dispatchers) --
     @property
@@ -115,13 +116,13 @@ def unwrap(x):
     return x.source if isinstance(x, PreparedOperand) else x
 
 
-def _matmul_key(kind: str, shape, dtype, layout: str,
+def _matmul_key(kind: str, shape, dtype, transposed: bool,
                 site: Optional[str]) -> Tuple:
-    return (kind, tuple(shape), jnp.dtype(dtype).name, layout, site)
+    return (kind, tuple(shape), jnp.dtype(dtype).name, transposed, site)
 
 
 def _prepare_matmul(w, *, transpose: bool, m_hint: Optional[int],
-                    site: Optional[str], pm_layout: str,
+                    site: Optional[str],
                     prepare_grads: bool = False) -> PreparedOperand:
     from repro.kernels import ops as kops    # lazy: avoid import cycle
     from repro.kernels import tuning
@@ -129,14 +130,14 @@ def _prepare_matmul(w, *, transpose: bool, m_hint: Optional[int],
     batched = w.ndim == 3
     mat = jnp.swapaxes(w, -1, -2) if transpose else w
     k, n = mat.shape[-2], mat.shape[-1]
-    batch = mat.shape[0] if batched else 1
     acc = sq.accum_dtype(w.dtype)
     kind = "matmul_batched" if batched else "matmul"
-    key = _matmul_key(kind, w.shape, w.dtype, pm_layout, site)
+    key = _matmul_key(kind, w.shape, w.dtype, transpose, site)
     plan = _PLAN_CACHE.get((key, m_hint))
     if plan is None:
-        plan = tuning.plan_matmul(m_hint or DEFAULT_M_HINT, n, k, acc,
-                                  pm_layout=pm_layout, batch=batch)
+        # the planner kernels.ops.sq_matmul runs, so prepare and execute
+        # pad alike
+        plan = tuning.plan_matmul(m_hint or DEFAULT_M_HINT, n, k, acc)
         _PLAN_CACHE[(key, m_hint)] = plan
     canon, corr = kops.prepare_matmul_rhs(mat, plan, acc)
     # dL/dx consumes the weight with the contraction/output axes swapped,
@@ -147,7 +148,7 @@ def _prepare_matmul(w, *, transpose: bool, m_hint: Optional[int],
     if prepare_grads and not batched:
         gsite = f"{site}.bwd_x" if site else None
         gradp = _prepare_matmul(w, transpose=not transpose, m_hint=m_hint,
-                                site=gsite, pm_layout=pm_layout)
+                                site=gsite)
     return PreparedOperand(w, canon, corr, None, gradp, kind, plan, transpose,
                            site, key)
 
@@ -166,14 +167,13 @@ def _prepare_conv2d(w, *, site: Optional[str]) -> PreparedOperand:
         raise ValueError(f"conv2d filters must be rank 2-4, got {w.shape}")
     acc = sq.accum_dtype(w.dtype)
     wt, sw, wmat, cmat = kops.prepare_conv2d_weights(w4, acc)
-    key = _matmul_key("conv2d", w.shape, w.dtype, "-", site)
+    key = _matmul_key("conv2d", w.shape, w.dtype, False, site)
     return PreparedOperand(w, wt, sw, (wmat, cmat), None, "conv2d", None,
                            False, site, key)
 
 
 def prepare_operand(w, *, for_: str = "matmul", transpose: bool = False,
                     m_hint: Optional[int] = None, site: Optional[str] = None,
-                    interpret: Optional[bool] = None,
                     prepare_grads: bool = False) -> "PreparedOperand":
     """Precompute the constant-operand half of the kernel prep pipeline.
 
@@ -187,13 +187,13 @@ def prepare_operand(w, *, for_: str = "matmul", transpose: bool = False,
     materialized once, at prepare time.
 
     ``m_hint``: expected activation row extent -- resolves the
-    prepare-time tile plan.  Execution always re-plans for the actual M
-    (identically to raw dispatch, preserving bit-identity) and reuses the
-    prepared padding when the (bk, bn) multiples agree; on a mismatch the
-    zero padding is re-laid per call (a copy -- the O(K*N) widen/correct
-    work is still skipped), so pass the real M to make the reuse
-    zero-copy.  ``interpret`` picks the PM-block layout the plan is
-    resolved for (default: the current backend, like kernels.ops).
+    prepare-time tile plan with the planner ``kernels.ops.sq_matmul``
+    uses.  Execution always re-plans for the actual M (identically to
+    raw dispatch, preserving bit-identity) and reuses the prepared
+    padding when the (bk, bn) multiples agree, as they do for every
+    planned tile; on a mismatch (explicit execute-time tiles) the zero
+    padding is re-laid per call (a copy -- the O(K*N) widen/correct work
+    is still skipped).
 
     ``prepare_grads`` (2D matmul only): also prepare the *opposite-layout*
     form of the same source under ``<site>.bwd_x`` and carry it on the
@@ -227,8 +227,5 @@ def prepare_operand(w, *, for_: str = "matmul", transpose: bool = False,
     if w.ndim not in (2, 3):
         raise ValueError(f"matmul prepare needs a 2D (K, N) or 3D (B, K, N) "
                          f"operand, got {w.shape}")
-    from repro.kernels import ops as kops
-    interp = kops.default_interpret() if interpret is None else interpret
-    layout = "mnk" if interp else "mkn"
     return _prepare_matmul(w, transpose=transpose, m_hint=m_hint, site=site,
-                           pm_layout=layout, prepare_grads=prepare_grads)
+                           prepare_grads=prepare_grads)

@@ -2,11 +2,12 @@
 
 Layout:
 - ``sq_matmul`` / ``cpm3_matmul`` / ``cpm4_matmul`` / ``sq_conv`` /
-  ``sq_conv2d``: raw kernels (chunked block-PM accumulation, VMEM scratch
-  accumulators; ``sq_conv2d`` streams 2D windows without im2col);
+  ``sq_conv2d``: raw kernels (VMEM scratch accumulators; ``sq_matmul``
+  walks K one row at a time, the others reduce chunked PM blocks -- see
+  ``pm_blocks``; ``sq_conv2d`` streams 2D windows without im2col);
 - ``ops``: jit'd public wrappers (widening, padding, corrections, planner);
-- ``tuning``: the (bm, bn, bk, kc) / (bh, bw, bk, kc, bf) tile planners +
-  autotune cache;
+- ``tuning``: each kernel's PM schedule and the cost-model
+  (bm, bn, bk, kc) / (bh, bw, bk, kc, bf) tile planners;
 - ``ref``: pure-jnp oracles for the test sweeps.
 """
 from repro.kernels.ops import (sq_matmul, cpm3_matmul, cpm4_matmul, sq_conv,

@@ -12,10 +12,9 @@ Per (h, i, k) the three squares are:
     re    += shared - (b + c + s)^2   (paper eq 32)
     im    += shared + (a + s - c)^2   (paper eq 34)
 
-The contraction is chunked exactly like kernels.sq_matmul: each grid step
-processes its K slab in ``kc``-wide rank-2 broadcast chunks (PM blocks of
-shape (bm, kc, bn) for the "mkn" layout or (bm, bn, kc) for the
-minor-axis-reduce "mnk" layout -- see sq_matmul.py for the trade-off).
+Each grid step processes its K slab in ``kc``-wide rank-2 broadcast
+chunks, PM blocks of shape (bm, kc, bn) reduced over the chunk axis
+(:func:`repro.kernels.pm_blocks.pm_chunked_reduce`).
 
 The shared subexpressions of the three squares are HOISTED out of the
 PM terms: the combined planes ``a+b`` (rows), ``c+s`` and ``s-c``
@@ -45,7 +44,7 @@ from repro.kernels.pm_blocks import pm_chunked_reduce
 __all__ = ["cpm3_matmul_kernel", "cpm3_matmul_pallas"]
 
 
-def _cpm3_body(rs, cs, axis, carry):
+def _cpm3_body(rs, cs, carry):
     """One chunk's three squares (paper eqs 32/34) on pre-broadcast slabs.
 
     Row slabs are (a+b, b, a); column slabs (c, c+s, s-c) -- the pairwise
@@ -58,8 +57,8 @@ def _cpm3_body(rs, cs, axis, carry):
     shared = t * t                      # the square shared by Re and Im
     u = b_s + cs_s                      # (b + c + s)
     v = a_s + sc_s                      # (a + s - c)
-    re = re + jnp.sum(shared - u * u, axis)
-    im = im + jnp.sum(shared + v * v, axis)
+    re = re + jnp.sum(shared - u * u, -2)
+    im = im + jnp.sum(shared + v * v, -2)
     return re, im
 
 
@@ -72,8 +71,7 @@ def _cpm3_cols(c, s):
 
 
 def cpm3_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sre_ref, sim_ref,
-                       re_ref, im_ref, re_acc, im_acc, *, nk: int, kc: int,
-                       pm_layout: str):
+                       re_ref, im_ref, re_acc, im_acc, *, nk: int, kc: int):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
@@ -83,7 +81,7 @@ def cpm3_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sre_ref, sim_ref,
 
     re, im = pm_chunked_reduce(
         (re_acc[...], im_acc[...]), (a_ref, b_ref), (c_ref, s_ref),
-        kc=kc, pm_layout=pm_layout, body=_cpm3_body, rows=_cpm3_rows,
+        kc=kc, body=_cpm3_body, rows=_cpm3_rows,
         cols=_cpm3_cols)
     re_acc[...] = re
     im_acc[...] = im
@@ -96,7 +94,7 @@ def cpm3_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sre_ref, sim_ref,
 
 def cpm3_matmul_pallas(a, b, c, s, sre, sim, scs, ssc, *, bm: int = 256,
                        bn: int = 256, bk: int = 128, kc: int | None = None,
-                       pm_layout: str = "mkn", interpret: bool = False):
+                       interpret: bool = False):
     """Raw pallas_call wrapper.
 
     sre: (m, 1) row corrections Sab_h; sim: (m, 1) Sba_h;
@@ -114,8 +112,7 @@ def cpm3_matmul_pallas(a, b, c, s, sre, sim, scs, ssc, *, bm: int = 256,
     assert bk % kc == 0, (bk, kc)
     nk = k // bk
 
-    kernel = functools.partial(cpm3_matmul_kernel, nk=nk, kc=kc,
-                               pm_layout=pm_layout)
+    kernel = functools.partial(cpm3_matmul_kernel, nk=nk, kc=kc)
     re, im = pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn, nk),

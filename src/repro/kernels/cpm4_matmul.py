@@ -5,8 +5,8 @@ stream through; real/imag accumulators live in dedicated VMEM scratch
 buffers across the K walk (out refs written once, at the final K step) and
 are initialized with the shared corrections ``Sx_h + Sy_k`` (eq 18) -- note
 CPM4's real and imaginary parts share ONE correction pair, unlike CPM3's
-four distinct terms.  Grid semantics and K-slab chunking (``kc``,
-``pm_layout``) are exactly as in kernels.sq_matmul.
+four distinct terms.  Grid semantics and the chunked PM blocks (``kc``)
+are exactly as in kernels.cpm3_matmul.
 
 Per (h, i, k):
     re += (a + c)^2 + (b - s)^2        (eq 21)
@@ -35,7 +35,7 @@ from repro.kernels.pm_blocks import pm_chunked_reduce
 __all__ = ["cpm4_matmul_kernel", "cpm4_matmul_pallas"]
 
 
-def _cpm4_body(rs, cs, axis, carry):
+def _cpm4_body(rs, cs, carry):
     """One chunk's four squares (paper eqs 21/22) on pre-broadcast slabs.
 
     Column slabs are (c, s, -s) with the negation hoisted to rank 2 (see
@@ -47,8 +47,8 @@ def _cpm4_body(rs, cs, axis, carry):
     t2 = b_s + ns_s                     # (b - s) via the hoisted -s plane
     t3 = b_s + c_s
     t4 = a_s + s_s
-    re = re + jnp.sum(t1 * t1 + t2 * t2, axis)
-    im = im + jnp.sum(t3 * t3 + t4 * t4, axis)
+    re = re + jnp.sum(t1 * t1 + t2 * t2, -2)
+    im = im + jnp.sum(t3 * t3 + t4 * t4, -2)
     return re, im
 
 
@@ -57,7 +57,7 @@ def _cpm4_cols(c, s):
 
 
 def cpm4_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sx_ref, re_ref, im_ref,
-                       re_acc, im_acc, *, nk: int, kc: int, pm_layout: str):
+                       re_acc, im_acc, *, nk: int, kc: int):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
@@ -69,7 +69,7 @@ def cpm4_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sx_ref, re_ref, im_ref,
 
     re, im = pm_chunked_reduce(
         (re_acc[...], im_acc[...]), (a_ref, b_ref), (c_ref, s_ref),
-        kc=kc, pm_layout=pm_layout, body=_cpm4_body, cols=_cpm4_cols)
+        kc=kc, body=_cpm4_body, cols=_cpm4_cols)
     re_acc[...] = re
     im_acc[...] = im
 
@@ -81,7 +81,7 @@ def cpm4_matmul_kernel(a_ref, b_ref, c_ref, s_ref, sx_ref, re_ref, im_ref,
 
 def cpm4_matmul_pallas(a, b, c, s, sx, sy, *, bm: int = 256, bn: int = 256,
                        bk: int = 128, kc: int | None = None,
-                       pm_layout: str = "mkn", interpret: bool = False):
+                       interpret: bool = False):
     """sx: (m, 1) row corrections; sy: (1, n) column corrections (eq 18),
     added post-kernel (linearity; see cpm3_matmul.py for the Fig.2 note)."""
     m, k = a.shape
@@ -90,8 +90,7 @@ def cpm4_matmul_pallas(a, b, c, s, sx, sy, *, bm: int = 256, bn: int = 256,
     kc = bk if kc is None else kc
     assert bk % kc == 0, (bk, kc)
     nk = k // bk
-    kernel = functools.partial(cpm4_matmul_kernel, nk=nk, kc=kc,
-                               pm_layout=pm_layout)
+    kernel = functools.partial(cpm4_matmul_kernel, nk=nk, kc=kc)
     re, im = pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn, nk),

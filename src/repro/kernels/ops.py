@@ -2,7 +2,7 @@
 
 Handles: dtype widening (paper's bit-growth rules), padding to tile
 multiples, correction-term precomputation, tile planning (via
-kernels.tuning -- cost-model ranked, autotune-cache aware), and the
+kernels.tuning -- cost-model ranked), and the
 interpret-mode fallback on CPU (kernels target TPU; interpret=True executes
 the kernel body in Python for bit-faithful validation).
 
@@ -16,10 +16,9 @@ prepare-then-execute per call; passing a
 :func:`repro.core.prepared.prepare_operand`) reuses the prepared half, so
 both entry styles share one code path and are bit-identical by
 construction.  Corrections are computed BEFORE padding (padded zeros
-contribute zero anyway).  The PM layout is resolved here and baked into
-the plan (see kernels.sq_matmul): "mnk" on interpret/CPU; on the TPU,
-the "kmn" row walk for ``sq_matmul`` and "mkn" PM blocks for every other
-kernel.
+contribute zero anyway).  Each kernel runs its one PM schedule on every
+backend (:func:`repro.kernels.tuning.pm_schedule`): interpret mode runs
+the schedule the chip runs.
 """
 from __future__ import annotations
 
@@ -77,17 +76,6 @@ def _pad_operands(plan, row_ops, col_ops, row_corrs, col_corrs):
     row_corrs = [_pad_to(t, plan.bm, 0) for t in row_corrs]
     col_corrs = [_pad_to(t, plan.bn, 1) for t in col_corrs]
     return row_ops, col_ops, row_corrs, col_corrs
-
-
-def _resolve_plan(m, n, k, dtype, *, bm, bn, bk, kc, pm_layout, interpret,
-                  kind, n_row_ops=1, n_col_ops=1, n_acc=1, batch=1):
-    """Backend-aware plan resolution (see module docstring)."""
-    layout = pm_layout or ("mnk" if interpret else
-                           "kmn" if kind == "sq_matmul" else "mkn")
-    return tuning.plan_matmul(
-        m, n, k, sq.accum_dtype(dtype), bm=bm, bn=bn, bk=bk, kc=kc,
-        pm_layout=layout, kind=kind, n_row_ops=n_row_ops,
-        n_col_ops=n_col_ops, n_acc=n_acc, batch=batch)
 
 
 # --------------------------------------------------------------------------
@@ -174,8 +162,7 @@ def _sq_matmul_exec(a, bw, sb, n, plan, interpret):
     aw = _pad_to(_pad_to(aw, plan.bm, 0), plan.bk, 1)
     sa = _pad_to(sa, plan.bm, 0)
     out = sq_matmul_pallas(aw, bw, sa, sb, bm=plan.bm, bn=plan.bn,
-                           bk=plan.bk, kc=plan.kc, pm_layout=plan.pm_layout,
-                           interpret=interpret)
+                           bk=plan.bk, kc=plan.kc, interpret=interpret)
     return out[:m, :n]
 
 
@@ -198,7 +185,6 @@ def _sq_matmul_batched_exec(a, bw, sb, n, fb, plan, interpret):
         aw, bw, sa, sb = (_pad_to(t, fb, 0) for t in (aw, bw, sa, sb))
     out = sq_matmul_batched_pallas(aw, bw, sa, sb, bm=plan.bm, bn=plan.bn,
                                    bk=plan.bk, kc=plan.kc, fb=fb,
-                                   pm_layout=plan.pm_layout,
                                    interpret=interpret)
     return out[:nb, :m, :n]
 
@@ -213,18 +199,16 @@ def _sq_matmul_batched_impl(a, b, fb, plan, interpret):
 def _pick_fb(plan, nb: int) -> int:
     """Batch-fold width: enough elements per grid step that the folded row
     tile reaches ~FOLD_ROW_TARGET rows (the small-(M, N) large-B regime;
-    see kernels.routing); a "kmn" fold holds no more accumulator vregs
-    than one tile may."""
-    fb = FOLD_ROW_TARGET // max(1, plan.bm)
-    if plan.pm_layout == "kmn":
-        fb = min(fb, cm.KMN_ACC_VREGS // cm.kmn_acc_vregs(plan.bm, plan.bn))
+    see kernels.routing), holding no more accumulator vregs than one
+    row-walk tile may."""
+    fb = min(FOLD_ROW_TARGET // max(1, plan.bm),
+             cm.KMN_ACC_VREGS // cm.kmn_acc_vregs(plan.bm, plan.bn))
     return max(1, min(nb, fb))
 
 
 def sq_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
               bk: int | None = None, kc: int | None = None,
-              pm_layout: str | None = None, interpret: bool | None = None,
-              fold: bool = False):
+              interpret: bool | None = None, fold: bool = False):
     """Square-based matmul via the Pallas systolic-emulation kernel.
 
     a: (m, k), b: (k, n); any float or int8/int16 dtype; returns the
@@ -274,9 +258,8 @@ def sq_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
                              f"{tuple(b_shape)}")
         nb, m, k = a.shape
         n = b_shape[2]
-        plan = _resolve_plan(m, n, k, a.dtype, bm=bm, bn=bn, bk=bk, kc=kc,
-                             pm_layout=pm_layout, interpret=interpret_r,
-                             kind="sq_matmul", batch=nb)
+        plan = tuning.plan_matmul(m, n, k, sq.accum_dtype(a.dtype), bm=bm,
+                                  bn=bn, bk=bk, kc=kc)
         fb = _pick_fb(plan, nb) if fold else 1
         if prep is not None:
             matched = _match_rhs_padding(prep, plan, sq.accum_dtype(a.dtype))
@@ -293,13 +276,12 @@ def sq_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
         # collapse leading batch dims to rows (dense-layer convention)
         lead = a.shape[:-1]
         out = sq_matmul(a.reshape(-1, a.shape[-1]), b, bm=bm, bn=bn, bk=bk,
-                        kc=kc, pm_layout=pm_layout, interpret=interpret)
+                        kc=kc, interpret=interpret)
         return out.reshape(*lead, b_shape[-1])
     m, k = a.shape
     n = b_shape[1]
-    plan = _resolve_plan(m, n, k, a.dtype, bm=bm, bn=bn, bk=bk, kc=kc,
-                         pm_layout=pm_layout, interpret=interpret_r,
-                         kind="sq_matmul")
+    plan = tuning.plan_matmul(m, n, k, sq.accum_dtype(a.dtype), bm=bm, bn=bn,
+                              bk=bk, kc=kc)
     if prep is not None:
         matched = _match_rhs_padding(prep, plan, sq.accum_dtype(a.dtype))
         if matched is not None:
@@ -309,25 +291,14 @@ def sq_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
     return _sq_matmul_impl(a, b, plan, interpret_r)
 
 
-def _lane_divisor(b: int, extent: int) -> int:
-    """The largest whole-lane tile <= ``b`` that divides ``extent``, else
-    the whole extent (a block that spans its dim is always legal)."""
-    if extent % b == 0:
-        return b
-    for c in range(b - b % tuning.LANE, 0, -tuning.LANE):
-        if extent % c == 0:
-            return c
-    return extent
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "plan", "interpret"))
-def _sq_grouped_exec(x, w, tile_expert, n_used, bm, plan, interpret):
+@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
+def _sq_grouped_exec(x, w, tile_expert, n_used, plan, interpret):
     xw = x.astype(jnp.float32)
     sa = sq.row_correction(xw, axis=-1)[:, None]                 # (R, 1)
     sb = sq.col_correction(w.astype(jnp.float32), axis=-2)[:, None, :]
     return sq_grouped_matmul_pallas(
-        xw, w, sa, sb, tile_expert, n_used, bm=bm, bn=plan.bn, bk=plan.bk,
-        kc=plan.kc, pm_layout=plan.pm_layout, interpret=interpret)
+        xw, w, sa, sb, tile_expert, n_used, bm=plan.bm, bn=plan.bn,
+        bk=plan.bk, kc=plan.kc, interpret=interpret)
 
 
 def sq_grouped_matmul(x, w, tile_expert, n_used, *, bm: int,
@@ -339,9 +310,9 @@ def sq_grouped_matmul(x, w, tile_expert, n_used, *, bm: int,
     (:func:`repro.kernels.sq_grouped_matmul.group_rows`); w: (E, K, N)
     float weights, read in their stored dtype; tile_expert: (R // bm,)
     int32; n_used: (1,) int32 tiles that hold rows.  Returns (R, N) f32;
-    rows of tiles past ``n_used`` are undefined.  Column and K tiles come
-    from the matmul planner at ``m = bm``, moved to divisors of N and K
-    so the weights are never padded.
+    rows of tiles past ``n_used`` are undefined.  Tiles come from
+    :func:`repro.kernels.tuning.plan_grouped_matmul`: the weights are
+    never padded.
     """
     interpret = default_interpret() if interpret is None else interpret
     if not jnp.issubdtype(w.dtype, jnp.floating):
@@ -350,14 +321,8 @@ def sq_grouped_matmul(x, w, tile_expert, n_used, *, bm: int,
     N = w.shape[-1]
     if R % bm:
         raise ValueError(f"{R} rows are not whole {bm}-row tiles")
-    base = _resolve_plan(bm, N, K, jnp.float32, bm=None, bn=None, bk=None,
-                         kc=None, pm_layout=None, interpret=interpret,
-                         kind="sq_grouped_matmul")
-    bn, bk = _lane_divisor(base.bn, N), _lane_divisor(base.bk, K)
-    kc = bk if base.pm_layout == "mkn" and base.kc == base.bk \
-        else tuning._align_kc(base.kc, bk)
-    plan = tuning.TilePlan(bm, bn, bk, kc, base.pm_layout)
-    return _sq_grouped_exec(x, w, tile_expert, n_used, bm, plan, interpret)
+    plan = tuning.plan_grouped_matmul(bm, N, K)
+    return _sq_grouped_exec(x, w, tile_expert, n_used, plan, interpret)
 
 
 # --------------------------------------------------------------------------
@@ -378,14 +343,13 @@ def _cpm3_impl(a, b, c, s, plan, interpret):
         plan, [a, b], [c, s], [sre, sim], [scs, ssc])
     re, im = cpm3_matmul_pallas(a, b, c, s, sre, sim, scs, ssc,
                                 bm=plan.bm, bn=plan.bn, bk=plan.bk,
-                                kc=plan.kc, pm_layout=plan.pm_layout,
-                                interpret=interpret)
+                                kc=plan.kc, interpret=interpret)
     return re[:m, :n], im[:m, :n]
 
 
 def cpm3_matmul(x, y, *, bm: int | None = None, bn: int | None = None,
                 bk: int | None = None, kc: int | None = None,
-                pm_layout: str | None = None, interpret: bool | None = None):
+                interpret: bool | None = None):
     """Complex matmul with 3 squares per multiply via the Pallas kernel.
 
     x: (m, k) complex, y: (k, n) complex; returns (re, im) planes.
@@ -393,10 +357,8 @@ def cpm3_matmul(x, y, *, bm: int | None = None, bn: int | None = None,
     interpret = default_interpret() if interpret is None else interpret
     m, k = x.shape
     n = y.shape[1]
-    plan = _resolve_plan(m, n, k, jnp.real(x).dtype, bm=bm, bn=bn, bk=bk,
-                         kc=kc, pm_layout=pm_layout, interpret=interpret,
-                         kind="cpm3_matmul", n_row_ops=2, n_col_ops=2,
-                         n_acc=2)
+    plan = tuning.plan_matmul(m, n, k, sq.accum_dtype(jnp.real(x).dtype),
+                              bm=bm, bn=bn, bk=bk, kc=kc, kind="cpm3_matmul")
     return _cpm3_impl(jnp.real(x), jnp.imag(x), jnp.real(y), jnp.imag(y),
                       plan, interpret)
 
@@ -412,22 +374,19 @@ def _cpm4_impl(a, b, c, s, plan, interpret):
     (a, b), (c, s), (sx,), (sy,) = _pad_operands(
         plan, [a, b], [c, s], [sx], [sy])
     re, im = cpm4_matmul_pallas(a, b, c, s, sx, sy, bm=plan.bm, bn=plan.bn,
-                                bk=plan.bk, kc=plan.kc,
-                                pm_layout=plan.pm_layout, interpret=interpret)
+                                bk=plan.bk, kc=plan.kc, interpret=interpret)
     return re[:m, :n], im[:m, :n]
 
 
 def cpm4_matmul(x, y, *, bm: int | None = None, bn: int | None = None,
                 bk: int | None = None, kc: int | None = None,
-                pm_layout: str | None = None, interpret: bool | None = None):
+                interpret: bool | None = None):
     """Complex matmul with 4 squares per multiply via the Pallas kernel."""
     interpret = default_interpret() if interpret is None else interpret
     m, k = x.shape
     n = y.shape[1]
-    plan = _resolve_plan(m, n, k, jnp.real(x).dtype, bm=bm, bn=bn, bk=bk,
-                         kc=kc, pm_layout=pm_layout, interpret=interpret,
-                         kind="cpm4_matmul", n_row_ops=2, n_col_ops=2,
-                         n_acc=2)
+    plan = tuning.plan_matmul(m, n, k, sq.accum_dtype(jnp.real(x).dtype),
+                              bm=bm, bn=bn, bk=bk, kc=kc, kind="cpm4_matmul")
     return _cpm4_impl(jnp.real(x), jnp.imag(x), jnp.real(y), jnp.imag(y),
                       plan, interpret)
 
@@ -527,8 +486,7 @@ def _sq_conv2d_fused_exec(x, wt, sw, cout, plan, stride, pads, interpret):
     sw = _pad_to(sw, plan.bf, 1)
     out = sq_conv2d_pallas(xt, wt, sw, ohp=ohp, owp=owp, bh=plan.bh,
                            bw=plan.bw, bk=plan.bk, bf=plan.bf, kc=plan.kc,
-                           stride=stride, pm_layout=plan.pm_layout,
-                           interpret=interpret)
+                           stride=stride, interpret=interpret)
     out = out[:, :oh, :ow, :cout]
     return jnp.transpose(out, (0, 3, 1, 2))      # back to (B, cout, oh, ow)
 
@@ -546,7 +504,7 @@ def _sq_conv2d_fused_impl(x, w, plan, stride, pads, interpret):
 def sq_conv2d(x, w, *, stride=1, padding="VALID", bh: int | None = None,
               bw: int | None = None, bk: int | None = None,
               kc: int | None = None, bf: int | None = None,
-              pm_layout: str | None = None, interpret: bool | None = None):
+              interpret: bool | None = None):
     """Square-based 2D correlation via the FUSED window-streaming kernel.
 
     The paper's §5.1 engine streams input windows straight through the PM
@@ -583,8 +541,7 @@ def sq_conv2d(x, w, *, stride=1, padding="VALID", bh: int | None = None,
     cout, cin, kh, kw = w4_shape
     plan = tuning.plan_conv2d(
         hp, wp, kh, kw, cin, cout, sq.accum_dtype(x4.dtype),
-        stride=strides, batch=x4.shape[0], bh=bh, bw=bw, bk=bk, kc=kc,
-        bf=bf, pm_layout=pm_layout or ("mnk" if interpret_r else "mkn"))
+        stride=strides, bh=bh, bw=bw, bk=bk, kc=kc, bf=bf)
     if prep is not None and prep.canon.dtype == sq.accum_dtype(x4.dtype):
         out = _sq_conv2d_fused_exec(x4, prep.canon, prep.corr, cout, plan,
                                     strides, pads, interpret_r)
@@ -635,11 +592,8 @@ def sq_conv2d_im2col(x, w, *, stride=1, padding="VALID",
     strides, pads, _, (oh, ow) = _conv2d_geometry(x4.shape, w4_shape,
                                                   stride, padding)
     cout, cin, kh, kw = w4_shape
-    plan = _resolve_plan(x4.shape[0] * oh * ow, cout, cin * kh * kw,
-                         x4.dtype, bm=None, bn=None, bk=None, kc=None,
-                         pm_layout=None, interpret=interpret_r,
-                         kind="sq_matmul")
     acc = sq.accum_dtype(x4.dtype)
+    plan = tuning.plan_matmul(x4.shape[0] * oh * ow, cout, cin * kh * kw, acc)
     if prep is not None and prep.im2col is not None \
             and prep.im2col[0].dtype == acc:
         wmat, cmat = prep.im2col

@@ -3,9 +3,9 @@
 Every square kernel walks a K slab and accumulates ``(a + b)^2`` terms;
 they differ only in the squares computed per step (one PM term for the
 real kernel and for attention, three/four for CPM3/CPM4).  This module
-owns the part they share -- slicing, broadcast shaping, the layout
-dispatch, and the homogeneous ``fori_loop`` -- so the layout logic exists
-exactly once.
+owns the part they share -- slicing, broadcast shaping and the
+homogeneous ``fori_loop`` of both PM schedules -- so each exists exactly
+once.
 
 Slabs are sliced from the VMEM *refs* (``ref[..., pl.ds(c * kc, kc)]``),
 never by dynamically slicing loaded values: Mosaic has no lowering for
@@ -17,27 +17,22 @@ only proposes such plans.  The walk is a ``fori_loop`` and not a Python
 loop: Mosaic gives every statically unrolled chunk its own VMEM stack
 allocation, so an unrolled walk runs out of VMEM.
 
-Three PM layouts (see kernels.sq_matmul for the performance story).  The
-two *chunked* layouts (:data:`CHUNKED_LAYOUTS`, :func:`pm_chunked_reduce`)
-form a rank-3 PM block per ``kc``-wide chunk:
+Two PM schedules, one per kernel; :mod:`repro.kernels.tuning` names
+which kernel runs which (:func:`repro.kernels.tuning.pm_schedule`), and
+plans its tiles for it.
 
-``"mkn"``
-    Slabs broadcast to (bm, kc, 1) x (1, kc, bn); ``body`` reduces the
-    second-minor axis.  bn stays on the 128-lane minor axis.  The layout
-    of every TPU kernel but ``sq_matmul``: the paged-attention and
-    grouped-expert kernels keep it, since their tiles were tuned and
-    timed on it and the row walk below never was.
-``"mnk"``
-    Column chunks are transposed; slabs broadcast to (bm, 1, kc) x
-    (1, bn, kc); ``body`` reduces the minor axis, which fuses into a
-    dot-product-shaped loop nest -- the CPU/interpret schedule.
+Chunked PM blocks (:func:`pm_chunked_reduce`, :func:`pm_block_accum`)
+    Every kernel but ``sq_matmul``: CPM3/CPM4, the fused conv2d, the
+    grouped-expert and the paged-attention kernels.  One ``kc``-wide
+    chunk broadcasts its slabs to (bm, kc, 1) x (1, kc, bn), forms the
+    rank-3 PM block and reduces its second-minor axis; bn stays on the
+    128-lane minor axis.  A block holds ``bm * kc * bn`` words, far
+    more than the 64-vreg (256 KiB) register file at any useful ``kc``,
+    so on the TPU Mosaic spills each block-wide value -- the
+    lane-broadcast row operand, the sums, the squares -- to VMEM and
+    reduces across sublanes.
 
-A chunk's block holds ``bm * kc * bn`` words, far more than the 64-vreg
-(256 KiB) register file at any useful ``kc``, so on the TPU Mosaic spills
-each block-wide value -- the lane-broadcast row operand, the sums, the
-squares -- to VMEM and reduces across sublanes.
-
-``"kmn"`` (:func:`pm_row_walk`, ``sq_matmul`` only)
+The row walk (:func:`pm_row_walk`, ``sq_matmul`` only)
     No block at all: the (bm, bn) partial sum lives in vregs while K is
     walked one index at a time, ``part += (a[:, k] + b[k, :])^2`` with
     ``a``'s column broadcast over lanes and ``b``'s row over sublanes.
@@ -56,17 +51,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["PM_LAYOUTS", "CHUNKED_LAYOUTS", "KMN_GROUP",
-           "pm_chunked_reduce", "pm_row_walk"]
+__all__ = ["KMN_GROUP", "pm_chunked_reduce", "pm_block_accum",
+           "pm_row_walk"]
 
-CHUNKED_LAYOUTS = ("mkn", "mnk")
-PM_LAYOUTS = CHUNKED_LAYOUTS + ("kmn",)
-# K indices one "kmn" loop iteration unrolls: one lane group of ``a``.
+# K indices one row-walk loop iteration unrolls: one lane group of ``a``.
 KMN_GROUP = 128
 
 
-def pm_chunked_reduce(carry, row_refs, col_refs, *, kc: int, pm_layout: str,
-                      body, rows=None, cols=None):
+def pm_chunked_reduce(carry, row_refs, col_refs, *, kc: int, body,
+                      rows=None, cols=None):
     """Run ``body`` over every kc-wide chunk of the K slab.
 
     row_refs: tuple of (..., bm, bk) refs; col_refs: tuple of (..., bk, bn)
@@ -74,13 +67,10 @@ def pm_chunked_reduce(carry, row_refs, col_refs, *, kc: int, pm_layout: str,
     ``rows``/``cols`` optionally map a chunk's loaded (..., bm, kc) /
     (..., kc, bn) slabs to the tuple ``body`` squares (pairwise sums
     formed once per chunk at rank 2, not once per PM term).
-    ``body(row_slabs, col_slabs, axis, carry) -> carry`` receives the
-    chunk's slabs pre-broadcast (layouts above) and the reduction axis;
-    it computes the squares and accumulates.
+    ``body(row_slabs, col_slabs, carry) -> carry`` receives the chunk's
+    slabs broadcast to (..., bm, kc, 1) and (..., 1, kc, bn); it
+    computes the squares and accumulates them reduced over axis -2.
     """
-    if pm_layout not in CHUNKED_LAYOUTS:
-        raise ValueError(f"unknown chunked pm_layout {pm_layout!r}; "
-                         f"expected one of {CHUNKED_LAYOUTS}")
     nc = row_refs[0].shape[-1] // kc
 
     def chunk(c, carry):
@@ -93,22 +83,32 @@ def pm_chunked_reduce(carry, row_refs, col_refs, *, kc: int, pm_layout: str,
             cs = tuple(co[..., pl.ds(start, kc), :] for co in col_refs)
         rs = rows(*rs) if rows is not None else rs
         cs = cols(*cs) if cols is not None else cs
-        if pm_layout == "mkn":
-            rs = tuple(r[..., :, :, None] for r in rs)       # (bm, kc, 1)
-            cs = tuple(co[..., None, :, :] for co in cs)     # (1, kc, bn)
-            return body(rs, cs, -2, carry)
-        rs = tuple(r[..., :, None, :] for r in rs)           # (bm, 1, kc)
-        cs = tuple(jnp.swapaxes(co, -1, -2)[..., None, :, :]
-                   for co in cs)                             # (1, bn, kc)
-        return body(rs, cs, -1, carry)
+        rs = tuple(r[..., :, :, None] for r in rs)           # (bm, kc, 1)
+        cs = tuple(co[..., None, :, :] for co in cs)         # (1, kc, bn)
+        return body(rs, cs, carry)
 
     if nc == 1:
         return chunk(0, carry)
     return jax.lax.fori_loop(0, nc, chunk, carry)
 
 
+def pm_block_accum(acc, a_ref, b_ref, *, kc: int):
+    """PM accumulation of one K slab in chunked PM blocks:
+    ``acc + sum_k (a[..., i, k] + b[..., k, j])^2``.
+
+    a_ref: (..., bm, bk) and b_ref: (..., bk, bn) VMEM refs holding values
+    widened to the accumulator dtype; acc: the carried (..., bm, bn)
+    value.  The PM step of the conv2d, grouped-expert and
+    paged-attention kernels."""
+    def body(rs, cs, acc):
+        s = rs[0] + cs[0]                    # PE operand adders
+        return acc + jnp.sum(s * s, -2)      # squarers + block reduction
+
+    return pm_chunked_reduce(acc, (a_ref,), (b_ref,), kc=kc, body=body)
+
+
 def pm_row_walk(acc, a_ref, b_ref, *, kc: int):
-    """The "kmn" walk: ``acc + sum_k (a[..., i, k] + b[..., k, j])^2``.
+    """The row walk: ``acc + sum_k (a[..., i, k] + b[..., k, j])^2``.
 
     a_ref: (..., bm, bk) and b_ref: (..., bk, bn) VMEM refs holding values
     widened to the accumulator dtype; acc: the carried (..., bm, bn)

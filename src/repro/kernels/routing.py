@@ -3,11 +3,9 @@
 ``BENCH_kernels.json`` proves the best execution route for a square-form
 contraction flips with shape: the fused window-streaming conv kernel wins
 4-6x at batch 4, while at tiny-K single-channel shapes the two conv
-routes sit near parity (the PR 3 tuned trajectory had im2col ~1.7x ahead
-there; the regime rule encodes the patch-blowup asymptotics, and
-:func:`set_route_override` pins measured winners per shape); tiny GEMMs
-are dominated by
-pallas-call overhead where the MXU-routed ``square_virtual`` form is
+routes sit near parity (im2col measured ~1.7x ahead there in interpret
+mode; the regime rule encodes the patch-blowup asymptotics); tiny GEMMs
+are dominated by pallas-call overhead where the MXU-routed ``square_virtual`` form is
 strictly faster; and batched GEMMs with very small (M, N) per element
 waste a grid step's fixed overhead on a few lane-ops.  Historically the
 route was hard-coded per mode; this module makes it a *cost-model* choice,
@@ -36,17 +34,13 @@ resolved once per (shape, dtype) at dispatch time:
                    pools, where one gather beats a many-step grid, and
                    is the only route for integer-logits paths).
 
-Overrides (most specific wins):
+Precedence:
 
 1. ``REPRO_ROUTE`` -- force a route globally (``REPRO_ROUTE=fused``) or
    per kind (``REPRO_ROUTE=matmul=kernel,conv2d=im2col``); ``auto`` (or
    unset) defers to the planner.  The repro escape hatch: pin the route a
    measurement was taken under.
-2. The autotune cache -- entries keyed ``route:<kind>:<sig>`` (written by
-   :func:`set_route_override` or by hand) pin a route per exact shape,
-   riding the same JSON table as the tile plans
-   (``$REPRO_TUNING_CACHE``, honored only when autotune is enabled).
-3. The cost model -- the threshold rules above, built from the
+2. The cost model -- the threshold rules above, built from the
    :mod:`repro.core.cost_model` tile-cost terms.
 """
 from __future__ import annotations
@@ -66,7 +60,6 @@ from repro.obs import trace as obs_trace
 
 __all__ = ["Route", "select_route", "select_matmul_route",
            "select_conv2d_route", "select_paged_attn_route",
-           "set_route_override", "route_key",
            "MATMUL_ROUTES", "CONV2D_ROUTES", "PAGED_ATTN_ROUTES",
            "VIRTUAL_FLOOR_MULTS", "FOLD_STEP_LANE_OPS",
            "IM2COL_PATCH_BYTES_MAX", "IM2COL_K_MAX",
@@ -97,11 +90,14 @@ VIRTUAL_FLOOR_MULTS = 32768
 FOLD_STEP_LANE_OPS = 8 * 4096
 FOLD_MIN_BATCH = 4
 
-# im2col wins while its patch matrix stays cache-resident (same working-set
-# budget as the "mnk" tile planner) AND the flattened K axis is below one
-# lane group -- tiny-K windows give the fused kernel's shared-window
-# machinery nothing to amortize (paper §5.1 regime boundary).
-IM2COL_PATCH_BYTES_MAX = tuning.CACHE_BUDGET
+# The chunk width the fold threshold's per-element PM work is counted at.
+FOLD_KC = 32
+
+# im2col wins while its patch matrix stays cache-resident (a 2 MiB
+# working set) AND the flattened K axis is below one lane group -- tiny-K
+# windows give the fused kernel's shared-window machinery nothing to
+# amortize (paper §5.1 regime boundary).
+IM2COL_PATCH_BYTES_MAX = 2 * 1024 * 1024
 IM2COL_K_MAX = tuning.LANE
 
 # The fused paged-attention kernel streams a sequence's live pool blocks,
@@ -182,43 +178,6 @@ def _env_route(kind: str, valid) -> Optional[str]:
                      f"{tuple(sorted(_ALL_ROUTES))} or 'auto'")
 
 
-def route_key(kind: str, sizes: dict, dtype) -> str:
-    """Cache key of a route override entry (tuning-cache JSON)."""
-    sig = "x".join(str(sizes[f]) for f in sorted(sizes))
-    return f"route:{kind}:{sig}:{jnp.dtype(dtype).name}"
-
-
-def _cached_route(kind: str, sizes: dict, dtype, valid) -> Optional[Route]:
-    if not tuning.autotune_enabled():
-        return None
-    entry = tuning.load_cache().get(route_key(kind, sizes, dtype))
-    if entry and entry.get("route") in valid:
-        return Route(entry["route"], "autotune-cache override")
-    return None
-
-
-def set_route_override(kind: str, sizes: dict, route: str,
-                       path: Optional[str] = None) -> str:
-    """Pin a route for an exact shape in the tuning cache (the empirical
-    counterpart of the cost-model rules; consulted by
-    :func:`select_route` whenever autotune is enabled)."""
-    valid = _KIND_ROUTES.get(kind)
-    if valid is None:
-        raise ValueError(f"unknown route kind {kind!r}; expected one of "
-                         f"{tuple(_KIND_ROUTES)}")
-    if route not in valid:
-        raise ValueError(f"unknown {kind} route {route!r}; expected one of "
-                         f"{valid}")
-    # key under the ACCUMULATOR dtype -- the selectors look entries up
-    # post-widening, so a bf16/int8 pin must land on the same key
-    dtype = sq.accum_dtype(jnp.dtype(sizes.pop("dtype", "float32")))
-    cache = dict(tuning.load_cache(path))
-    key = route_key(kind, sizes, dtype)
-    cache[key] = {"route": route}
-    tuning.save_cache(cache, path)
-    return key
-
-
 @_traced_selector("matmul")
 def select_matmul_route(m: int, n: int, k: int, *, batch: int = 1,
                         dtype=jnp.float32) -> Route:
@@ -226,18 +185,13 @@ def select_matmul_route(m: int, n: int, k: int, *, batch: int = 1,
     env = _env_route("matmul", MATMUL_ROUTES)
     if env is not None:
         return Route(env, "REPRO_ROUTE override")
-    sizes = {"b": batch, "m": m, "n": n, "k": k}
-    cached = _cached_route("matmul", sizes, sq.accum_dtype(dtype),
-                           MATMUL_ROUTES)
-    if cached is not None:
-        return cached
     mults = batch * m * n * k
     if mults < VIRTUAL_FLOOR_MULTS:
         return Route("virtual", f"volume {mults} below kernel-overhead "
                                 f"floor {VIRTUAL_FLOOR_MULTS}")
     if batch == 1:
         return Route("kernel", "unbatched GEMM")
-    step_ops = cm.pm_tile_vpu_ops(m, n, k, kc=tuning.KC_MNK_MAX)
+    step_ops = cm.pm_tile_vpu_ops(m, n, k, kc=FOLD_KC)
     if batch >= FOLD_MIN_BATCH and step_ops < FOLD_STEP_LANE_OPS:
         return Route("fold", f"per-element PM work {step_ops:.0f} lane-ops "
                              f"below the grid-step floor "
@@ -254,11 +208,6 @@ def select_conv2d_route(oh: int, ow: int, kh: int, kw: int, cin: int,
     if env is not None:
         return Route(env, "REPRO_ROUTE override")
     acc = sq.accum_dtype(dtype)
-    sizes = {"b": batch, "oh": oh, "ow": ow, "kh": kh, "kw": kw,
-             "ci": cin, "co": cout}
-    cached = _cached_route("conv2d", sizes, acc, CONV2D_ROUTES)
-    if cached is not None:
-        return cached
     kvol = cin * kh * kw
     patch = cm.conv2d_patch_bytes(oh, ow, kh, kw, cin, batch=batch,
                                   itemsize=jnp.dtype(acc).itemsize)
@@ -285,12 +234,6 @@ def select_paged_attn_route(s: int, t: int, *, batch: int = 1,
     env = _env_route("paged_attn", PAGED_ATTN_ROUTES)
     if env is not None:
         return Route(env, "REPRO_ROUTE override")
-    sizes = {"b": batch, "s": s, "t": t, "kv": kv_heads, "g": group,
-             "hd": hd}
-    cached = _cached_route("paged_attn", sizes, sq.accum_dtype(dtype),
-                           PAGED_ATTN_ROUTES)
-    if cached is not None:
-        return cached
     gbytes = cm.paged_attn_gather_bytes(t, kv_heads, hd, batch=batch)
     if s > PAGED_KERNEL_MAX_S:
         return Route("gather", f"query tile {s} > {PAGED_KERNEL_MAX_S}: "

@@ -23,9 +23,9 @@ the ``kh*kw`` taps in a ``fori_loop``:
   in a VMEM scratch slab -- the tile-local
   im2col that implicit-GEMM convolutions form in SRAM, bounded by the
   tile size, not the image size -- and routed through the chunked
-  block-PM contraction (:func:`repro.kernels.sq_matmul.pm_block_accum`)
+  block-PM contraction (:func:`repro.kernels.pm_blocks.pm_block_accum`)
   against that tap's (bk, bf) filter block: ``kc``-wide rank-2 broadcast
-  squaring in either PM layout, accumulating into a VMEM scratch tile
+  squaring in (bm, kc, bf) PM blocks, accumulating into a VMEM scratch tile
   that is live across the whole channel walk;
 - the data-side correction (the view's ``-x^2`` terms, shared by all
   ``bf`` filters of the step) is folded in one rank-2 pass -- O(M*K),
@@ -61,14 +61,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sq_matmul import pm_block_accum
+from repro.kernels.pm_blocks import pm_block_accum
 
 __all__ = ["sq_conv2d_kernel", "sq_conv2d_pallas"]
 
 
 def sq_conv2d_kernel(x_ref, w_ref, sw_ref, out_ref, acc_ref, slab_ref,
                      wtap_ref, *, nc: int, kc: int, bh: int, bw: int,
-                     sh: int, sv: int, pm_layout: str, is_int: bool):
+                     sh: int, sv: int, is_int: bool):
     """One (b, i, j, f, c) grid step of the fused 2D square-convolution.
 
     x_ref: (1, Hp, Wp, bk) this batch element's plane, channel-sliced;
@@ -97,8 +97,7 @@ def sq_conv2d_kernel(x_ref, w_ref, sw_ref, out_ref, acc_ref, slab_ref,
                    pl.ds(j * (bw * sv) + dj, bw, sv), :]   # (bh, bw, bk)
         slab_ref[...] = xs.reshape(bm, bk)
         wtap_ref[...] = w_ref[di, dj]
-        acc = pm_block_accum(acc, slab_ref, wtap_ref, kc=kc,
-                             pm_layout=pm_layout)
+        acc = pm_block_accum(acc, slab_ref, wtap_ref, kc=kc)
         # Data-side correction (-x^2, paper eq 14 Sx): rank-2, shared by
         # all bf filters of the step -- O(M*K), not O(M*K*N).
         a = slab_ref[...]
@@ -119,7 +118,7 @@ def sq_conv2d_kernel(x_ref, w_ref, sw_ref, out_ref, acc_ref, slab_ref,
 def sq_conv2d_pallas(x, w, sw, *, ohp: int, owp: int, bh: int, bw: int,
                      bk: int, bf: int, kc: int | None = None,
                      stride: tuple[int, int] = (1, 1),
-                     pm_layout: str = "mkn", interpret: bool = False):
+                     interpret: bool = False):
     """Raw pallas_call wrapper for the fused 2D square-convolution.
 
     Operands must be pre-widened to the accumulator dtype and pre-padded
@@ -144,8 +143,7 @@ def sq_conv2d_pallas(x, w, sw, *, ohp: int, owp: int, bh: int, bw: int,
     is_int = jnp.issubdtype(x.dtype, jnp.integer)
 
     kernel = functools.partial(sq_conv2d_kernel, nc=nc, kc=kc, bh=bh, bw=bw,
-                               sh=sh, sv=sv, pm_layout=pm_layout,
-                               is_int=is_int)
+                               sh=sh, sv=sv, is_int=is_int)
     return pl.pallas_call(
         kernel,
         grid=(nb, ohp // bh, owp // bw, Np // bf, nc),

@@ -22,7 +22,7 @@ output rows are left as they are; no pick reads them.
 Arithmetic is :mod:`repro.kernels.sq_matmul`'s: the accumulator starts at
 the corrections ``Sa_i + Sb_j`` (``-sum_k x^2`` per row, ``-sum_k w^2``
 per expert column, computed outside), each K step adds ``sum_k (x +
-w)^2`` through :func:`repro.kernels.sq_matmul.pm_block_accum`, and the
+w)^2`` through :func:`repro.kernels.pm_blocks.pm_block_accum`, and the
 last halves.  Weights stay in their stored dtype in HBM and are widened
 to f32 in VMEM; rows and corrections are f32.  Float only.
 """
@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sq_matmul import pm_block_accum
+from repro.kernels.pm_blocks import pm_block_accum
 
 __all__ = ["sq_grouped_matmul_kernel", "sq_grouped_matmul_pallas",
            "group_rows", "max_tiles"]
@@ -83,8 +83,7 @@ def group_rows(expert, n_experts: int, bm: int):
 
 
 def sq_grouped_matmul_kernel(te_ref, used_ref, x_ref, w_ref, sa_ref, sb_ref,
-                             out_ref, acc_ref, wf_ref, *, nk: int, kc: int,
-                             pm_layout: str):
+                             out_ref, acc_ref, wf_ref, *, nk: int, kc: int):
     """One (tile, column block, K block) step.
 
     x_ref: (bm, bk) f32 rows; w_ref: (1, bk, bn) the tile's expert's
@@ -103,8 +102,7 @@ def sq_grouped_matmul_kernel(te_ref, used_ref, x_ref, w_ref, sa_ref, sb_ref,
     @pl.when(live)
     def _accumulate():
         wf_ref[...] = w_ref[0].astype(jnp.float32)
-        acc_ref[...] = pm_block_accum(acc_ref[...], x_ref, wf_ref, kc=kc,
-                                      pm_layout=pm_layout)
+        acc_ref[...] = pm_block_accum(acc_ref[...], x_ref, wf_ref, kc=kc)
 
     @pl.when(live & (kk == nk - 1))
     def _finalize():
@@ -113,7 +111,6 @@ def sq_grouped_matmul_kernel(te_ref, used_ref, x_ref, w_ref, sa_ref, sb_ref,
 
 def sq_grouped_matmul_pallas(x, w, sa, sb, tile_expert, n_used, *, bm: int,
                              bn: int, bk: int, kc: int | None = None,
-                             pm_layout: str = "mkn",
                              interpret: bool = False):
     """x (R, K) f32 rows grouped by tile; w (E, K, N) in its stored dtype;
     sa (R, 1) and sb (E, 1, N) f32 corrections; tile_expert
@@ -149,8 +146,7 @@ def sq_grouped_matmul_pallas(x, w, sa, sb, tile_expert, n_used, *, bm: int,
         dead = i >= used[0]
         return (te[jnp.minimum(i, last(used))], 0, jnp.where(dead, nn - 1, j))
 
-    kernel = functools.partial(sq_grouped_matmul_kernel, nk=nk, kc=kc,
-                               pm_layout=pm_layout)
+    kernel = functools.partial(sq_grouped_matmul_kernel, nk=nk, kc=kc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_tiles, nn, nk),

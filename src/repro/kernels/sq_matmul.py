@@ -17,43 +17,27 @@ K axis (grid minor dimension), exactly like a weight-stationary pass:
 
 Dataflow
 --------
-Each (bm, bk) x (bk, bn) grid step accumulates its K slab under one of
-three static PM layouts (:mod:`repro.kernels.pm_blocks`), set by the
-backend (:mod:`repro.kernels.ops`); the planner in
-:mod:`repro.kernels.tuning` picks ``(bm, bn, bk, kc)`` for it:
+Each (bm, bk) x (bk, bn) grid step walks its K slab one row at a time
+(:func:`repro.kernels.pm_blocks.pm_row_walk`); the planner in
+:mod:`repro.kernels.tuning` picks ``(bm, bn, bk, kc)`` for it.  The
+(bm, bn) partial sum stays in vregs while K is walked one index at a
+time::
 
-``"kmn"`` -- the TPU layout of this kernel
-    The (bm, bn) partial sum stays in vregs while K is walked one index
-    at a time::
+    part[i, j] += (a[i, k] + b[k, j])^2    # k = 0 .. kc-1, unrolled
 
-        part[i, j] += (a[i, k] + b[k, j])^2    # k = 0 .. kc-1, unrolled
-
-    with ``a``'s column broadcast over lanes and ``b``'s row over
-    sublanes; a ``fori_loop`` walks the slab in ``kc``-wide lane groups
-    (128 on the chip) and adds each group's partial to the carried
-    accumulator.  Nothing block-sized is formed: no (bm, kc, bn) value to
-    spill, no cross-sublane reduction.  One lane broadcast serves
-    ``bn / 128`` vregs of PM terms and one row broadcast ``bm / 8``, so
-    the planner takes wide ``bn`` and all rows of a small M in one tile
-    (the weights are then streamed once), bounded by the vreg budget of
-    :func:`repro.core.cost_model.pm_walk_grid_cost`.
-``"mkn"``
-    Chunked: one ``kc``-wide chunk forms the rank-3 PM block
-    ``s[i, c, j] = a[i, c] + b[c, j]`` (bm, kc, bn) and reduces it over
-    the middle axis.  At any useful ``kc`` the block outgrows the
-    register file and Mosaic spills each block-wide value to VMEM, so
-    this kernel runs it on the chip only when a caller asks for it.
-    The paged-attention and grouped-expert kernels, which share
-    :func:`pm_block_accum`, stay on it: their tiles were tuned and timed
-    on it and the row walk never was.
-``"mnk"``
-    Chunked, with each ``b`` chunk transposed to a (bm, bn, kc) block
-    reduced over the *minor* axis: a dot-product-shaped loop nest, which
-    is what CPU interpret mode (and the XLA CPU backend generally)
-    executes fastest -- ~6x over the seed rank-1 kernel at 128^3 f32.
-
-All three are the same arithmetic (one operand add + one square per PM
-term); integer operands stay bit-exact under each.
+with ``a``'s column broadcast over lanes and ``b``'s row over sublanes; a
+``fori_loop`` walks the slab in ``kc``-wide lane groups (128 on the chip)
+and adds each group's partial to the carried accumulator.  Nothing
+block-sized is formed: no (bm, kc, bn) value to spill, no cross-sublane
+reduction.  One lane broadcast serves ``bn / 128`` vregs of PM terms and
+one row broadcast ``bm / 8``, so the planner takes wide ``bn`` and all
+rows of a small M in one tile (the weights are then streamed once),
+bounded by the vreg budget of
+:func:`repro.core.cost_model.pm_walk_grid_cost`.  Timed on a TPU v5e at
+the benchmark cells' shapes, it ran up to 7.2x faster than the chunked PM
+blocks the other kernels use (``benchmarks/kernel_timing.py
+--sweep-sq-matmul``).  It is one operand add and one square per PM term;
+integer operands stay bit-exact.
 
 One kernel serves the plain, batched and batch-folded forms: its refs
 carry ``fb`` batch elements on a leading axis (``fb == 1`` for a plain or
@@ -78,35 +62,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pm_blocks import (KMN_GROUP, PM_LAYOUTS,
-                                     pm_chunked_reduce, pm_row_walk)
+from repro.kernels.pm_blocks import KMN_GROUP, pm_row_walk
 
-__all__ = ["sq_matmul_kernel", "sq_matmul_pallas", "sq_matmul_batched_pallas",
-           "pm_block_accum", "PM_LAYOUTS"]
-
-
-def pm_block_accum(acc, a_ref, b_ref, *, kc: int, pm_layout: str):
-    """PM accumulation of one K slab: ``acc + sum_k (a[i,k] + b[k,j])^2``.
-
-    a_ref: (..., bm, bk) and b_ref: (..., bk, bn) VMEM refs holding values
-    pre-widened to the accumulator dtype; acc: the carried (..., bm, bn)
-    accumulator.  The K slab is walked in ``kc``-wide groups by the
-    shared machinery in kernels.pm_blocks: the row walk for "kmn", PM
-    blocks for the chunked layouts.
-    """
-    if pm_layout == "kmn":
-        return pm_row_walk(acc, a_ref, b_ref, kc=kc)
-
-    def body(rs, cs, axis, acc):
-        s = rs[0] + cs[0]                    # PE operand adders
-        return acc + jnp.sum(s * s, axis)    # squarers + block reduction
-
-    return pm_chunked_reduce(acc, (a_ref,), (b_ref,), kc=kc,
-                             pm_layout=pm_layout, body=body)
+__all__ = ["sq_matmul_kernel", "sq_matmul_pallas", "sq_matmul_batched_pallas"]
 
 
 def sq_matmul_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref, *,
-                     nk: int, kc: int, pm_layout: str, is_int: bool):
+                     nk: int, kc: int, is_int: bool):
     """One (batch-block, i, j, k) grid step of the square matmul.
 
     a_ref: (fb, bm, bk); b_ref: (fb, bk, bn); sa_ref: (fb, bm, 1);
@@ -123,8 +85,7 @@ def sq_matmul_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref, *,
         # register first with Sa_i + Sb_j").
         acc_ref[...] = sa_ref[...] + sb_ref[...]
 
-    acc_ref[...] = pm_block_accum(acc_ref[...], a_ref, b_ref, kc=kc,
-                                  pm_layout=pm_layout)
+    acc_ref[...] = pm_row_walk(acc_ref[...], a_ref, b_ref, kc=kc)
 
     @pl.when(k_step == nk - 1)
     def _finalize():
@@ -139,15 +100,14 @@ def sq_matmul_kernel(a_ref, b_ref, sa_ref, sb_ref, out_ref, acc_ref, *,
 
 def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
                              bk: int = 128, kc: int | None = None,
-                             fb: int = 1, pm_layout: str = "mkn",
-                             interpret: bool = False):
+                             fb: int = 1, interpret: bool = False):
     """Batched pallas_call wrapper: a (B, m, k), b (B, k, n), corrections
     sa (B, m, 1) / sb (B, 1, n).  ``fb`` batch elements per grid step on
     the outermost batch grid axis; B must be an fb multiple (the ops
     wrapper zero-pads, and zero batch elements are exact no-ops).
     Operands must be pre-widened to the accumulator dtype and pre-padded
     to tile multiples (see kernels.ops).  ``kc`` must divide ``bk``
-    (defaults to ``bk``: one chunk; for "kmn", one lane group)."""
+    (defaults to one lane group, or ``bk`` when it is narrower)."""
     nb, m, k = a.shape
     nb2, k2, n = b.shape
     assert nb == nb2 and k == k2
@@ -155,13 +115,12 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     assert nb % fb == 0, (nb, fb)
     if kc is None:
-        kc = min(bk, KMN_GROUP) if pm_layout == "kmn" else bk
+        kc = min(bk, KMN_GROUP)
     assert bk % kc == 0, (bk, kc)
     nk = k // bk
     is_int = jnp.issubdtype(a.dtype, jnp.integer)
 
-    kernel = functools.partial(sq_matmul_kernel, nk=nk, kc=kc,
-                               pm_layout=pm_layout, is_int=is_int)
+    kernel = functools.partial(sq_matmul_kernel, nk=nk, kc=kc, is_int=is_int)
     return pl.pallas_call(
         kernel,
         grid=(nb // fb, m // bm, n // bn, nk),
@@ -183,10 +142,10 @@ def sq_matmul_batched_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
 
 def sq_matmul_pallas(a, b, sa, sb, *, bm: int = 256, bn: int = 256,
                      bk: int = 128, kc: int | None = None,
-                     pm_layout: str = "mkn", interpret: bool = False):
+                     interpret: bool = False):
     """Plain (m, k) x (k, n) wrapper: the batched kernel at one element,
     with sa (m, 1) / sb (1, n).  Same operand contract as
     :func:`sq_matmul_batched_pallas`."""
     return sq_matmul_batched_pallas(
         a[None], b[None], sa[None], sb[None], bm=bm, bn=bn, bk=bk, kc=kc,
-        pm_layout=pm_layout, interpret=interpret)[0]
+        interpret=interpret)[0]

@@ -42,7 +42,7 @@ Every block obeys Mosaic's rule that the two minor block dims are (8,
 blocks, whole ``(rows, hd)`` query panels, whole ``(1, bs)`` positions.
 
 Per head, both contractions run through the shared square-PM machinery
-(:func:`repro.kernels.sq_matmul.pm_block_accum`):
+(:func:`repro.kernels.pm_blocks.pm_block_accum`):
 
 - **scores**: ``2 * (q @ k^T)`` accumulated as ``sum_h (q + k)^2`` with
   the rank-2 corrections ``-sum q^2`` / ``-sum k^2`` as the accumulator
@@ -80,8 +80,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pm_blocks import CHUNKED_LAYOUTS
-from repro.kernels.sq_matmul import pm_block_accum
+from repro.kernels.pm_blocks import pm_block_accum
 
 __all__ = ["sq_paged_attn", "sq_paged_attn_kernel", "tile_blocks",
            "walk_bounds", "TILE_TOKENS"]
@@ -132,7 +131,7 @@ def _walk_tables(tables, lo, hi, tpb: int):
 
 def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, *refs, tpb: int,
                          kv_heads: int, kc_qk: int, kc_pv: int,
-                         pm_layout: str, window: Optional[int],
+                         window: Optional[int],
                          softcap: float, attend_limit: int,
                          latent_dv: Optional[int] = None):
     """One (sequence, tile) grid step.
@@ -202,8 +201,7 @@ def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, *refs, tpb: int,
             qv, ktv = q[...], kt[...]
             sq_row = -jnp.sum(qv * qv, axis=1, keepdims=True)   # (rows, 1)
             sk_col = -jnp.sum(ktv * ktv, axis=0, keepdims=True)  # (1, T)
-            s = 0.5 * pm_block_accum(sq_row + sk_col, q, kt, kc=kc_qk,
-                                     pm_layout=pm_layout)
+            s = 0.5 * pm_block_accum(sq_row + sk_col, q, kt, kc=kc_qk)
             if softcap and softcap > 0.0:
                 s = jnp.tanh(s / softcap) * softcap
             s = jnp.where(mask, s, NEG_INF)
@@ -227,8 +225,7 @@ def sq_paged_attn_kernel(tables_ref, q_ref, qpos_ref, *refs, tpb: int,
             vv = v[...]
             sp_row = -jnp.sum(p * p, axis=1, keepdims=True)     # (rows, 1)
             sv_col = -jnp.sum(vv * vv, axis=0, keepdims=True)   # (1, hd)
-            pv = 0.5 * pm_block_accum(sp_row + sv_col, p_ref, v, kc=kc_pv,
-                                      pm_layout=pm_layout)
+            pv = 0.5 * pm_block_accum(sp_row + sv_col, p_ref, v, kc=kc_pv)
             acc_ref[h] = acc_ref[h] * corr + pv
             return carry
 
@@ -244,7 +241,6 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
                   block_size: int, window: Optional[int] = None,
                   softcap: float = 0.0, attend_limit: int = 2 ** 29,
                   kc_qk: Optional[int] = None, kc_pv: Optional[int] = None,
-                  pm_layout: Optional[str] = None,
                   interpret: Optional[bool] = None,
                   dv: Optional[int] = None):
     """Fused paged attention: softmax(q @ K^T) @ V over block tables.
@@ -277,11 +273,6 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
-    if pm_layout is None:
-        pm_layout = "mnk" if interpret else "mkn"
-    if pm_layout not in CHUNKED_LAYOUTS:
-        raise ValueError(f"unknown pm_layout {pm_layout!r}; expected one "
-                         f"of {CHUNKED_LAYOUTS}")
     latent = v_pool is None
     if latent and not (dv and 0 < dv <= hd):
         raise ValueError(f"latent mode (no V pool) needs 0 < dv <= {hd}, "
@@ -326,7 +317,7 @@ def sq_paged_attn(q, k_pool, v_pool, tables, pos_pool, q_pos, *,
 
     kernel = functools.partial(
         sq_paged_attn_kernel, tpb=tpb, kv_heads=KV, kc_qk=kc_qk,
-        kc_pv=kc_pv, pm_layout=pm_layout, window=window, softcap=softcap,
+        kc_pv=kc_pv, window=window, softcap=softcap,
         attend_limit=attend_limit, latent_dv=Dv if latent else None)
     pos_specs = [pl.BlockSpec((1, 1, block_size), pos_map(j))
                  for j in range(tpb)]

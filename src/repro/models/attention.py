@@ -504,19 +504,15 @@ def paged_read(qf, k_pool, v_pool, pos, paged, *, dt, window, softcap,
 
     from repro.core import guards
     from repro.kernels import tuning
-    from repro.kernels.ops import default_interpret
     from repro.kernels.sq_paged_attn import sq_paged_attn, tile_blocks
-    interp = default_interpret()
     bs = paged["block_size"]
     plan = tuning.plan_paged_attn(
-        S * G, hd, tile_blocks(bs, paged["tables"].shape[1]) * bs,
-        k_pool.dtype, kv_heads=KV, dv=dv,
-        pm_layout="mnk" if interp else "mkn")
+        hd, tile_blocks(bs, paged["tables"].shape[1]) * bs)
     out = sq_paged_attn(
         qf, k_pool, v_pool, paged["tables"], paged["pos_pool"], pos,
         block_size=bs, window=window, softcap=softcap,
         attend_limit=ATTEND_POS_LIMIT, kc_qk=plan.kc_qk, kc_pv=plan.kc_pv,
-        pm_layout=plan.pm_layout, interpret=interp, dv=dv)
+        dv=dv)
     gp = guards.guard_policy()
     if gp.enabled and guards.check_finite(out) is False:
         # eager-only (check_finite is None under a jit trace): trip the

@@ -238,8 +238,7 @@ class LM:
                          policy=cfg.contraction_policy, site="logits")
 
     # --------------------------------------------- prepared weights (infer)
-    def prepare_params(self, params, *, interpret=None,
-                       prepare_grads: bool = False):
+    def prepare_params(self, params, *, prepare_grads: bool = False):
         """Weight-stationary inference params (paper §4-§5).
 
         Returns a params tree where every dense/projection/expert weight
@@ -266,7 +265,6 @@ class LM:
         cfg = self.cfg
         hd = cfg.resolved_head_dim
         H, KV = cfg.n_heads, cfg.n_kv_heads
-        interp = interpret
         pg = prepare_grads
 
         def prep_dense(p, site):
@@ -274,8 +272,7 @@ class LM:
             if w.ndim != 2:
                 return p                      # stacked (scan) leaf: keep raw
             q = dict(p)
-            q["w"] = prepare_operand(w, site=site, interpret=interp,
-                                     prepare_grads=pg)
+            q["w"] = prepare_operand(w, site=site, prepare_grads=pg)
             return q
 
         def prep_mla(p):
@@ -293,8 +290,7 @@ class LM:
                 elif nm == "wo":
                     w = w.reshape(-1, w.shape[-1])
                 q[nm] = dict(p[nm], w=prepare_operand(
-                    w, site=site, interpret=interp,
-                    prepare_grads=pg and w.ndim == 2))
+                    w, site=site, prepare_grads=pg and w.ndim == 2))
             return q
 
         def prep_attn(p):
@@ -307,14 +303,12 @@ class LM:
                     return p                  # stacked: keep the block raw
                 sub = dict(q[nm])
                 sub["w"] = prepare_operand(w.reshape(w.shape[0], nh * hd),
-                                           site="attn_qkv", interpret=interp,
-                                           prepare_grads=pg)
+                                           site="attn_qkv", prepare_grads=pg)
                 q[nm] = sub
             wo = q["wo"]["w"]
             sub = dict(q["wo"])
             sub["w"] = prepare_operand(wo.reshape(H * hd, wo.shape[-1]),
-                                       site="attn_out", interpret=interp,
-                                       prepare_grads=pg)
+                                       site="attn_out", prepare_grads=pg)
             q["wo"] = sub
             return q
 
@@ -326,8 +320,7 @@ class LM:
                 if w.ndim != 3:
                     return p
                 sub = dict(p[nm])
-                sub["w"] = prepare_operand(w, site="moe_expert",
-                                           interpret=interp)
+                sub["w"] = prepare_operand(w, site="moe_expert")
                 q[nm] = sub
             return q
 
@@ -352,7 +345,6 @@ class LM:
         table = _head_table(params)
         new["logits_prep"] = prepare_operand(table.astype(jnp.float32),
                                              transpose=True, site="logits",
-                                             interpret=interp,
                                              prepare_grads=pg)
         return new
 

@@ -276,9 +276,9 @@ _DEFAULT = MetricsRegistry()
 
 
 def default_registry() -> MetricsRegistry:
-    """The process-default registry (module-level instrumentation --
-    autotune cache hits/misses -- lands here; engines and trainers carry
-    their own registries so per-run invariants stay per-run)."""
+    """The process-default registry (callers that pass no registry of
+    their own publish here; engines and trainers carry their own
+    registries so per-run invariants stay per-run)."""
     return _DEFAULT
 
 

@@ -5,7 +5,6 @@ Every configuration is checked against ``jax.lax.conv_general_dilated``
 (``ops.sq_conv2d_im2col``) -- the two must agree because they are the
 same arithmetic through different dataflows.
 """
-import json
 
 import jax
 import jax.numpy as jnp
@@ -150,8 +149,8 @@ def test_explicit_plan_overrides_respected():
     w = jnp.asarray(RNG.normal(size=(4, 6, 3, 3)).astype(np.float32))
     base = np.asarray(ops.sq_conv2d(x, w))
     for kwargs in [dict(bh=4, bw=5, bk=3, kc=9, bf=2),
-                   dict(bh=10, bw=10, bk=6, kc=1, bf=4, pm_layout="mkn"),
-                   dict(bh=2, bw=12, bk=2, kc=6, bf=3, pm_layout="mnk")]:
+                   dict(bh=10, bw=10, bk=6, kc=1, bf=4),
+                   dict(bh=2, bw=12, bk=2, kc=6, bf=3)]:
         out = np.asarray(ops.sq_conv2d(x, w, **kwargs))
         np.testing.assert_allclose(out, base, rtol=1e-4, atol=1e-2)
 
@@ -198,52 +197,19 @@ def test_conv2d_unknown_mode_raises():
 
 
 # ---------------------------------------------------------------------------
-# plan_conv2d / autotune
+# plan_conv2d
 # ---------------------------------------------------------------------------
 
 def test_plan_conv2d_kc_divides_flattened_axis():
     for (h, w, kh, kw, cin, cout) in [(32, 32, 3, 3, 64, 64),
                                       (15, 18, 5, 3, 7, 9),
                                       (12, 12, 3, 3, 1, 1)]:
-        for layout in ("mkn", "mnk"):
-            plan = tuning.plan_conv2d(h, w, kh, kw, cin, cout,
-                                      pm_layout=layout)
-            assert (kh * kw * plan.bk) % plan.kc == 0, plan
-            assert plan.bk <= cin and plan.bf <= cout
+        plan = tuning.plan_conv2d(h, w, kh, kw, cin, cout)
+        assert (kh * kw * plan.bk) % plan.kc == 0, plan
+        assert plan.bk <= cin and plan.bf <= cout
 
 
 def test_plan_conv2d_explicit_wins():
     plan = tuning.plan_conv2d(32, 32, 3, 3, 64, 64, bh=8, bw=16, bk=32,
-                              kc=16, bf=32, pm_layout="mnk")
-    assert plan == tuning.Conv2DPlan(8, 16, 32, 16, 32, "mnk")
-
-
-def test_plan_conv2d_cache_roundtrip(tmp_path, monkeypatch):
-    path = tmp_path / "cache.json"
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    tuning.clear_cache()
-    entry = {"bh": 10, "bw": 10, "bk": 8, "kc": 8, "bf": 16,
-             "pm_layout": "mnk", "us_per_call": 1.0}
-    path.write_text(json.dumps(
-        {"sq_conv2d:20x20:k3x3:s1x1:c8->16:float32": entry}))
-    plan = tuning.plan_conv2d(20, 20, 3, 3, 8, 16, pm_layout="mnk")
-    assert plan == tuning.Conv2DPlan(10, 10, 8, 8, 16, "mnk")
-    # layout-mismatched entries must not be served
-    plan = tuning.plan_conv2d(20, 20, 3, 3, 8, 16, pm_layout="mkn")
-    assert plan.pm_layout == "mkn" and plan != \
-        tuning.Conv2DPlan(10, 10, 8, 8, 16, "mkn")
-    tuning.clear_cache()
-
-
-def test_autotune_conv2d_smoke(tmp_path, monkeypatch):
-    path = tmp_path / "cache.json"
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    tuning.clear_cache()
-    cache = tuning.autotune_conv2d([(10, 10, 3, 3, 2, 2)],
-                                   max_candidates=2, reps=1)
-    key = "sq_conv2d:10x10:k3x3:s1x1:c2->2:float32"
-    assert key in cache and cache[key]["us_per_call"] > 0
-    plan = tuning.plan_conv2d(10, 10, 3, 3, 2, 2,
-                              pm_layout=cache[key]["pm_layout"])
-    assert plan.bh == cache[key]["bh"] and plan.kc == cache[key]["kc"]
-    tuning.clear_cache()
+                              kc=16, bf=32)
+    assert plan == tuning.Conv2DPlan(8, 16, 32, 16, 32)

@@ -1,16 +1,18 @@
-"""Equivalence + planner tests for the vectorized (chunked) Pallas kernels.
+"""Equivalence + planner tests for the vectorized Pallas kernels.
 
-The chunked kernels must compute exactly what the pre-refactor rank-1
-kernels computed: the streamed square-form contraction of
+The kernels must compute exactly what the pre-refactor rank-1 kernels
+computed: the streamed square-form contraction of
 ``core.matmul.pm_matmul_scan``.  Integer paths bit-match; float paths match
-to reassociation tolerance (chunking changes the add order, nothing else).
+to reassociation tolerance (grouping changes the add order, nothing else).
+The planner tests pin each kernel's one PM schedule and the plans the
+benchmark cells' shapes resolve to.
 """
-import json
-
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import squares as sq
 from repro.core.matmul import pm_matmul_scan
 from repro.kernels import ops, tuning
 
@@ -19,19 +21,18 @@ RNG = np.random.default_rng(11)
 RAGGED_SHAPES = [(1, 1, 1), (7, 13, 9), (100, 60, 130), (64, 128, 32),
                  (130, 257, 140)]
 
-# (bm, bn, bk, kc) plans: degenerate 1-chunk (kc == bk), rank-1 (kc == 1),
-# and mid chunkings, across both PM-block layouts.
+# (bm, bn, bk, kc) row-walk plans: one group per slab (kc == bk), rank-1
+# (kc == 1, the seed dataflow), and mid groupings; two lane groups over a
+# K of 200 padded to 256; bm 32 with a bn of 512 (clamped to these shapes)
 PLANS = [
-    dict(bm=32, bn=128, bk=32, kc=32, pm_layout="mnk"),    # 1-chunk
-    dict(bm=32, bn=128, bk=32, kc=32, pm_layout="mkn"),    # 1-chunk, TPU lay
-    dict(bm=64, bn=128, bk=128, kc=1, pm_layout="mkn"),    # rank-1 (seed)
-    dict(bm=64, bn=128, bk=128, kc=32, pm_layout="mnk"),
-    dict(bm=8, bn=128, bk=64, kc=16, pm_layout="mkn"),
-    # "kmn" row walk: one unrolled group; two lane groups over a K of 200
-    # padded to 256; bm 32 with a bn of 512 (clamped to these shapes)
-    dict(bm=8, bn=128, bk=64, kc=64, pm_layout="kmn"),
-    dict(bm=8, bn=128, bk=256, kc=128, pm_layout="kmn"),
-    dict(bm=32, bn=512, bk=256, kc=128, pm_layout="kmn"),
+    dict(bm=32, bn=128, bk=32, kc=32),                     # one group
+    dict(bm=16, bn=256, bk=128, kc=64),
+    dict(bm=64, bn=128, bk=128, kc=1),                     # rank-1 (seed)
+    dict(bm=64, bn=128, bk=128, kc=32),
+    dict(bm=8, bn=128, bk=64, kc=16),
+    dict(bm=8, bn=128, bk=64, kc=64),
+    dict(bm=8, bn=128, bk=256, kc=128),
+    dict(bm=32, bn=512, bk=256, kc=128),
 ]
 
 
@@ -71,16 +72,16 @@ def test_plans_agree_int8_bitexact(plan):
 
 
 @pytest.mark.parametrize("kind", ["cpm3_matmul", "cpm4_matmul"])
-@pytest.mark.parametrize("kc,pm_layout", [(1, "mkn"), (64, "mkn"),
-                                          (16, "mnk"), (64, "mnk")])
-def test_cpm_chunked_layouts_agree(kind, kc, pm_layout):
+@pytest.mark.parametrize("kc", [pytest.param(kc, id=f"{kc}-mkn")
+                                for kc in (1, 16, 32, 64)])
+def test_cpm_chunked_layouts_agree(kind, kc):
     m, k, n = 40, 64, 24
     x = jnp.asarray((RNG.normal(size=(m, k))
                      + 1j * RNG.normal(size=(m, k))).astype(np.complex64))
     y = jnp.asarray((RNG.normal(size=(k, n))
                      + 1j * RNG.normal(size=(k, n))).astype(np.complex64))
     op = getattr(ops, kind)
-    re, im = op(x, y, bk=64, kc=kc, pm_layout=pm_layout)
+    re, im = op(x, y, bk=64, kc=kc)
     z = np.asarray(x) @ np.asarray(y)
     np.testing.assert_allclose(np.asarray(re), z.real, rtol=1e-3, atol=1e-3 * k)
     np.testing.assert_allclose(np.asarray(im), z.imag, rtol=1e-3, atol=1e-3 * k)
@@ -143,8 +144,8 @@ def test_planner_small_operands_exact():
 
 def test_planner_kc_divides_bk():
     for (m, n, k) in [(128, 128, 128), (1000, 333, 77), (8, 8, 8)]:
-        for layout in ("mkn", "mnk"):
-            plan = tuning.plan_matmul(m, n, k, pm_layout=layout)
+        for kind in ("sq_matmul", "cpm3_matmul"):
+            plan = tuning.plan_matmul(m, n, k, kind=kind)
             assert plan.bk % plan.kc == 0, plan
 
 
@@ -153,140 +154,15 @@ def test_planner_explicit_tiles_respected():
     assert (plan.bm, plan.bn, plan.bk, plan.kc) == (64, 128, 128, 16)
 
 
-def test_planner_mnk_cache_budget():
-    """mnk plans cap the reduce depth and keep the hot (bn, kc) panel
-    cache-resident (the chunk-wide bound was retired: large-bm single-step
-    plans are the measured winners on tall-skinny im2col shapes)."""
-    for plan in tuning.candidate_plans(1024, 1024, 1024, pm_layout="mnk"):
-        if plan.kc > 1:
-            assert plan.kc <= tuning.KC_MNK_MAX
-            assert (plan.bn + tuning.SUBLANE) * plan.kc * 4 \
-                <= tuning.CACHE_BUDGET
-
-
 def test_planner_vmem_budget():
     from repro.core import cost_model as cm
     for plan in tuning.candidate_plans(2048, 2048, 2048):
-        cost = cm.pm_grid_cost(2048, 2048, 2048, *plan.astuple())
+        cost = cm.pm_walk_grid_cost(2048, 2048, 2048, *plan.astuple())
         assert cost.vmem_bytes <= tuning.VMEM_BUDGET
-
-
-def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
-    """plan_matmul must serve plans straight from a JSON cache file."""
-    path = tmp_path / "tuning_cache.json"
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    tuning.clear_cache()
-    entry = {"bm": 16, "bn": 128, "bk": 64, "kc": 16, "pm_layout": "mnk",
-             "us_per_call": 1.0}
-    path.write_text(json.dumps({"sq_matmul:64x64x64:float32": entry}))
-    plan = tuning.plan_matmul(64, 64, 64, jnp.float32, pm_layout="mnk")
-    assert plan == tuning.TilePlan(16, 128, 64, 16, "mnk")
-    # a layout mismatch must NOT serve the cached plan (CPU-tuned "mnk"
-    # entries never leak into TPU "mkn" plans)
-    plan = tuning.plan_matmul(64, 64, 64, jnp.float32, pm_layout="mkn")
-    assert plan.pm_layout == "mkn" and plan.bm != 16
-    # explicit user tiles bypass the cache
-    plan = tuning.plan_matmul(64, 64, 64, jnp.float32, bm=32,
-                              pm_layout="mnk")
-    assert plan.bm == 32
-    tuning.clear_cache()
-
-
-def test_autotune_sweep_smoke(tmp_path, monkeypatch):
-    """End-to-end: autotune a tiny shape, then plan from the cache."""
-    path = tmp_path / "cache.json"
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    tuning.clear_cache()
-    cache = tuning.autotune_matmul([(32, 32, 32)], jnp.float32,
-                                   max_candidates=2, reps=1)
-    key = "sq_matmul:32x32x32:float32"
-    assert key in cache and cache[key]["us_per_call"] > 0
-    plan = tuning.plan_matmul(32, 32, 32, jnp.float32,
-                              pm_layout=cache[key]["pm_layout"])
-    assert plan.bm == cache[key]["bm"] and plan.kc == cache[key]["kc"]
-    tuning.clear_cache()
-
-
-def test_autotune_miss_warns_once(tmp_path, monkeypatch):
-    """On a cache miss the planner warns ONCE per key and falls back to the
-    cost-model plan (no silent per-call sweeping)."""
-    import warnings
-
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "none.json"))
-    tuning.clear_cache()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        p1 = tuning.plan_matmul(37, 41, 43, jnp.float32, pm_layout="mnk")
-        assert len(w) == 1 and "autotune cache miss" in str(w[0].message)
-        p2 = tuning.plan_matmul(37, 41, 43, jnp.float32, pm_layout="mnk")
-        assert len(w) == 1                       # warned once, not twice
-    assert p1 == p2                              # deterministic model plan
-    # explicit tiles never consult the cache, so they never warn
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        tuning.plan_matmul(37, 41, 43, jnp.float32, bm=8, bn=128, bk=128)
-        assert len(w) == 0
-    tuning.clear_cache()
-
-
-def test_autotune_escape_hatch(tmp_path, monkeypatch):
-    """REPRO_AUTOTUNE=0 disables cache lookups AND the miss warning."""
-    import warnings
-
-    path = tmp_path / "cache.json"
-    entry = {"bm": 16, "bn": 128, "bk": 64, "kc": 16, "pm_layout": "mnk",
-             "us_per_call": 1.0}
-    path.write_text(json.dumps({"sq_matmul:64x64x64:float32": entry}))
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
-    tuning.clear_cache()
-    assert not tuning.autotune_enabled()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        plan = tuning.plan_matmul(64, 64, 64, jnp.float32, pm_layout="mnk")
-        assert len(w) == 0                       # no miss warning
-    assert plan != tuning.TilePlan(16, 128, 64, 16, "mnk")   # cache ignored
-    tuning.clear_cache()
-
-
-# The training bench's GEMM population (benchmarks/training.py model at
-# B=2, S=64): forward ffn pair at M = B*S*2 rows after attn concat, the
-# TRANSPOSED backward pair the custom VJP emits for dL/dx / dL/dW, and
-# the chunked vocab-grad trio (loss_chunk rows t=64 against v=4096).
-TRAINING_GEMMS = [           # (m, n, k), matching plan_matmul's order
-    (256, 256, 128),      # qkv/out fwd + bwd_x (square d_model block)
-    (256, 1024, 128),     # ffn up bwd pair
-    (1024, 256, 128),     # ffn down bwd pair (transposed partner)
-    (64, 4096, 256),      # chunked logits fwd (t x d @ d x v)
-    (64, 256, 4096),      # logits bwd_x (t x v @ v x d)
-    (4096, 256, 64),      # logits bwd_w (v x t @ t x d, transposed)
-]
-
-
-def test_training_shapes_served_from_committed_cache(monkeypatch):
-    """Every training-bench GEMM (forward, transposed-backward pair, and
-    vocab-grad trio) must hit the COMMITTED package cache: plan_matmul
-    serves the tuned plan with zero miss warnings, so a square_pallas
-    train step traces warning-free out of the box."""
-    import warnings
-
-    monkeypatch.delenv("REPRO_TUNING_CACHE", raising=False)
-    tuning.clear_cache()
-    cache = tuning.load_cache()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        for (m, n, k) in TRAINING_GEMMS:
-            key = f"sq_matmul:{m}x{n}x{k}:float32"
-            assert key in cache, f"committed cache missing {key}"
-            entry = cache[key]
-            plan = tuning.plan_matmul(m, n, k, jnp.float32,
-                                      pm_layout=entry["pm_layout"])
-            assert plan == tuning.TilePlan(
-                entry["bm"], entry["bn"], entry["bk"], entry["kc"],
-                entry["pm_layout"]), key
-    misses = [str(x.message) for x in w if "cache miss" in str(x.message)]
-    assert not misses, misses
-    tuning.clear_cache()
+    for plan in tuning.candidate_plans(2048, 2048, 2048, kind="cpm3_matmul"):
+        cost = cm.pm_grid_cost(2048, 2048, 2048, *plan.astuple(),
+                               n_row_ops=2, n_col_ops=2, n_acc=2)
+        assert cost.vmem_bytes <= tuning.VMEM_BUDGET
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
@@ -310,24 +186,8 @@ def test_batched_kernel_matches_unbatched(dtype):
             np.testing.assert_allclose(out[i], ref, rtol=1e-5, atol=1e-4)
 
 
-def test_autotune_batched_writes_batch_key(tmp_path, monkeypatch):
-    """autotune_matmul(batch=N) writes the batch-keyed entry that
-    plan_matmul(batch=N) looks up (closing the miss-warning loop)."""
-    path = tmp_path / "cache.json"
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    tuning.clear_cache()
-    cache = tuning.autotune_matmul([(16, 16, 16)], jnp.float32,
-                                   max_candidates=1, reps=1, batch=3)
-    key = "sq_matmul:3b:16x16x16:float32"
-    assert key in cache
-    plan = tuning.plan_matmul(16, 16, 16, jnp.float32, batch=3,
-                              pm_layout=cache[key]["pm_layout"])
-    assert plan.bm == cache[key]["bm"] and plan.kc == cache[key]["kc"]
-    tuning.clear_cache()
-
-
 # ---------------------------------------------------------------------------
-# The "kmn" row walk: wide tiles, the batched/fold path, TPU planning
+# The row walk: wide tiles, the batched/fold path, the chip's plans
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
@@ -343,8 +203,7 @@ def test_kmn_wide_tiles_agree(dtype, bm, bn):
     else:
         a = jnp.asarray(RNG.normal(size=(m, k)).astype(np.float32))
         b = jnp.asarray(RNG.normal(size=(k, n)).astype(np.float32))
-    out = np.asarray(ops.sq_matmul(a, b, bm=bm, bn=bn, bk=256, kc=128,
-                                   pm_layout="kmn"))
+    out = np.asarray(ops.sq_matmul(a, b, bm=bm, bn=bn, bk=256, kc=128))
     ref = np.asarray(pm_matmul_scan(a, b))
     if dtype == "int8":
         np.testing.assert_array_equal(out, ref)
@@ -355,8 +214,8 @@ def test_kmn_wide_tiles_agree(dtype, bm, bn):
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
 @pytest.mark.parametrize("fold", [False, True])
 def test_kmn_batched_agrees(dtype, fold):
-    """The batched and batch-folded kernels under "kmn" compute what the
-    chunked layout does (int8 bit-exact)."""
+    """The batched and batch-folded row walks compute each element's
+    streamed square-form contraction (int8 bit-exact)."""
     nb, m, k, n = 5, 16, 128, 256
     if dtype == "int8":
         a = jnp.asarray(RNG.integers(-128, 128, (nb, m, k)).astype(np.int8))
@@ -364,8 +223,9 @@ def test_kmn_batched_agrees(dtype, fold):
     else:
         a = jnp.asarray(RNG.normal(size=(nb, m, k)).astype(np.float32))
         b = jnp.asarray(RNG.normal(size=(nb, k, n)).astype(np.float32))
-    out = np.asarray(ops.sq_matmul(a, b, pm_layout="kmn", fold=fold))
-    ref = np.asarray(ops.sq_matmul(a, b, pm_layout="mnk"))
+    out = np.asarray(ops.sq_matmul(a, b, fold=fold))
+    ref = np.stack([np.asarray(pm_matmul_scan(a[i], b[i]))
+                    for i in range(nb)])
     if dtype == "int8":
         np.testing.assert_array_equal(out, ref)
     else:
@@ -374,78 +234,57 @@ def test_kmn_batched_agrees(dtype, fold):
 
 def test_kmn_fold_width_keeps_vreg_budget():
     from repro.core import cost_model as cm
-    plan = tuning.TilePlan(8, 512, 128, 128, "kmn")
+    plan = tuning.TilePlan(8, 512, 128, 128)
     fb = ops._pick_fb(plan, 64)
     assert fb * cm.kmn_acc_vregs(8, 512) <= cm.KMN_ACC_VREGS
-    assert ops._pick_fb(tuning.TilePlan(8, 512, 128, 128, "mkn"), 64) == 32
 
 
 @pytest.mark.parametrize("m", [8, 32])
-def test_tpu_sq_matmul_plan_is_kmn(monkeypatch, m):
-    """On the TPU, sq_matmul resolves the row walk at the decode shapes,
-    with every row of the call in one tile (the weights stream once) and
-    the tile within the vreg budget; the grouped kernel still resolves
-    "mkn"."""
+def test_tpu_sq_matmul_plan_is_kmn(m):
+    """sq_matmul walks rows, at the decode shapes with every row of the
+    call in one tile (the weights stream once) and the tile within the
+    vreg budget; the grouped kernel keeps the chunked blocks."""
     from repro.core import cost_model as cm
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
-    plan = ops._resolve_plan(m, 10240, 3840, jnp.bfloat16, bm=None, bn=None,
-                             bk=None, kc=None, pm_layout=None,
-                             interpret=False, kind="sq_matmul")
-    assert plan.pm_layout == "kmn"
+    assert tuning.pm_schedule("sq_matmul") == tuning.ROW_WALK == "kmn"
+    plan = tuning.plan_matmul(m, 10240, 3840, sq.accum_dtype(jnp.bfloat16))
     assert plan.bm == m and plan.bn >= 512 and plan.kc == 128
     assert cm.kmn_acc_vregs(plan.bm, plan.bn) <= cm.KMN_ACC_VREGS
-    grouped = ops._resolve_plan(8, 1408, 2048, jnp.float32, bm=None,
-                                bn=None, bk=None, kc=None, pm_layout=None,
-                                interpret=False, kind="sq_grouped_matmul")
-    assert grouped.pm_layout == "mkn"
+    assert tuning.pm_schedule("sq_grouped_matmul") == tuning.CHUNKED
 
 
 def test_grouped_and_paged_kernels_stay_chunked(monkeypatch):
-    """sq_grouped_matmul's plan resolves "mkn" on the TPU, and the paged
-    kernel refuses the row walk."""
-    from repro.kernels import sq_paged_attn as spa
+    """Every kernel but sq_matmul plans the chunked blocks, and the
+    grouped call plans as the grouped kind; no kernel takes a schedule
+    argument."""
+    import inspect
+
+    from repro.kernels.cpm3_matmul import cpm3_matmul_pallas
+    from repro.kernels.cpm4_matmul import cpm4_matmul_pallas
+    from repro.kernels.sq_conv2d import sq_conv2d_pallas
+    from repro.kernels.sq_grouped_matmul import sq_grouped_matmul_pallas
+    from repro.kernels.sq_matmul import sq_matmul_batched_pallas
+    from repro.kernels.sq_paged_attn import sq_paged_attn
+    for kind in ("cpm3_matmul", "cpm4_matmul", "sq_grouped_matmul",
+                 "sq_conv2d", "sq_paged_attn"):
+        assert tuning.pm_schedule(kind) == tuning.CHUNKED == "mkn"
     seen = []
-    real = ops._resolve_plan
+    real = tuning.plan_matmul
 
     def spy(*args, **kw):
-        plan = real(*args, **kw)
-        seen.append(plan.pm_layout)
-        return plan
+        seen.append(kw.get("kind", "sq_matmul"))
+        return real(*args, **kw)
 
-    monkeypatch.setattr(ops, "_resolve_plan", spy)
-    monkeypatch.setattr(ops, "_sq_grouped_exec",
-                        lambda *a, **k: None)
+    monkeypatch.setattr(tuning, "plan_matmul", spy)
+    monkeypatch.setattr(ops, "_sq_grouped_exec", lambda *a, **k: None)
     x = jnp.zeros((16, 256), jnp.float32)
     w = jnp.zeros((2, 256, 128), jnp.float32)
     ops.sq_grouped_matmul(x, w, jnp.zeros((2,), jnp.int32),
                           jnp.ones((1,), jnp.int32), bm=8, interpret=False)
-    assert seen == ["mkn"]
-    with pytest.raises(ValueError, match="pm_layout"):
-        spa.sq_paged_attn(jnp.zeros((1, 1, 1, 1, 8)), jnp.zeros((16, 1, 8)),
-                          jnp.zeros((16, 1, 8)), jnp.zeros((1, 1), jnp.int32),
-                          jnp.zeros((16,), jnp.int32),
-                          jnp.zeros((1, 1), jnp.int32), block_size=16,
-                          pm_layout="kmn")
-
-
-def test_kmn_plan_counter(monkeypatch):
-    """tuning_sq_matmul_kmn_plans_total counts sq_matmul plans resolved to
-    "kmn", and nothing else."""
-    from repro.obs import metrics as obs_metrics
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
-    ctr = obs_metrics.default_registry().counter(
-        "tuning_sq_matmul_kmn_plans_total")
-    before = ctr.value
-    tuning.plan_matmul(8, 10240, 3840, pm_layout="kmn")
-    tuning.plan_matmul(32, 10240, 3840, pm_layout="kmn")
-    assert ctr.value == before + 2
-    tuning.plan_matmul(8, 10240, 3840, pm_layout="mkn")
-    tuning.plan_matmul(8, 1408, 2048, pm_layout="mkn",
-                       kind="sq_grouped_matmul")
-    tuning.plan_matmul(64, 64, 64, pm_layout="mnk")
-    assert ctr.value == before + 2
-    tuning.plan_matmul(64, 64, 64, bm=8, bn=128, bk=64, pm_layout="kmn")
-    assert ctr.value == before + 3
+    assert seen == ["sq_grouped_matmul"]
+    for fn in (sq_matmul_batched_pallas, cpm3_matmul_pallas,
+               cpm4_matmul_pallas, sq_conv2d_pallas, sq_grouped_matmul_pallas,
+               sq_paged_attn):
+        assert "pm_layout" not in inspect.signature(fn).parameters, fn
 
 
 @pytest.mark.parametrize("shape,want", [
@@ -454,28 +293,196 @@ def test_kmn_plan_counter(monkeypatch):
     ((32, 10240, 3840), (8, 128, 3840, 1920)),
     ((256, 256, 256), (32, 256, 256, 256)),
 ])
-def test_chunked_layouts_plan_as_before(monkeypatch, shape, want):
-    """A single chunked layout is still ranked by the unchanged cost: the
-    "mkn" picks of the grouped kernel are the ones it had before "kmn"
+def test_chunked_layouts_plan_as_before(shape, want):
+    """The chunked schedule is still ranked by the unchanged cost: the
+    picks of the grouped kernel are the ones it had before the row walk
     existed (Moonlight's expert shapes among them)."""
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
     m, n, k = shape
-    plan = tuning.plan_matmul(m, n, k, pm_layout="mkn",
-                              kind="sq_grouped_matmul")
-    assert plan == tuning.TilePlan(*want, "mkn")
+    plan = tuning.plan_matmul(m, n, k, kind="sq_grouped_matmul")
+    assert plan == tuning.TilePlan(*want)
 
 
 @pytest.mark.parametrize("m", [8, 32, 256])
-def test_kmn_plans_pad_only_to_the_lane_granule(monkeypatch, m):
-    """A "kmn" plan pads K and N no further than the 128-lane granule, so
-    the bench's HLO counts the call's true shape and a prepared weight
+def test_kmn_plans_pad_only_to_the_lane_granule(m):
+    """A row-walk plan pads K and N no further than the 128-lane granule,
+    so the bench's HLO counts the call's true shape and a prepared weight
     (laid out at that granule) is reused without a per-call re-pad."""
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
 
     def up(x):
         return -(-x // tuning.LANE) * tuning.LANE
 
     for (k, n) in [(3840, 1024), (3840, 10240), (10240, 3840), (3840, 32000),
                    (2048, 2816), (2048, 576), (2816, 2048), (2048, 20480)]:
-        plan = tuning.plan_matmul(m, n, k, pm_layout="kmn")
+        plan = tuning.plan_matmul(m, n, k)
         assert up(k) % plan.bk == 0 and up(n) % plan.bn == 0, (k, n, plan)
+
+
+# ---------------------------------------------------------------------------
+# The plans the benchmark cells' shapes resolve to on the TPU, pinned
+# ---------------------------------------------------------------------------
+
+# sq_matmul calls of the cells (benchmarks/kernel_timing.py
+# CELL_SQ_MATMUL_SHAPES): (batch, m, k, n) -> (bm, bn, bk, kc) and the
+# fold width of a batched call
+CELL_SQ_MATMUL_PLANS = [
+    ((1, 8, 3840, 1024), (8, 1024, 1280, 128), 1),
+    ((1, 8, 3840, 3840), (8, 3840, 384, 128), 1),
+    ((1, 8, 3840, 10240), (8, 2560, 384, 128), 1),
+    ((1, 8, 3840, 32000), (8, 3200, 384, 128), 1),
+    ((1, 32, 3840, 1024), (32, 1024, 1280, 128), 1),
+    ((1, 32, 3840, 3840), (32, 768, 1920, 128), 1),
+    ((1, 32, 3840, 10240), (32, 1024, 1280, 128), 1),
+    ((1, 32, 3840, 32000), (32, 640, 1920, 128), 1),
+    ((1, 8, 10240, 3840), (8, 3840, 256, 128), 1),
+    ((1, 32, 10240, 3840), (32, 768, 1280, 128), 1),
+    ((1, 32, 2048, 3072), (32, 1024, 1024, 128), 1),
+    ((1, 32, 2048, 576), (32, 640, 2048, 128), 1),
+    ((1, 32, 2048, 2816), (16, 1408, 1024, 128), 1),
+    ((1, 32, 2048, 11264), (32, 1024, 1024, 128), 1),
+    ((1, 32, 2048, 20480), (32, 1024, 1024, 128), 1),
+    ((1, 32, 2048, 2048), (32, 1024, 1024, 128), 1),
+    ((1, 32, 2816, 2048), (32, 1024, 1408, 128), 1),
+    ((1, 32, 11264, 2048), (32, 1024, 1408, 128), 1),
+    ((16, 32, 128, 512), (32, 512, 128, 128), 2),
+    ((16, 32, 512, 128), (32, 128, 512, 128), 8),
+]
+CELL_PLANS = (
+    [pytest.param("sq_matmul", call, (plan, fb), id="sq_matmul-" +
+                  "x".join(map(str, call))) for call, plan, fb in
+     CELL_SQ_MATMUL_PLANS]
+    # Moonlight's grouped experts: (bm, k, n) -> (bm, bn, bk, kc)
+    + [pytest.param("grouped", (8, 2048, 1408), (8, 128, 2048, 2048),
+                    id="grouped-8x2048x1408"),
+       pytest.param("grouped", (8, 1408, 2048), (8, 128, 1408, 1408),
+                    id="grouped-8x1408x2048")]
+    # decode attention: (hd, tile) -> (kc_qk, kc_pv); danube's 8 KV heads
+    # of 120, Moonlight's 576-wide latent pool, 128-token tiles
+    + [pytest.param("paged", (120, 128), (120, 128), id="paged-danube"),
+       pytest.param("paged", (576, 128), (576, 128), id="paged-moonlight")])
+
+
+@pytest.mark.parametrize("kind,call,want", CELL_PLANS)
+def test_cell_plans_unchanged(kind, call, want):
+    """Every cell shape resolves to the plan the TPU ran before each
+    kernel was held to one schedule (the plans were recorded from the
+    TPU-side resolution of that tree)."""
+    if kind == "sq_matmul":
+        b, m, k, n = call
+        plan = tuning.plan_matmul(m, n, k, sq.accum_dtype(jnp.bfloat16))
+        assert (plan.astuple(), ops._pick_fb(plan, b)) == want
+    elif kind == "grouped":
+        bm, k, n = call
+        assert tuning.plan_grouped_matmul(bm, n, k).astuple() == want
+    else:
+        p = tuning.plan_paged_attn(*call)
+        assert (p.kc_qk, p.kc_pv) == want
+
+
+def _record_exec(monkeypatch, name, plan_at, fb_at=None):
+    """Stub ``ops.<name>`` (an execute half) with a recorder of the plan
+    (and fold width) it is handed."""
+    seen = []
+
+    def rec(*args, **kw):
+        seen.append((args[plan_at],
+                     None if fb_at is None else args[fb_at]))
+
+    monkeypatch.setattr(ops, name, rec)
+    return seen
+
+
+def _cplx(shape):
+    return jnp.asarray((RNG.normal(size=shape)
+                        + 1j * RNG.normal(size=shape)).astype(np.complex64))
+
+
+BACKEND_FORMS = {
+    # name: (execute half to stub, plan arg index, fb arg index, call)
+    "sq_matmul": ("_sq_matmul_impl", 2, None, lambda i: ops.sq_matmul(
+        jnp.zeros((8, 384)), jnp.zeros((384, 1280)), interpret=i)),
+    "sq_matmul_batched": ("_sq_matmul_batched_impl", 3, 2,
+                          lambda i: ops.sq_matmul(
+                              jnp.zeros((3, 32, 512)),
+                              jnp.zeros((3, 512, 256)), interpret=i)),
+    "sq_matmul_folded": ("_sq_matmul_batched_impl", 3, 2,
+                         lambda i: ops.sq_matmul(
+                             jnp.zeros((16, 32, 128)),
+                             jnp.zeros((16, 128, 512)), interpret=i,
+                             fold=True)),
+    "grouped": ("_sq_grouped_exec", 4, None, lambda i: ops.sq_grouped_matmul(
+        jnp.zeros((16, 2048)), jnp.zeros((2, 2048, 1408), jnp.bfloat16),
+        jnp.zeros((2,), jnp.int32), jnp.ones((1,), jnp.int32), bm=8,
+        interpret=i)),
+    "cpm3": ("_cpm3_impl", 4, None, lambda i: ops.cpm3_matmul(
+        _cplx((40, 300)), _cplx((300, 200)), interpret=i)),
+    "cpm4": ("_cpm4_impl", 4, None, lambda i: ops.cpm4_matmul(
+        _cplx((40, 300)), _cplx((300, 200)), interpret=i)),
+    "conv2d": ("_sq_conv2d_fused_impl", 2, None, lambda i: ops.sq_conv2d(
+        jnp.zeros((2, 64, 34, 34)), jnp.zeros((64, 64, 3, 3)),
+        interpret=i)),
+}
+
+
+@pytest.mark.parametrize("form", list(BACKEND_FORMS) + ["paged"])
+def test_plan_is_backend_independent(monkeypatch, form):
+    """Interpret mode runs the plan, and so the schedule, the chip runs:
+    each call form hands its kernel the same plan for interpret=True as
+    for interpret=False."""
+    if form == "paged":
+        from repro.kernels import sq_paged_attn as spa
+        from repro.models import attention as attn
+
+        seen = []
+        monkeypatch.setattr(spa, "sq_paged_attn", lambda *a, **kw: seen.append(
+            (kw["kc_qk"], kw["kc_pv"])) or jnp.zeros((1, 1, 8, 4, 120)))
+        for interpret in (True, False):
+            monkeypatch.setattr(ops, "default_interpret", lambda i=interpret: i)
+            paged = {"tables": jnp.zeros((1, 96), jnp.int32), "block_size": 16,
+                     "pos_pool": jnp.zeros((1536,), jnp.int32)}
+            attn.paged_read(
+                jnp.zeros((1, 1, 8, 4, 120)), jnp.zeros((1536, 8, 120)),
+                jnp.zeros((1536, 8, 120)), jnp.zeros((1, 1), jnp.int32),
+                paged, dt=jnp.dtype(jnp.bfloat16), window=None, softcap=0.0,
+                mode="square_pallas", policy=None,
+                gather_attend=lambda: None)
+        assert len(seen) == 2 and seen[0] == seen[1] == (120, 128)
+        return
+    name, plan_at, fb_at, call = BACKEND_FORMS[form]
+    seen = _record_exec(monkeypatch, name, plan_at, fb_at)
+    call(True)
+    call(False)
+    assert len(seen) == 2 and seen[0] == seen[1], seen
+
+
+# The cells' prepared weights at their decode M: (K, N) of the canonical
+# operand, transposed tables for the tied heads
+PREPARED_CELL_WEIGHTS = [
+    pytest.param((32000, 3840), True, 8, id="danube-head"),
+    pytest.param((20480, 2048), True, 32, id="moonlight-head"),
+    pytest.param((2048, 576), False, 32, id="moonlight-wkv_a"),
+    pytest.param((11264, 2048), False, 32, id="moonlight-lead-down"),
+]
+
+
+@pytest.mark.parametrize("shape,transpose,m", PREPARED_CELL_WEIGHTS)
+def test_prepared_plan_is_the_execute_plan(monkeypatch, shape, transpose, m):
+    """A prepared weight is padded as its execute plan pads it, so
+    sq_matmul streams the prepared arrays untouched (no per-call slice
+    and re-pad).  Traced abstractly: no weight is materialized."""
+    from repro.core.prepared import prepare_operand
+
+    seen = []
+    monkeypatch.setattr(ops, "_sq_matmul_exec",
+                        lambda a, bw, sb, n, plan, interpret: seen.append(
+                            (bw, sb)))
+    k = shape[0] if not transpose else shape[1]
+
+    def run(w, a):
+        prep = prepare_operand(w, transpose=transpose, site="cell")
+        ops.sq_matmul(a, prep, interpret=True)
+        assert seen[-1][0] is prep.canon and seen[-1][1] is prep.corr
+        return prep.canon
+
+    jax.eval_shape(run, jax.ShapeDtypeStruct(shape, jnp.float32),
+                   jax.ShapeDtypeStruct((m, k), jnp.bfloat16))
+    assert len(seen) == 1

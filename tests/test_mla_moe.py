@@ -45,8 +45,7 @@ KERNELS = ContractionPolicy.of(attn_paged="square_pallas",
 
 
 @pytest.fixture(autouse=True)
-def _no_autotune(monkeypatch):
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+def _fresh_route_health():
     routing.reset_route_health()
     yield
     routing.reset_route_health()

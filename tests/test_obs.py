@@ -1,5 +1,5 @@
 """Observability layer unit suite (repro.obs): tracer semantics, metric
-types, exporters, and the route/guard/tuning surfacing hooks.
+types, exporters, and the route/guard surfacing hooks.
 
 The load-bearing properties pinned here:
 
@@ -12,15 +12,14 @@ The load-bearing properties pinned here:
 - the Chrome export is loadable trace_event JSON and the request
   breakdown reconstructs queue/prefill/ttft/decode from lifecycle
   events alone;
-- RouteHealth.snapshot() and the autotune cache-miss warning carry the
-  operator-facing payloads (trip ordinals, ready-to-paste cache entry).
+- RouteHealth.snapshot() carries the operator-facing payload (trip
+  ordinals).
 """
 import json
-import warnings
 
 import pytest
 
-from repro.kernels import routing, tuning
+from repro.kernels import routing
 from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -334,7 +333,7 @@ def test_chrome_trace_is_valid_trace_event_json(tmp_path):
     assert min(e["ts"] for e in xs) == 0.0
 
 
-# ------------------------------------------- route health / tuning hooks
+# ---------------------------------------------------- route health hooks
 
 def test_route_health_snapshot_fields():
     routing.reset_route_health()
@@ -379,38 +378,6 @@ def test_guard_trip_emits_trace_events():
         assert names.count("guard.demote") == 1
     finally:
         routing.reset_route_health()
-
-
-def test_autotune_miss_warning_carries_pasteable_entry(tmp_path,
-                                                       monkeypatch):
-    # point the cache at an empty scratch file so the lookup MUST miss
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "cache.json"))
-    tuning.clear_cache()
-    try:
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            plan = tuning.plan_matmul(7, 11, 13)
-        msgs = [str(x.message) for x in w
-                if "autotune cache miss" in str(x.message)]
-        assert len(msgs) == 1
-        (msg,) = msgs
-        assert "ready to paste" in msg
-        payload = json.loads(msg[msg.index("{"):])
-        ((key, entry),) = payload.items()
-        assert key.startswith("sq_matmul:7x11x13:")
-        # the entry is exactly the plan this call served
-        assert entry == {"bm": plan.bm, "bn": plan.bn, "bk": plan.bk,
-                         "kc": plan.kc, "pm_layout": plan.pm_layout}
-        # paste it into the cache file: the next lookup is a silent hit
-        tuning.save_cache(payload)
-        with warnings.catch_warnings(record=True) as w2:
-            warnings.simplefilter("always")
-            plan2 = tuning.plan_matmul(7, 11, 13)
-        assert not [x for x in w2
-                    if "autotune cache miss" in str(x.message)]
-        assert (plan2.bm, plan2.bn, plan2.bk) == (plan.bm, plan.bn, plan.bk)
-    finally:
-        tuning.clear_cache()
 
 
 def test_unified_snapshot_covers_whole_stack(tmp_path, capsys):
@@ -500,19 +467,3 @@ def test_unified_snapshot_covers_whole_stack(tmp_path, capsys):
     obs_report.render(snap)
     out = capsys.readouterr().out
     assert "tok/s" in out and "square-route audit" in out.lower()
-
-
-def test_cache_lookup_counters_and_events(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "cache.json"))
-    tuning.clear_cache()
-    try:
-        reg = obs_metrics.default_registry()
-        miss0 = reg.counter("tuning_cache_misses_total").value
-        with obs_trace.capture() as tr, warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            tuning.plan_matmul(7, 11, 17)
-        assert reg.counter("tuning_cache_misses_total").value == miss0 + 1
-        evs = [r for r in tr.records() if r.name == "tuning.cache"]
-        assert len(evs) == 1 and evs[0].args["hit"] is False
-    finally:
-        tuning.clear_cache()

@@ -8,8 +8,6 @@ row convention, same f32 accumulation -- because the serving engine flips
 between them purely on the cost model."""
 import dataclasses
 import functools
-import json
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +23,7 @@ from repro.serve import paged as pg
 
 
 @pytest.fixture(autouse=True)
-def _no_autotune(monkeypatch):
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+def _fresh_route_health():
     routing.reset_route_health()
     yield
     routing.reset_route_health()
@@ -119,9 +116,10 @@ _CASES = {
 }
 
 
-@pytest.mark.parametrize("pm_layout", ["mnk", "mkn"])
-@pytest.mark.parametrize("case", list(_CASES))
-def test_kernel_matches_gather_reference(pm_layout, case):
+# the ids keep the "-mkn" of the chunked PM blocks, the kernel's schedule
+@pytest.mark.parametrize("case", [pytest.param(c, id=f"{c}-mkn")
+                                  for c in _CASES])
+def test_kernel_matches_gather_reference(case):
     setup, call = (dict(d) for d in _CASES[case])
     pad = setup.pop("pad", 0)
     bs = setup.get("block_size", 4)
@@ -130,8 +128,7 @@ def test_kernel_matches_gather_reference(pm_layout, case):
         args = _evict(args, call["window"], bs)
     if pad:                                   # padded query rows (-1)
         args = (*args[:5], args[5].at[0, -pad:].set(-1))
-    out = sq_paged_attn(*args, block_size=bs, pm_layout=pm_layout,
-                        interpret=True, **call)
+    out = sq_paged_attn(*args, block_size=bs, interpret=True, **call)
     ref = _reference(*args, block_size=bs, window=call.get("window"),
                      softcap=call.get("softcap", 0.0))
     valid = np.asarray(args[5]) >= 0          # padding rows are discarded
@@ -313,59 +310,23 @@ def test_paged_attn_route_env_override(monkeypatch):
     assert routing.select_paged_attn_route(1, 512).name == "gather"
 
 
-def test_paged_attn_route_cache_pin(monkeypatch, tmp_path):
-    path = str(tmp_path / "cache.json")
-    monkeypatch.setenv("REPRO_TUNING_CACHE", path)
-    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
-    tuning.clear_cache()
-    sizes = {"b": 1, "s": 1, "t": 32, "kv": 2, "g": 2, "hd": 64}
-    routing.set_route_override("paged_attn", dict(sizes), "kernel")
-    r = routing.select_paged_attn_route(1, 32, kv_heads=2, group=2, hd=64)
-    assert r.name == "kernel" and "cache" in r.reason
-    tuning.clear_cache()
-
-
 def test_select_route_generic_and_unknown_kind():
     r = routing.select_route("paged_attn",
                              {"s": 1, "t": 128, "kv": 2, "g": 2, "hd": 64})
     assert r.name == "kernel"
     with pytest.raises(ValueError, match="unknown route kind"):
         routing.select_route("attn", {})
-    with pytest.raises(ValueError, match="unknown route kind"):
-        routing.set_route_override("attn", {}, "kernel")
 
 
-def test_plan_paged_attn(monkeypatch, tmp_path):
-    # the third axis is the tile the kernel walks per grid step (tokens)
-    p = tuning.plan_paged_attn(8, 64, 128, pm_layout="mnk")
-    assert p.kc_qk == tuning.KC_MNK_MAX and p.kc_pv == tuning.KC_MNK_MAX
-    p = tuning.plan_paged_attn(8, 64, 128, pm_layout="mkn")
+def test_plan_paged_attn():
+    # the second axis is the tile the kernel walks per grid step (tokens)
+    p = tuning.plan_paged_attn(64, 128)
     assert (p.kc_qk, p.kc_pv) == (64, 128)       # full-axis chunks
-    p = tuning.plan_paged_attn(8, 64, 128, kc_qk=16, kc_pv=4)
+    p = tuning.plan_paged_attn(64, 128, kc_qk=16, kc_pv=4)
     assert (p.kc_qk, p.kc_pv) == (16, 4)
     # explicit knobs are clamped to divide their axes
-    p = tuning.plan_paged_attn(8, 48, 96, kc_qk=32, kc_pv=64)
+    p = tuning.plan_paged_attn(48, 96, kc_qk=32, kc_pv=64)
     assert 48 % p.kc_qk == 0 and 96 % p.kc_pv == 0
-    # an entry keyed on the one-head, one-block kernel's geometry is never
-    # served; one keyed on the tile and head count is
-    path = tmp_path / "cache.json"
-    path.write_text(json.dumps({
-        "sq_paged_attn:8x64x128:float32":
-            {"kc_qk": 8, "kc_pv": 8, "pm_layout": "mkn"},
-        "sq_paged_attn:4kv:8x64x128:bfloat16":
-            {"kc_qk": 32, "kc_pv": 64, "pm_layout": "mkn"}}))
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(path))
-    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
-    tuning.clear_cache()
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")        # the expected miss
-            p = tuning.plan_paged_attn(8, 64, 128, kv_heads=1)
-        assert (p.kc_qk, p.kc_pv) == (64, 128)
-        p = tuning.plan_paged_attn(8, 64, 128, jnp.bfloat16, kv_heads=4)
-        assert (p.kc_qk, p.kc_pv) == (32, 64)
-    finally:
-        tuning.clear_cache()
 
 
 # ----------------------------------------------------- dispatch wiring

@@ -2,13 +2,11 @@
 
 Acceptance (ISSUE 4): PreparedOperand reuse is bit-identical to raw-array
 dispatch across ALL five modes and dtypes (incl. int8); the cache key
-invalidates on shape/dtype/layout/site changes; and select_route's four
+invalidates on shape/dtype/site changes; and select_route's four
 regime choices are pinned to the cost model (tiny-K conv -> im2col,
 batch-4 conv -> fused, small-MN-large-B GEMM -> batch-fold, sub-floor ->
-virtual), with the REPRO_ROUTE and autotune-cache overrides honored.
+virtual), with the REPRO_ROUTE override honored.
 """
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +17,7 @@ from repro.core import matmul as M
 from repro.core.einsum import fs_einsum
 from repro.core.matmul import MODES
 from repro.core.prepared import PreparedOperand, prepare_operand, unwrap
-from repro.kernels import ops, routing, tuning
+from repro.kernels import ops, routing
 
 RNG = np.random.default_rng(11)
 
@@ -129,8 +127,8 @@ def test_prepare_is_idempotent_and_unwrap():
 
 
 def test_cache_key_invalidation():
-    """The cache key must change with shape, dtype, layout (transpose /
-    pm-layout) and site -- anything that changes the prepared artifact."""
+    """The cache key must change with shape, dtype, layout (transpose)
+    and site -- anything that changes the prepared artifact."""
     w32 = jnp.zeros((16, 24), jnp.float32)
     base = prepare_operand(w32, site="dense")
     assert prepare_operand(jnp.zeros((16, 24), jnp.bfloat16),
@@ -138,8 +136,7 @@ def test_cache_key_invalidation():
     assert prepare_operand(jnp.zeros((24, 16), jnp.float32),
                            site="dense").key != base.key
     assert prepare_operand(w32, site="ffn").key != base.key
-    assert prepare_operand(w32, site="dense",
-                           interpret=False).key != base.key
+    assert prepare_operand(w32, site="dense", transpose=True).key != base.key
     assert prepare_operand(w32, site="dense").key == base.key
 
 
@@ -225,38 +222,6 @@ def test_repro_route_bare_name_scopes_to_its_kind(monkeypatch):
     assert routing.select_conv2d_route(60, 60, 5, 5, 1, 1).name == "im2col"
 
 
-def test_route_override_keys_on_accumulator_dtype(tmp_path, monkeypatch):
-    """A bf16/int8 route pin must land on the key the selectors look up
-    (they key post-widening, on the accumulator dtype)."""
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "c.json"))
-    tuning.clear_cache()
-    routing.set_route_override(
-        "matmul", {"b": 1, "m": 8, "n": 8, "k": 8, "dtype": "bfloat16"},
-        "kernel")
-    assert routing.select_matmul_route(8, 8, 8,
-                                       dtype=jnp.bfloat16).name == "kernel"
-    tuning.clear_cache()
-
-
-def test_route_autotune_cache_override(tmp_path, monkeypatch):
-    """A route: entry in the tuning cache pins the shape's route; the
-    REPRO_AUTOTUNE=0 hatch disables it like any other cache consult."""
-    cache_file = tmp_path / "cache.json"
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(cache_file))
-    tuning.clear_cache()
-    key = routing.set_route_override(
-        "matmul", {"b": 1, "m": 256, "n": 256, "k": 256}, "virtual")
-    assert json.loads(cache_file.read_text())[key] == {"route": "virtual"}
-    assert routing.select_matmul_route(256, 256, 256).name == "virtual"
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
-    assert routing.select_matmul_route(256, 256, 256).name == "kernel"
-    monkeypatch.delenv("REPRO_AUTOTUNE")
-    with pytest.raises(ValueError, match="route"):
-        routing.set_route_override("matmul", {"m": 1, "n": 1, "k": 1},
-                                   "bogus")
-    tuning.clear_cache()
-
-
 def test_einsum_pallas_routes_through_planner(monkeypatch):
     """square_pallas einsum dispatch honors the forced route end-to-end
     (numerics stay correct on every route)."""
@@ -274,14 +239,13 @@ def test_einsum_pallas_routes_through_planner(monkeypatch):
 # Batch-folded kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("layout", ["mnk", "mkn"])
-def test_folded_kernel_matches_batched(layout):
+def test_folded_kernel_matches_batched():
     """fold=True is the same arithmetic as the one-element-per-step
-    batched kernel, for both PM-block layouts and for int8."""
+    batched kernel."""
     a = jnp.asarray(RNG.normal(size=(10, 6, 40)).astype(np.float32))
     b = jnp.asarray(RNG.normal(size=(10, 40, 12)).astype(np.float32))
-    r1 = np.asarray(ops.sq_matmul(a, b, pm_layout=layout))
-    r2 = np.asarray(ops.sq_matmul(a, b, pm_layout=layout, fold=True))
+    r1 = np.asarray(ops.sq_matmul(a, b))
+    r2 = np.asarray(ops.sq_matmul(a, b, fold=True))
     np.testing.assert_allclose(r1, r2, rtol=1e-6, atol=1e-5)
     np.testing.assert_allclose(r2, np.asarray(a) @ np.asarray(b),
                                rtol=1e-5, atol=1e-4)

@@ -8,8 +8,7 @@ the scoped VMEM), so these tests guard the kernels the chip runs.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.  Every test passes
-``interpret=False`` and a TPU layout (``pm_layout="mkn"``, or "kmn" for
-``sq_matmul``) explicitly, because code that asks
+``interpret=False`` explicitly, because code that asks
 ``jax.default_backend()`` still sees the CPU.
 """
 import dataclasses
@@ -25,8 +24,9 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import cost_model as cm
+from repro.core import squares as sq
 from repro.kernels import ops, tuning
-from repro.kernels.sq_matmul import sq_matmul_pallas
+from repro.kernels.cpm3_matmul import cpm3_matmul_pallas
 from repro.kernels.sq_paged_attn import sq_paged_attn
 
 # danube-3-4b attention geometry: 8 KV heads x 4 query groups, head_dim 120
@@ -71,57 +71,45 @@ def _names_sq_matmul_kernel(hlo):
 
 
 # danube's decode and chunk GEMMs, its MLP down-projection and head, and
-# Moonlight's head (an eighth of the vocabulary); the "mkn" cases keep
-# the ids they had before the layout was a parameter
-@pytest.mark.parametrize("m,k,n,pm_layout", [
-    pytest.param(m, k, n, layout,
-                 id=f"{m}-{k}-{n}" + ("" if layout == "mkn" else "-kmn"))
-    for layout in ("mkn", "kmn")
+# Moonlight's head (an eighth of the vocabulary); the ids keep the "-kmn"
+# they had when the row walk was one of two schedules
+@pytest.mark.parametrize("m,k,n", [
+    pytest.param(m, k, n, id=f"{m}-{k}-{n}-kmn")
     for (m, k, n) in [(8, 3840, 10240), (256, 3840, 3840), (8, 10240, 3840),
                       (32, 3840, 32000), (32, 2048, 20480)]])
-def test_sq_matmul_compiles(one_chip, m, k, n, pm_layout):
-    fn = functools.partial(ops.sq_matmul, interpret=False,
-                           pm_layout=pm_layout)
+def test_sq_matmul_compiles(one_chip, m, k, n):
+    fn = functools.partial(ops.sq_matmul, interpret=False)
     hlo = _compile_hlo(fn, ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16),
                        sharding=one_chip)
     assert "tpu_custom_call" in hlo
-    if pm_layout == "kmn":
-        assert _names_sq_matmul_kernel(hlo)
+    assert _names_sq_matmul_kernel(hlo)
 
 
 # attention-shaped batched GEMM (B*H heads of (S, hd) @ (hd, T)), folded
 # or not, and Moonlight's absorbed latent projections, 16 heads of 32
 # rows, which route to the batched (unfolded) kernel
-@pytest.mark.parametrize("b,m,k,n,fold,pm_layout", [
-    pytest.param(b, m, k, n, fold, layout,
+@pytest.mark.parametrize("b,m,k,n,fold", [
+    pytest.param(b, m, k, n, fold,
                  id=("" if (b, m, k, n) == (32, 16, 128, 256)
-                     else f"{b}-{m}-{k}-{n}-") + str(fold)
-                 + ("" if layout == "mkn" else "-kmn"))
-    for layout in ("mkn", "kmn")
+                     else f"{b}-{m}-{k}-{n}-") + str(fold) + "-kmn")
     for (b, m, k, n, fold) in [(32, 16, 128, 256, False),
                                (32, 16, 128, 256, True),
                                (16, 32, 128, 512, False),
                                (16, 32, 512, 128, False)]])
-def test_sq_matmul_batched_compiles(one_chip, fold, pm_layout, b, m, k, n):
-    fn = functools.partial(ops.sq_matmul, interpret=False,
-                           pm_layout=pm_layout, fold=fold)
+def test_sq_matmul_batched_compiles(one_chip, fold, b, m, k, n):
+    fn = functools.partial(ops.sq_matmul, interpret=False, fold=fold)
     hlo = _compile_hlo(fn, ((b, m, k), jnp.bfloat16),
                        ((b, k, n), jnp.bfloat16), sharding=one_chip)
     assert "tpu_custom_call" in hlo
-    if pm_layout == "kmn":
-        assert _names_sq_matmul_kernel(hlo)
+    assert _names_sq_matmul_kernel(hlo)
 
 
-def test_sq_matmul_tpu_plans_compile(one_chip, monkeypatch):
-    """The planner's own TPU picks (as ``ops._resolve_plan`` resolves
-    them on the chip) compile at the cells' decode shapes, and take the
-    row walk."""
-    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+def test_sq_matmul_tpu_plans_compile(one_chip):
+    """The planner's own picks compile at the cells' decode shapes, and
+    take the row walk."""
+    assert tuning.pm_schedule("sq_matmul") == tuning.ROW_WALK
     for (m, k, n) in [(8, 3840, 10240), (32, 3840, 3840), (32, 2048, 2048)]:
-        plan = ops._resolve_plan(m, n, k, jnp.bfloat16, bm=None, bn=None,
-                                 bk=None, kc=None, pm_layout=None,
-                                 interpret=False, kind="sq_matmul")
-        assert plan.pm_layout == "kmn", plan
+        plan = tuning.plan_matmul(m, n, k, sq.accum_dtype(jnp.bfloat16))
         fn = functools.partial(ops.sq_matmul, interpret=False,
                                **dataclasses.asdict(plan))
         hlo = _compile_hlo(fn, ((m, k), jnp.bfloat16),
@@ -131,14 +119,14 @@ def test_sq_matmul_tpu_plans_compile(one_chip, monkeypatch):
 
 @pytest.mark.parametrize("op", [ops.cpm3_matmul, ops.cpm4_matmul])
 def test_cpm_compiles(one_chip, op):
-    fn = functools.partial(op, interpret=False, pm_layout="mkn")
+    fn = functools.partial(op, interpret=False)
     hlo = _compile_hlo(fn, ((256, 256), jnp.complex64),
                        ((256, 256), jnp.complex64), sharding=one_chip)
     assert "tpu_custom_call" in hlo
 
 
 def test_sq_conv2d_compiles(one_chip):
-    fn = functools.partial(ops.sq_conv2d, interpret=False, pm_layout="mkn")
+    fn = functools.partial(ops.sq_conv2d, interpret=False)
     hlo = _compile_hlo(fn, ((4, 64, 32, 32), jnp.float32),
                        ((64, 64, 3, 3), jnp.float32), sharding=one_chip)
     assert "tpu_custom_call" in hlo
@@ -179,8 +167,7 @@ def test_sq_paged_attn_compiles_at_danube_geometry(one_chip):
     B, KV, G, hd, bs = d["B"], d["KV"], d["G"], d["hd"], d["block_size"]
     nb = d["blocks_per_seq"]
     pool = (1 + B * nb) * bs                 # null block + every table
-    fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False,
-                           pm_layout="mkn")
+    fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False)
     args = [jax.ShapeDtypeStruct((B, 1, KV, G, hd), jnp.float32,
                                  sharding=one_chip),
             _row_major((pool, KV, hd), jnp.bfloat16, one_chip),
@@ -210,7 +197,7 @@ def test_sq_paged_attn_compiles_across_geometries(one_chip, B, S, KV, G,
                                                   hd, bs, nb, dtype):
     pool = (1 + B * nb) * bs
     fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False,
-                           pm_layout="mkn", window=bs * 3)
+                           window=bs * 3)
     hlo = _compile_hlo(fn, ((B, S, KV, G, hd), jnp.float32),
                        ((pool, KV, hd), dtype), ((pool, KV, hd), dtype),
                        ((B, nb), jnp.int32), ((pool,), jnp.int32),
@@ -263,21 +250,22 @@ def test_danube_decode_step_widens_no_pool(one_chip, monkeypatch):
 
 
 def test_planner_budget_edge_plan_compiles(one_chip):
-    """The largest plan the VMEM check admits still fits the chip: the
-    check counts every buffer, the live PM block included, at its tiled
-    size."""
+    """The largest chunked-block plan the VMEM check admits still fits the
+    chip: the check counts every buffer, the live PM blocks included, at
+    its tiled size."""
     m, n, k = 512, 3840, 3840
-    plans = tuning.candidate_plans(m, n, k, pm_layout="mkn")
+    plans = tuning.candidate_plans(m, n, k, kind="cpm3_matmul")
     edge = max(plans, key=lambda p: cm.pm_grid_cost(
-        m, n, k, *p.astuple()).vmem_bytes)
+        m, n, k, *p.astuple(), n_row_ops=2, n_col_ops=2,
+        n_acc=2).vmem_bytes)
     mp, np_, kp = (-(-d // t) * t for d, t in
                    ((m, edge.bm), (n, edge.bn), (k, edge.bk)))
-    fn = functools.partial(sq_matmul_pallas, bm=edge.bm, bn=edge.bn,
-                           bk=edge.bk, kc=edge.kc, pm_layout="mkn",
-                           interpret=False)
-    hlo = _compile_hlo(fn, ((mp, kp), jnp.float32), ((kp, np_), jnp.float32),
-                       ((mp, 1), jnp.float32), ((1, np_), jnp.float32),
-                       sharding=one_chip)
+    fn = functools.partial(cpm3_matmul_pallas, bm=edge.bm, bn=edge.bn,
+                           bk=edge.bk, kc=edge.kc, interpret=False)
+    f32 = jnp.float32
+    hlo = _compile_hlo(fn, ((mp, kp), f32), ((mp, kp), f32), ((kp, np_), f32),
+                       ((kp, np_), f32), ((mp, 1), f32), ((mp, 1), f32),
+                       ((1, np_), f32), ((1, np_), f32), sharding=one_chip)
     assert "tpu_custom_call" in hlo
 
 
@@ -297,7 +285,7 @@ def test_latent_sq_paged_attn_compiles_at_moonlight_geometry(one_chip):
                             m["block_size"], m["blocks_per_seq"])
     pool = (1 + B * nb) * bs
     fn = functools.partial(sq_paged_attn, block_size=bs, interpret=False,
-                           pm_layout="mkn", dv=Dv)
+                           dv=Dv)
 
     def latent(q, kv, tables, pos_pool, q_pos):
         return fn(q, kv, None, tables, pos_pool, q_pos)
